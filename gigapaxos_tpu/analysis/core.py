@@ -225,7 +225,7 @@ def build_context(repo_root: Path, decls) -> Context:
         usage.extend(
             sf for sf in load_tree(tests_dir, repo_root)
             if "analysis_fixtures" not in sf.rel)
-    for extra in ("bench.py", "tpu_watch.py", "render_perf.py"):
+    for extra in ("bench.py", "chip_smoke.py", "render_perf.py"):
         p = repo_root / extra
         if p.is_file():
             sf = load_file(p, repo_root)

@@ -9,7 +9,8 @@ tests to check parity).
 
 Public surface:
 
-- ``HAVE_NATIVE``: bool
+- ``have_native() -> bool``: the C++ library built and loaded (False =
+  the Python fallbacks serve)
 - ``scan_frames(buf) -> (offs, lens, consumed)``
 - ``parse_requests(buf, offs, lens) -> (sender, gkey, req_id, flags,
   pay_off, pay)``

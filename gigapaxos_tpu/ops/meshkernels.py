@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from gigapaxos_tpu.ops import kernels as _K
@@ -135,9 +134,9 @@ class MeshKernels:
             return jax.jit(
                 EngineLedger.traced(
                     f"mesh.{name}",
-                    shard_map(local, mesh=mesh,
-                              in_specs=(sh,) + (rp,) * n_in,
-                              out_specs=out_specs, check_rep=False)),
+                    jax.shard_map(local, mesh=mesh,
+                                  in_specs=(sh,) + (rp,) * n_in,
+                                  out_specs=out_specs, check_vma=False)),
                 donate_argnums=0)
 
         # packed hot entries: (state, [k, B]) -> (state, [j, B])

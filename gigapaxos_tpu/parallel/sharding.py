@@ -35,7 +35,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gigapaxos_tpu.ops.meshkernels import GROUP_AXIS
@@ -115,9 +114,9 @@ def make_sharded_storm(mesh: Mesh, n_replicas: int = 3):
     caller and unused here (the fleet tuple's length carries it)."""
     del n_replicas  # shape comes from the states tuple itself
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(GROUP_AXIS), P(), P(), P(), P()),
-             out_specs=(P(GROUP_AXIS), P()), check_rep=False)
+             out_specs=(P(GROUP_AXIS), P()), check_vma=False)
     def _local(states, g, rlo, rhi, valid):
         d = jax.lax.axis_index(GROUP_AXIS)
         gs = states[0].G  # local block: rows per shard

@@ -153,28 +153,34 @@ class PaxosEmulation:
     def run_load_fast(self, n_requests: int, concurrency: int = 512,
                       payload: bytes = b"x", timeout: float = 30.0,
                       client_id: int = 1 << 20,
-                      entry_shift: int = 0) -> Dict:
+                      entry_shift: int = 0,
+                      groups: Optional[List[str]] = None,
+                      capture: bool = False) -> Dict:
         """Windowed pipelined load (ref TESTPaxosClient; see
         testing/loadgen.py) — the measurement path for the throughput
         bench; ``run_load`` below is the per-request-client path used by
         correctness tests.  ``entry_shift`` rotates each group's entry
         node away from its coordinator (shift 1 = next member), forcing
         the per-request forwarding path — the wire-bench uses it to
-        exercise peer-to-peer proposal traffic."""
+        exercise peer-to-peer proposal traffic.  ``groups`` drives that
+        list of names round-robin instead of every group; ``capture``
+        returns each request's times and response payload (see
+        ``run_fast_load``)."""
         from gigapaxos_tpu.testing.loadgen import run_fast_load_sync
         live = sorted(i for i, nd in self.nodes.items() if nd is not None)
         servers = [self.addr_map[i] for i in live]
         # route each group to its initial coordinator if alive
         route = []
         from gigapaxos_tpu.paxos.packets import group_key
-        for g in self.groups:
+        names = self.groups if groups is None else groups
+        for g in names:
             mem = self.members_of(g)
             coord = mem[(group_key(g) + entry_shift) % len(mem)]
             route.append(live.index(coord) if coord in live else 0)
         return run_fast_load_sync(
-            servers, self.groups, n_requests, concurrency=concurrency,
+            servers, names, n_requests, concurrency=concurrency,
             payload=payload, client_id=client_id, timeout=timeout,
-            route=route)
+            route=route, capture=capture)
 
     def run_load(self, n_requests: int, concurrency: int = 64,
                  payload: bytes = b"x", timeout: float = 15.0,

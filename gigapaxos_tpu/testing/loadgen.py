@@ -75,7 +75,7 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
                         concurrency: int = 512, payload: bytes = b"x",
                         client_id: int = 1 << 20, timeout: float = 30.0,
                         route: Optional[Sequence[int]] = None,
-                        burst: int = 64) -> Dict:
+                        burst: int = 64, capture: bool = False) -> Dict:
     """Drive ``n_requests`` round-robin over ``group_names`` with a global
     window of ``concurrency`` outstanding; returns the same stats dict as
     ``PaxosEmulation.run_load``.
@@ -83,6 +83,11 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
     ``route[k]``: server index for group k (default ``gkey % len(servers)``
     = the initial coordinator).  Stragglers are retransmitted (same
     req_id — dedup is server-side) once a second until ``timeout``.
+
+    ``capture`` adds a ``"capture"`` entry to the result: per request
+    (indexed by sequence number) its ``req_id``, send and receive times
+    and the response payload the app returned — what a checker needs to
+    rebuild the acked history (``chaos/invariants.py``).
     """
     client_id = _fresh_client_id(client_id)
     gkeys = np.asarray([pkt.group_key(g) for g in group_names], np.uint64)
@@ -92,6 +97,8 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
     t_send = np.zeros(n_requests, np.float64)
     t_recv = np.full(n_requests, -1.0, np.float64)
     status = np.full(n_requests, -1, np.int16)
+    payloads: List[Optional[bytes]] = [None] * n_requests if capture \
+        else []
     req_base = np.uint64(client_id << 32)
     loop = asyncio.get_running_loop()
 
@@ -125,7 +132,7 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
                  for o in offs])
             now = time.perf_counter()
             if is_resp.any():
-                _s, _gk, req_id, st, _po, _pay = native.parse_requests(
+                _s, _gk, req_id, st, po, pay = native.parse_requests(
                     bytes(buf[:consumed]), offs[is_resp], lens[is_resp])
                 seqs = (req_id & np.uint64(0xFFFFFFFF)).astype(np.int64)
                 ok = (seqs >= 0) & (seqs < n_requests)
@@ -137,6 +144,9 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
                 fresh = t_recv[seqs] < 0
                 t_recv[seqs[fresh]] = now
                 status[seqs[fresh]] = st[ok][fresh]
+                if capture:
+                    for j, sq in zip(ok[fresh], seqs[fresh]):
+                        payloads[sq] = pay[int(po[j]):int(po[j + 1])]
                 k = int(fresh.sum())
                 n_done += k
                 outstanding -= k
@@ -203,7 +213,7 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
     got = (t_recv > 0) & (status == 0)
     lat = (t_recv - t_send)[got]
     errs = int((status > 0).sum() + (t_recv < 0).sum())
-    return {
+    out = {
         "requests": n_requests,
         "ok": int(got.sum()),
         "errors": errs,
@@ -214,6 +224,12 @@ async def run_fast_load(servers: Sequence[Tuple[str, int]],
         "lat_p99_ms": round(1e3 * float(np.percentile(lat, 99)), 2)
         if len(lat) else None,
     }
+    if capture:
+        out["capture"] = {
+            "req_id": req_base | np.arange(n_requests, dtype=np.uint64),
+            "t_send": t_send, "t_recv": t_recv, "status": status,
+            "payload": payloads}
+    return out
 
 
 def run_fast_load_sync(*args, **kw) -> Dict:

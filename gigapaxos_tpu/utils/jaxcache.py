@@ -1,17 +1,18 @@
 """Persistent XLA compilation cache, shared by every entry point.
 
-One-core operational reality: SPMD specializations of the columnar
-kernels take seconds each to compile, and the driver's dryrun, the
-bench, and the test suite all re-compile the same dozen kernels from
-scratch in fresh processes.  JAX's persistent compilation cache
-(``jax_compilation_cache_dir``) keys on (HLO, platform, flags), so a
-repo-local cache directory makes every process after the first hit
-warm compiles — which is the difference between a dryrun that fits the
-driver's budget and one that times out (round-3 ``MULTICHIP_r03.json``
-``rc=124``).
+The columnar kernels at serving capacity take seconds each to compile,
+and the node runtime, the smoke, the bench, the dryrun children and the
+test suite all compile the same dozen kernels in fresh processes.
+JAX's persistent compilation cache keys on (HLO, platform, flags, cache
+path), so one fixed directory makes every process after the first load
+its compiles from disk.
 
-The cache dir lives inside the repo (untracked) so it survives across
-driver rounds on the same machine but never ships in the tree.
+Where the cache lives is decided from OUTSIDE the program: when
+``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it into
+``jax_compilation_cache_dir`` and this module sets no other directory;
+otherwise it is the fixed ``<checkout>/.jax_cache`` (git-ignored).
+Never a temporary, pid- or time-derived path: a directory that moves
+never hits.
 """
 
 from __future__ import annotations
@@ -22,47 +23,33 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
-# once-flag: the cache knobs are PROCESS-GLOBAL jax config.  Every
-# ColumnarBackend construction calls this, and before the guard each
-# one silently re-pointed the global cache dir — clobbering an earlier
-# explicit `dirpath` (or an operator's own jax_compilation_cache_dir)
-# from a completely unrelated backend init.  First caller wins; later
-# calls are no-ops reporting whether a cache is active — holding the
-# ACTIVE dir so a later request for a different one can be refused.
+# the directory this process caches in, once enable_persistent_cache
+# has run (the knobs are PROCESS-GLOBAL jax config; every
+# ColumnarBackend construction calls it, and only the first call
+# touches them)
 _enabled: str | None = None
 
 
-def enable_persistent_cache(dirpath: str | None = None) -> bool:
-    """Point jax at the repo-local compilation cache (idempotent; only
-    the first call in a process touches jax config).  Best-effort: a
-    jax build without the knobs (or an unwritable dir) degrades to
-    normal in-memory caching."""
+def enable_persistent_cache() -> None:
+    """Turn JAX's persistent compilation cache on (idempotent; only the
+    first call in a process touches jax config).  The directory is
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it — then
+    no code sets another — and ``<checkout>/.jax_cache`` otherwise."""
     global _enabled
     if _enabled:
-        if dirpath is not None and dirpath != _enabled:
-            # explicit request for a DIFFERENT dir after the cache is
-            # already active: honoring it would clobber the first
-            # caller's global config — report failure instead of a
-            # silent no-op "success"
-            return False
-        return True
+        return
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          dirpath or CACHE_DIR)
-        # cache everything: the hot kernels are small programs whose
-        # compile time (not size) is what hurts on this host
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _enabled = dirpath or CACHE_DIR
-        # arm the ledger's jax.monitoring listeners now so the very
-        # first compile's cache_hits/cache_misses events are counted
-        from gigapaxos_tpu.utils.engineledger import EngineLedger
-        EngineLedger.install()
-        return True
-    except Exception:
-        return False
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    # cache everything: the hot kernels are small programs whose
+    # compile time (not size) is what hurts
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _enabled = jax.config.jax_compilation_cache_dir
+    # arm the ledger's jax.monitoring listeners now so the very
+    # first compile's cache_hits/cache_misses events are counted
+    from gigapaxos_tpu.utils.engineledger import EngineLedger
+    EngineLedger.install()
 
 
 def cache_metrics() -> dict:

@@ -123,14 +123,25 @@ def test_pallas_accept_multi_lane_rows_and_overflow():
         assert int(st.acc[r, s % W, ACC_RHI]) == 20 + i
 
 
-def test_columnar_backend_pallas_path():
-    """ColumnarBackend with the Pallas accept enabled (interpret on CPU)
-    agrees with the default XLA path through the backend SPI."""
+def test_columnar_backend_pallas_path(monkeypatch):
+    """ColumnarBackend with the Pallas accept enabled agrees with the
+    default XLA path through the backend SPI.  The backend never picks
+    interpret mode by itself (an asked-for kernel builds or raises), so
+    on the CPU the TEST hands it the interpreter."""
+    import functools
+
+    import jax
+
+    from gigapaxos_tpu.ops import pallas_accept
     from gigapaxos_tpu.paxos.backend import ColumnarBackend
     from gigapaxos_tpu.paxos.paxosconfig import PC
     from gigapaxos_tpu.utils.config import Config
 
     Config.set(PC.ENGINE_MESH, "off")  # Mosaic path is single-device
+    if jax.default_backend() == "cpu":
+        monkeypatch.setattr(
+            pallas_accept, "PallasAccept",
+            functools.partial(pallas_accept.PallasAccept, interpret=True))
     G, W, B = 64, 8, 24
     rng = np.random.default_rng(7)
     bks = [ColumnarBackend(G, W, use_pallas_accept=flag)

@@ -229,6 +229,10 @@ def test_off_wire_byte_identical():
                     break
                 captured.extend(data)
             got.set()
+            # py3.12: Server.wait_closed() waits for every accepted
+            # connection, and this raw server owns its side of the one
+            # the Transport opened — close it or the test never ends
+            writer.close()
 
         srv = await asyncio.start_server(handle, "127.0.0.1", 0)
         port = srv.sockets[0].getsockname()[1]
