@@ -20,7 +20,7 @@ the repo's plain references:
   through a ``backend="scalar"`` emulation on the host.
 - ``--mesh`` (four chips, run by hand): only the served stream with the
   slab sharded over every local device, against the same stream with
-  ``ENGINE_MESH="off"`` and the scalar replay (2**16 rows, 62,000 live
+  ``ENGINE_MESH="off"`` and the scalar replay (2**18 rows, 250,000 live
   groups, so that live rows reach all four shards).
 
 Anything that fails raises: there is no fallback and no ``"ok": true``
@@ -46,17 +46,6 @@ import numpy as np
 from gigapaxos_tpu.utils.jaxcache import (cache_metrics,
                                           enable_persistent_cache)
 
-# cold TPU compiles of a dozen kernels land inside the first round; the
-# smoke checks results, not failure detection, so no node may be
-# suspected while a peer's worker sits in the compiler
-_FAILURE_TIMEOUT_S = 120.0
-_REQUEST_TIMEOUT_S = 300.0
-# the deployment under test keeps its live groups RESIDENT: with the
-# default PC.PAUSE_IDLE_S (60 s) a run that outlives a minute starts
-# paging idle groups to the pause table under the stream — an unpause
-# storm (ROADMAP R5's cell, not this smoke's) that also sheds the very
-# app state the replicas are compared on
-_PAUSE_IDLE_S = 0.0
 
 
 def say(phase: str, **facts) -> None:
@@ -284,7 +273,58 @@ def _check_placement(emu, platform: str, mesh: str,
     return facts
 
 
-def _drive(emu, plan, concurrency: int, label: str):
+def _lost_request_report(emu, group: str, rid: int) -> dict:
+    """What every node knows of a request that was never answered: its
+    trace (when ``GP_PC_TRACE_REQUESTS=1``), the host's in-flight and
+    dedupe tables, the group's host mirrors and device row, and the
+    node's counters — enough to tell a request that never arrived from
+    one that was proposed, decided or executed and then dropped."""
+    from gigapaxos_tpu.utils.instrument import RequestInstrumenter
+
+    def plain(v):
+        return v.tolist() if isinstance(v, np.ndarray) else v
+
+    out: dict = {"group": group, "req_id": rid,
+                 "trace": RequestInstrumenter.format(rid)}
+    for i, nd in emu.nodes.items():
+        info = nd.group_info(group) or {}
+        row = info.get("row")
+        fl = nd._proposed.get(rid)
+        facts = {
+            "group": info,
+            "proposed": None if fl is None else {
+                "row": fl.row, "slot": fl.slot, "bal": fl.bal,
+                "age_s": round(time.time() - fl.proposed, 1),
+                "redriven_s_ago": round(time.time() - fl.redriven, 1)},
+            "client_wait": rid in nd._client_wait,
+            "payload": [rid in nd._payloads, rid in nd._payloads_old],
+            "executed": [rid in nd._executed_recent,
+                         rid in nd._executed_old],
+            "app_count": nd.app.count.get(group),
+            "counters": nd.metrics(include_profiler=False)["counters"],
+            "net_drops": nd.transport.metrics()["drops"],
+            "inq": nd._inq.qsize(),
+        }
+        if row is not None:
+            facts["decided_pending"] = {
+                int(s): int(r) for s, r in nd._dec.get(row, {}).items()}
+            facts["election"] = row in nd._elections or nd._mass_has(row)
+            facts["parked"] = len(nd._parked.get(row, ()))
+            facts["barrier"] = row in nd._catchup_barrier
+            facts["in_flight_on_row"] = [
+                (r, f.slot) for r, f in nd._proposed.items()
+                if f.row == row]
+            if hasattr(nd.backend, "snapshot_rows"):
+                with nd._engine_lock:
+                    snap = nd.backend.snapshot_rows([row])[0]
+                facts["device_row"] = {k: plain(v) for k, v in snap.items()
+                                       if k != "members"}
+        out[f"node{i}"] = facts
+    return out
+
+
+def _drive(emu, plan, concurrency: int, label: str,
+           request_timeout: float):
     """Send the plan round by round; every request must be acked.
     Returns (hist, acks, round_s): per group the acked history in
     ``chaos/invariants.py``'s record form, per round the (position,
@@ -295,15 +335,18 @@ def _drive(emu, plan, concurrency: int, label: str):
     for rnd in plan:
         t0 = time.perf_counter()
         res = emu.run_load_fast(len(rnd), concurrency=concurrency,
-                                timeout=_REQUEST_TIMEOUT_S, groups=rnd,
+                                timeout=request_timeout, groups=rnd,
                                 capture=True)
         round_s.append(round(time.perf_counter() - t0, 3))
         cap = res.pop("capture")
-        bad = [(rnd[k], int(cap["status"][k]))
-               for k in np.flatnonzero(cap["status"] != 0)[:5]]
+        bad = np.flatnonzero(cap["status"] != 0)[:5]
+        for k in bad:
+            say("lost-request", engine=label, status=int(cap["status"][k]),
+                **_lost_request_report(emu, rnd[k], int(cap["req_id"][k])))
         assert res["ok"] == len(rnd) and not res["errors"], \
             f"{label}: requests lost (status -1) or refused: {res}, " \
-            f"first (group, status): {bad}"
+            f"first (group, status): " \
+            f"{[(rnd[k], int(cap['status'][k])) for k in bad]}"
         row = []
         for k, g in enumerate(rnd):
             body = json.loads(cap["payload"][k])
@@ -315,6 +358,30 @@ def _drive(emu, plan, concurrency: int, label: str):
     return hist, acks, round_s
 
 
+def _replica_state(nd, groups):
+    """(counts, digests) of ``groups`` on one replica, wherever it keeps
+    them: resident in the app, or — a group the deactivator paged out
+    as idle (``PC.PAUSE_IDLE_S``) — in its durable pause record, which
+    is what the next request to it is served from."""
+    import base64
+
+    from gigapaxos_tpu.paxos.packets import group_key
+    counts, digests = {}, {}
+    with nd._engine_lock:  # no pause or unpause under the reading
+        for g in groups:
+            if g in nd.app.count:
+                counts[g], digests[g] = nd.app.count[g], nd.app.digest[g]
+            elif group_key(g) in nd._paused:
+                st = json.loads(base64.b64decode(json.loads(
+                    nd.logger.peek_pause(group_key(g)))["app"]))
+                if st["count"]:
+                    counts[g], digests[g] = st["count"], st["digest"]
+        spurious = [g for g, c in nd.app.count.items()
+                    if c and g not in groups]
+    assert not spurious, f"writes nobody sent: {spurious[:5]}"
+    return counts, digests
+
+
 def _check_guarantees(emu, hist, label: str):
     """Every acked write on all replicas, one order per group, digests
     converged (``chaos/invariants.py``).  Followers execute behind the
@@ -324,13 +391,23 @@ def _check_guarantees(emu, hist, label: str):
     want = {g: len(recs) for g, recs in hist.items()}
     deadline = time.monotonic() + 120.0
     while True:
-        counts = {i: dict(nd.app.count) for i, nd in emu.nodes.items()}
-        if all(c == want for c in counts.values()):
+        state = {i: _replica_state(nd, want)
+                 for i, nd in emu.nodes.items()}
+        # a group may answer every request and still be wedged for the
+        # next one (a decided slot that can never execute): no node may
+        # keep a decision it has not executed or a proposal in flight
+        stuck = {i: (sum(s >= nd._cur[row] for row, d in nd._dec.items()
+                         for s in d), len(nd._proposed))
+                 for i, nd in emu.nodes.items()}
+        if all(c == want for c, _d in state.values()) and \
+                not any(any(v) for v in stuck.values()):
             break
         assert time.monotonic() < deadline, \
-            f"{label}: replicas did not converge on the acked writes"
+            f"{label}: replicas did not converge on the acked writes, " \
+            f"or kept (unexecuted decisions, proposals in flight): {stuck}"
         time.sleep(0.1)
-    digests = {i: dict(nd.app.digest) for i, nd in emu.nodes.items()}
+    counts = {i: c for i, (c, _d) in state.items()}
+    digests = {i: d for i, (_c, d) in state.items()}
     errs = inv.no_lost_acks(hist, counts) + inv.digests_converged(digests)
     for g, recs in hist.items():
         errs += [f"group {g}: {e}" for e in inv.check_single_order(recs)]
@@ -344,6 +421,7 @@ def _check_guarantees(emu, hist, label: str):
 
 def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
                window: int, concurrency: int, mesh: str = "auto",
+               request_timeout: float = 300.0,
                platform: str | None = None,
                spread_over: int | None = None,
                only_touched_groups: bool = False) -> dict:
@@ -365,9 +443,8 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
     # time, so their dedup caches cannot see a reused id)
     loadgen._next_client = None
     label = backend if backend != "columnar" else f"columnar/mesh={mesh}"
-    prior = {k: Config.get(k) for k in (PC.ENGINE_MESH, PC.PAUSE_IDLE_S)}
+    prior_mesh = Config.get(PC.ENGINE_MESH)
     Config.set(PC.ENGINE_MESH, mesh)
-    Config.set(PC.PAUSE_IDLE_S, _PAUSE_IDLE_S)
     logdir = tempfile.mkdtemp(prefix="gp_chip_smoke_")
     emu = None
     try:
@@ -376,8 +453,7 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
         emu = PaxosEmulation(logdir, n_nodes=3, n_groups=0,
                              backend=backend, app_cls=CounterApp,
                              capacity=capacity, window=window,
-                             sync_wal=True,
-                             failure_timeout_s=_FAILURE_TIMEOUT_S)
+                             sync_wal=True)
         t_boot = time.perf_counter() - t0
         t0 = time.perf_counter()
         if only_touched_groups:
@@ -390,7 +466,8 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
             _check_placement(emu, platform, mesh, spread_over)
 
         sub0 = submits()
-        hist, acks, round_s = _drive(emu, plan, concurrency, label)
+        hist, acks, round_s = _drive(emu, plan, concurrency, label,
+                                     request_timeout)
         sub1 = submits()
         counts, digests = _check_guarantees(emu, hist, label)
 
@@ -399,8 +476,8 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
         facts = {
             "engine": label, "nodes": 3, "capacity": capacity,
             "window": window, "sync_wal": bool(Config.get(PC.SYNC_WAL)),
-            "pause_idle_s": _PAUSE_IDLE_S,
-            "failure_timeout_s": _FAILURE_TIMEOUT_S,
+            "pause_idle_s": Config.get(PC.PAUSE_IDLE_S),
+            "failure_timeout_s": Config.get(PC.FAILURE_TIMEOUT_S),
             "groups_created": len(emu.groups),
             "requests": sum(len(r) for r in plan),
             "acked": sum(len(r) for r in acks),
@@ -414,6 +491,15 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
             "lanes_per_dispatch": round(items / calls, 1) if calls else None,
             "platforms": [nd.backend.engine_platform
                           for nd in emu.nodes.values()],
+            # what the nodes did beside serving: coordinator installs
+            # and ballot changes (a peer suspected while it sat in the
+            # compiler), accept re-drives, copies of a request read in
+            # one wave (retransmits piled up behind a stall), groups
+            # paged out as idle
+            **{k: [nd.metrics(include_profiler=False)["counters"][k]
+                   for nd in emu.nodes.values()]
+               for k in ("installs", "ballot_changes", "redriven",
+                         "wave_dups", "paused", "unpaused")},
             **placement,
             **{k: led1[k] - led0[k] for k in led1},
             "peak_bytes_in_use": _peak_bytes(),
@@ -424,8 +510,7 @@ def run_stream(backend: str, plan, *, n_groups: int, capacity: int,
     finally:
         if emu is not None:
             emu.stop()
-        for k, v in prior.items():
-            Config.set(k, v)
+        Config.set(PC.ENGINE_MESH, prior_mesh)
         shutil.rmtree(logdir, ignore_errors=True)
 
 
@@ -440,7 +525,8 @@ def assert_same_results(a: dict, b: dict, what: str) -> None:
 
 def served_phase(*, n_groups: int, capacity: int, window: int,
                  n_active: int, rounds: int, concurrency: int, seed: int,
-                 platform: str, mesh_ab: bool = False) -> dict:
+                 platform: str, mesh_ab: bool = False,
+                 request_timeout: float = 300.0) -> dict:
     """The served path as a node with no configuration builds it — on
     the default device, ``ENGINE_MESH="auto"`` (a mesh over every local
     device when there are several) — against the scalar replay.
@@ -450,7 +536,7 @@ def served_phase(*, n_groups: int, capacity: int, window: int,
     import jax
     plan = make_plan(seed, n_groups, n_active, rounds)
     common = dict(n_groups=n_groups, capacity=capacity, window=window,
-                  concurrency=concurrency)
+                  concurrency=concurrency, request_timeout=request_timeout)
     col = run_stream(
         "columnar", plan, mesh="auto", platform=platform,
         spread_over=len(jax.local_devices()) if mesh_ab else None,
@@ -491,15 +577,18 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("--window", type=int, default=16)
     p.add_argument("--groups", type=int, default=None,
                    help="live groups per node (default 100000; --mesh "
-                        "62000 so that live rows reach every shard)")
+                        "250000 so that live rows reach every shard)")
     p.add_argument("--capacity", type=int, default=None,
-                   help="slab rows (default 2**20; --mesh 2**16)")
+                   help="slab rows (default 2**20; --mesh 2**18)")
     p.add_argument("--active", type=int, default=1000,
                    help="groups named in each round of the stream")
     p.add_argument("--rounds", type=int, default=5,
                    help="rounds of --active requests (the first also "
                         "absorbs the cold compiles)")
     p.add_argument("--concurrency", type=int, default=256)
+    p.add_argument("--request-timeout", type=float, default=300.0,
+                   help="seconds of once-a-second retransmits after "
+                        "which an unanswered request counts as lost")
     return p
 
 
@@ -530,13 +619,11 @@ def main(argv=None) -> int:
 
     t_all = time.perf_counter()
     if args.mesh:
-        groups, capacity = args.groups or 62_000, args.capacity or 1 << 16
+        groups, capacity = args.groups or 250_000, args.capacity or 1 << 18
         say("cuts", live_groups=groups, slab_rows=capacity, note=(
             "rows are handed out from 0 up, so live rows reach all four "
-            "shards only in a slab they nearly fill: 62K of 2^16 rows "
-            "here, where 2^20 rows would need >786K creates per node; at "
-            "250K of 2^18 rows a cold first round lost one request on "
-            "four chips, mesh on or off (PERF.md, open questions)"))
+            "shards only in a slab they nearly fill: 250K of 2^18 rows "
+            "here, where 2^20 rows would need >786K creates per node"))
     else:
         t0 = time.perf_counter()
         storm_phase(args.storm_groups, args.window, args.storm_batch,
@@ -550,7 +637,8 @@ def main(argv=None) -> int:
     served_phase(n_groups=groups, capacity=capacity, window=args.window,
                  n_active=args.active, rounds=args.rounds,
                  concurrency=args.concurrency, seed=args.seed,
-                 platform="tpu", mesh_ab=args.mesh)
+                 platform="tpu", mesh_ab=args.mesh,
+                 request_timeout=args.request_timeout)
     say("served-wall", seconds=round(time.perf_counter() - t0, 1))
     say("done", total_wall_s=round(time.perf_counter() - t_all, 1),
         peak_bytes_in_use=_peak_bytes(), **_ledger())

@@ -140,7 +140,8 @@ def project_decls() -> Decls:
             guarded={c: "_stat_lock" for c in (
                 "n_executed", "n_decided", "n_paused", "n_unpaused",
                 "n_redriven", "n_parked", "n_park_dropped",
-                "n_redrive_capped", "n_installs", "n_ballot_changes",
+                "n_redrive_capped", "n_wave_dups", "n_installs",
+                "n_ballot_changes",
                 "n_shed", "n_shed_disk", "n_wal_nacked",
                 "_degraded_seen")},
         ),

@@ -205,9 +205,9 @@ def group_lanes_by_block(rows: np.ndarray, L: int
 class PallasAccept:
     """Drives the fused kernel; pads R to power-of-two buckets.
 
-    ``interpret=True`` runs the Pallas interpreter (CPU tests); real-TPU
-    callers probe one compile at init and fall back to the XLA scatter
-    path if Mosaic rejects the shapes.
+    ``interpret=True`` runs the Pallas interpreter (CPU tests) and is
+    only ever handed in, never chosen here; ``ColumnarBackend`` probes
+    one compile at init and raises if the kernel does not build.
     """
 
     def __init__(self, L: int = 16, interpret: bool = False):

@@ -615,6 +615,7 @@ class PaxosNode:
         self.n_parked = 0         # proposals parked awaiting leadership
         self.n_park_dropped = 0   # parked proposals dropped at cap
         self.n_redrive_capped = 0  # re-drive ticks that hit the 256 cap
+        self.n_wave_dups = 0      # copies of a request within one wave
         self.n_installs = 0       # coordinator installs won (failover)
         self.n_shed_disk = 0      # proposals shed status 5 (WAL impaired)
         self.n_wal_nacked = 0     # accepts nacked because WAL failed
@@ -2510,6 +2511,7 @@ class PaxosNode:
                 "unpaused": self.n_unpaused,
                 "redriven": self.n_redriven,
                 "redrive_capped": self.n_redrive_capped,
+                "wave_dups": self.n_wave_dups,
                 "parked": self.n_parked,
                 "park_dropped": self.n_park_dropped,
                 "shed": self.n_shed,
@@ -2920,6 +2922,15 @@ class PaxosNode:
         pay_parts: List[bytes] = []
         now = self._now()
         ex, exo = self._executed_recent, self._executed_old
+        # req_ids given a lane in THIS wave.  The _proposed dedupe below
+        # only knows earlier waves (entries are registered in _req_post):
+        # when the worker stalls past the clients' retransmit interval
+        # (a cold compile, a long GC), a request and its retransmits
+        # arrive in ONE wave, and without this set each copy took a slot
+        # of its own — the first execution consumed the payload and the
+        # later slots wedged the group for good
+        seen: set = set()
+        n_dups = 0
         # ---- vectorized client batches (the hot path: one _ReqSoA per
         # wire read; per-lane Python is 3-4 dict ops) ----
         for sb in soas:
@@ -2980,6 +2991,10 @@ class PaxosNode:
                     continue
                 self._client_wait[rid] = (int(snd[i]), now,
                                           int(sb.gkey[i]))
+                if rid in seen:
+                    n_dups += 1  # a copy already has its lane
+                    continue
+                seen.add(rid)
                 keep.append(i)
             if keep:
                 ka = np.asarray(keep, np.int64)
@@ -3045,6 +3060,10 @@ class PaxosNode:
                     self.id, o.gkey, o.req_id, o.sender, o.flags,
                     o.payload))
                 continue
+            if o.req_id in seen:
+                n_dups += 1
+                continue
+            seen.add(o.req_id)
             lanes.append((meta.row, o.req_id, o.flags, o.payload, o.sender))
         for o in props:
             meta = self._lookup(o.gkey)
@@ -3114,7 +3133,17 @@ class PaxosNode:
             if meta.row in self._catchup_barrier:
                 self._park(meta.row, o)
                 continue
+            if o.req_id in seen:
+                # the waiter is the entry replica either way
+                self._client_wait[o.req_id] = (o.entry, self._now(),
+                                               o.gkey)
+                n_dups += 1
+                continue
+            seen.add(o.req_id)
             lanes.append((meta.row, o.req_id, o.flags, o.payload, o.entry))
+        if n_dups:
+            with self._stat_lock:
+                self.n_wave_dups += n_dups
         if lanes:
             rows_parts.append(np.asarray([l[0] for l in lanes], np.int32))
             req_parts.append(np.asarray([l[1] for l in lanes], np.uint64))

@@ -218,7 +218,10 @@ def throughput_multiproc(args) -> dict:
     A chip belongs to one process, so N ``columnar`` children cannot
     all own it: that combination is refused unless the children are
     pinned to host XLA from outside (``JAX_PLATFORMS=cpu``), instead of
-    letting all but the first child die on boot."""
+    letting all but the first child die on boot.  The parent stays off
+    JAX (loading it would take the chip from the children), so it can
+    only read the variable, not look for a chip: the variable is
+    required on a host without an accelerator too."""
     import os
     import socket
     import subprocess
@@ -236,7 +239,8 @@ def throughput_multiproc(args) -> dict:
             "accelerator, and a chip belongs to one process: run the "
             "in-process harness (no --multiproc), one server process "
             "per chip, or set JAX_PLATFORMS=cpu to keep every child on "
-            "host XLA")
+            "host XLA (required on a CPU-only host too: this parent "
+            "stays off JAX and cannot look for a chip itself)")
     repo = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     ports = free_ports(args.nodes)

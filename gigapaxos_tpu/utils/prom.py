@@ -119,6 +119,8 @@ def render_prometheus(m: dict, prefix: str = "gp") -> str:
             ("unpaused", "groups unpaused on demand"),
             ("redriven", "accept re-drives (lost-Accept recovery)"),
             ("redrive_capped", "re-drive ticks that hit the cap"),
+            ("wave_dups", "copies of a request swallowed within one "
+             "wave (retransmits read together after a stall)"),
             ("parked", "proposals parked awaiting leadership"),
             ("park_dropped", "parked proposals dropped at cap"),
             ("shed", "requests answered retry by the backlog guard"),

@@ -56,10 +56,7 @@ def _fmt_ms(v, why=""):
     return f"n/a ({why})" if why else "n/a"
 
 
-# config 2's row key: what bench.py writes now, then the tracked
-# artifact's older name
-_CONFIG2_KEYS = ("config2_columnar_100k_groups_knee",
-                 "config2_columnar_100k_groups_host_xla_knee")
+_CONFIG2_KEY = "config2_columnar_100k_groups_knee"
 
 
 def render() -> str:
@@ -111,7 +108,7 @@ def render() -> str:
     # (histogram p50/p99 per update_delay tag) — one artifact carries
     # both the budget split and the tails, no re-run needed
     prof = None
-    for key in ("config1_e2e_3r_1k_groups",) + _CONFIG2_KEYS:
+    for key in ("config1_e2e_3r_1k_groups", _CONFIG2_KEY):
         cand = row(key)
         if cand and isinstance(cand["info"].get("profiler"), dict):
             prof = (key, cand["info"]["profiler"])
@@ -169,7 +166,7 @@ def render() -> str:
                     "`w.process@<k>` wall s / items) | "
                     f"{cells} — max/min skew {skew:.2f}x |")
 
-    r = row(_CONFIG2_KEYS[0]) or row(_CONFIG2_KEYS[1])
+    r = row(_CONFIG2_KEY)
     if r:
         i = r["info"]
         where = (i.get("engine") or {}).get("platform") or "cpu"
