@@ -401,6 +401,8 @@ def bench_latency(n_requests: int = 800, groups: int = 64,
         asyncio.run(body())
 
         stage_keys = ("decode", "engine", "wal", "emit")
+        span_kind = {"decode": "w.decode", "engine": "w.process",
+                     "wal": "wal", "emit": "w.emit"}
         cols = {k: [] for k in stage_keys + ("queue", "client")}
         for total, rid, gi in samples:
             spans = RequestInstrumenter.request_spans(rid)
@@ -416,7 +418,7 @@ def bench_latency(n_requests: int = 800, groups: int = 64,
                         (s["t1"] - s["t0"])
             attributed = 0.0
             for k in stage_keys:
-                v = float(bd.get(k, 0.0))
+                v = float(bd.get(span_kind[k], 0.0))
                 cols[k].append(v)
                 attributed += v
             cols["queue"].append(max(0.0, total - attributed))

@@ -190,7 +190,8 @@ def project_decls() -> Decls:
             guarded={a: "_lock" for a in
                      ("_ring", "_spans", "_open", "_slow",
                       "n_span_begun", "n_span_ended",
-                      "n_span_orphaned", "_last_evict")},
+                      "n_span_orphaned", "n_span_dropped",
+                      "_last_evict")},
         ),
         "ChaosPlane": ThreadedClass(
             locks=frozenset({"_lock"}),
@@ -266,6 +267,13 @@ def project_decls() -> Decls:
         "RequestInstrumenter.record": HotPath(
             "gate_first", gates=("enabled",)),
         "RequestInstrumenter.span_begin": HotPath(
+            "gate_first", gates=("enabled",)),
+        # the stage-span primitive at every worker/engine/WAL boundary:
+        # off, the sums and this one gate (the operator's switch, then
+        # the profiler's own is_enabled)
+        "span.__enter__": HotPath(
+            "gate_first", gates=("enabled",)),
+        "span.traced": HotPath(
             "gate_first", gates=("enabled",)),
         "RequestInstrumenter.note_done": HotPath(
             "gate_first", gates=("enabled",)),

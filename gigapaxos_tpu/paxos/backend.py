@@ -31,7 +31,8 @@ import numpy as np
 from gigapaxos_tpu.ops.oracle import OracleGroup, PValue, make_oracle_group
 from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT
 from gigapaxos_tpu.utils.engineledger import EngineLedger
-from gigapaxos_tpu.utils.instrument import RequestInstrumenter
+from gigapaxos_tpu.utils.instrument import (RequestInstrumenter, span,
+                                            traced)
 from gigapaxos_tpu.utils.profiler import DelayProfiler
 
 
@@ -545,8 +546,7 @@ class EngineWave:
         self._wave = RequestInstrumenter.current_wave()
 
     def collect(self):
-        t0 = time.monotonic()
-        overlap = t0 - self._submitted
+        overlap = time.monotonic() - self._submitted
         DelayProfiler.add_total("eng.overlap", overlap, self._n)
         if self._sfx:
             DelayProfiler.add_total("eng.overlap" + self._sfx, overlap,
@@ -554,20 +554,17 @@ class EngineWave:
         # span duration = host blocked materializing; overlap_s attr =
         # the device-ran-while-host-worked gap — the device-vs-host
         # split of the wave, queryable per request
-        sp = RequestInstrumenter.span_begin(
-            "eng.collect", wave=self._wave, lanes=self._n,
-            overlap_s=round(overlap, 6))
-        res = self._finish()
-        RequestInstrumenter.span_end(sp)
-        DelayProfiler.update_total("eng.collect", t0, self._n)
+        with span("eng.collect", n=self._n, wave=self._wave,
+                  lanes=self._n, overlap_s=round(overlap, 6)) as sp:
+            res = self._finish()
         # full wave wall (submit->materialized) as a histogram, per
         # shard when this slab is one lane of a sharded engine — the
         # per-shard wave-time distribution the flight deck renders
         DelayProfiler.update_delay("eng.wave" + self._sfx,
                                    self._submitted)
         if self._sfx:
-            DelayProfiler.update_total("eng.collect" + self._sfx, t0,
-                                       self._n)
+            DelayProfiler.add_total("eng.collect" + self._sfx,
+                                    sp.t1 - sp.t0, self._n)
         return res
 
 
@@ -622,6 +619,7 @@ class ColumnarBackend(AcceptorBackend):
         enable_persistent_cache()
         self._jax = jax
         self._k = kernels
+        self._kpfx = ""  # + a table entry's name = its ledger name
         self.state = make_state(capacity, window)
         self._window = window
         self.capacity = capacity
@@ -660,6 +658,7 @@ class ColumnarBackend(AcceptorBackend):
             # that keeps the wave shard-local — no cross-device gather
             # on the hot path
             self._k = mesh_kernels(self._mesh)
+            self._kpfx = "mesh."
             self.engine_mesh = int(self._mesh.size)
             pallas_ok = False  # Mosaic path is single-device
         self.engine_platform = (
@@ -763,14 +762,15 @@ class ColumnarBackend(AcceptorBackend):
         dispatch is asynchronous, so a wave deep enough to wrap any
         fixed-depth ring would overwrite an in-flight chunk's input."""
         b = bucket or _bucket(n)
-        out = np.empty((len(cols) + 1, b), np.int32)
-        for i, (col, fill) in enumerate(cols):
-            row = out[i]
-            row[:n] = col
-            row[n:] = fill
-        out[len(cols), :n] = 1  # valid mask
-        out[len(cols), n:] = 0
-        return self._dev(out)
+        with traced("eng.pack", n=n, bytes=4 * (len(cols) + 1) * b):
+            out = np.empty((len(cols) + 1, b), np.int32)
+            for i, (col, fill) in enumerate(cols):
+                row = out[i]
+                row[:n] = col
+                row[n:] = fill
+            out[len(cols), :n] = 1  # valid mask
+            out[len(cols), n:] = 0
+            return self._dev(out)
 
     def _disp(self):
         """Dispatch guard: the process-wide one-sharded-launch-at-a-
@@ -779,30 +779,56 @@ class ColumnarBackend(AcceptorBackend):
             return _MESH_DISPATCH_LOCK
         return contextlib.nullcontext()
 
-    def _submit1(self, kern, n, cols) -> List[Tuple[object, int]]:
-        """Launch a packed kernel over <=``_BUCKET_CAP``-lane chunks
+    def _submit_span(self, name: str, n: int, chunks, inputs: int = 1):
+        """The span of one submit: the ``eng.submit`` total and, while
+        spans are on, ``gp.eng.submit`` naming its kernel and bucket.
+        Beside it, always on, ``eng.lanes_dispatched`` takes the padded
+        lanes launched (:meth:`_submit_done` adds the valid ones per
+        kernel)."""
+        launched = inputs * sum(_bucket(b - a) for a, b in chunks)
+        DelayProfiler.add_total("eng.lanes_dispatched", 0.0, launched,
+                                calls=len(chunks))
+        return span("eng.submit", n=n, kernel=self._kpfx + name, lanes=n,
+                    bucket=_bucket(chunks[0][1] - chunks[0][0]),
+                    chunks=len(chunks), launched=launched)
+
+    def _submit_done(self, name: str, sp, n: int, chunks: int) -> None:
+        """``eng.k.<kernel>``: launch wall, a call per chunk, the valid
+        lanes (and the lane's own ``eng.submit@<shard>``)."""
+        dt = sp.t1 - sp.t0
+        DelayProfiler.add_total("eng.k." + self._kpfx + name, dt, n,
+                                calls=chunks)
+        if self._sfx:
+            DelayProfiler.add_total("eng.submit" + self._sfx, dt, n)
+
+    def _collect_now(self, outs, n: int) -> np.ndarray:
+        """Collect right behind the submit (the ops with no host work
+        to overlap): the same ``gp.eng.collect`` span as
+        :meth:`EngineWave.collect`, but no sum, so that ``eng.collect``
+        counts what it always counted."""
+        with traced("eng.collect", n=n, lanes=n):
+            return _collect_cols(outs)
+
+    def _submit1(self, name, n, cols) -> List[Tuple[object, int]]:
+        """Launch the packed kernel ``name`` (of the kernel table
+        ``self._k``) over <=``_BUCKET_CAP``-lane chunks
         (the bucket-ladder clamp) and start every chunk output's async
         device->host copy; returns the chunk list for _collect_cols.
         Chunks apply sequentially, which is a per-chunk linearization —
         safe for paxos exactly like the batch linearization (kernels.py
         determinism note), and what the scalar engines do per item."""
-        t0 = time.monotonic()
-        sp = RequestInstrumenter.span_begin("eng.submit", lanes=n,
-                                            bucket=_bucket(min(
-                                                n, _BUCKET_CAP)))
-        cols = [(np.asarray(c), f) for c, f in cols]
-        outs = []
-        for a, bnd in _chunks(n):
-            m = bnd - a
-            with self._disp():
-                self.state, o = kern(self.state, self._packed(
-                    m, *[(c[a:bnd], f) for c, f in cols]))
-            _d2h_start(o)
-            outs.append((o, m))
-        RequestInstrumenter.span_end(sp, chunks=len(outs))
-        DelayProfiler.update_total("eng.submit", t0, n)
-        if self._sfx:
-            DelayProfiler.update_total("eng.submit" + self._sfx, t0, n)
+        kern, chunks = getattr(self._k, name), _chunks(n)
+        with self._submit_span(name, n, chunks) as sp:
+            cols = [(np.asarray(c), f) for c, f in cols]
+            outs = []
+            for a, bnd in chunks:
+                m = bnd - a
+                with self._disp():
+                    self.state, o = kern(self.state, self._packed(
+                        m, *[(c[a:bnd], f) for c, f in cols]))
+                _d2h_start(o)
+                outs.append((o, m))
+        self._submit_done(name, sp, n, len(chunks))
         return outs
 
     # -- ops ---------------------------------------------------------------
@@ -844,7 +870,7 @@ class ColumnarBackend(AcceptorBackend):
                 lo, hi, np.ones(n, bool))
             res = AcceptRes(acked, stale, ow, cur_bal)
             return EngineWave(lambda: res, n, self._sfx)
-        outs = self._submit1(self._k.accept_p, n, [
+        outs = self._submit1("accept_p", n, [
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT), (lo, 0),
             (hi, 0)])
 
@@ -860,7 +886,7 @@ class ColumnarBackend(AcceptorBackend):
     def accept_reply_submit(self, rows, slots, bals, senders, acked
                             ) -> EngineWave:
         n = len(rows)
-        outs = self._submit1(self._k.accept_reply_p, n, [
+        outs = self._submit1("accept_reply_p", n, [
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
             (senders, 0), (np.asarray(acked, np.int32), 0)])
 
@@ -882,9 +908,9 @@ class ColumnarBackend(AcceptorBackend):
     def propose(self, rows, req_ids) -> ProposeRes:
         n = len(rows)
         lo, hi = _split64(req_ids)
-        outs = self._submit1(self._k.propose_p, n, [
+        outs = self._submit1("propose_p", n, [
             (rows, 0), (lo, 0), (hi, 0)])
-        out = _collect_cols(outs)
+        out = self._collect_now(outs, n)
         granted = out[0] != 0
         return ProposeRes(granted, out[1] != 0, out[2] != 0,
                           np.where(granted, out[3], NO_SLOT), out[4])
@@ -892,7 +918,7 @@ class ColumnarBackend(AcceptorBackend):
     def commit_submit(self, rows, slots, req_ids) -> EngineWave:
         n = len(rows)
         lo, hi = _split64(req_ids)
-        outs = self._submit1(self._k.commit_p, n, [
+        outs = self._submit1("commit_p", n, [
             (rows, 0), (slots, NO_SLOT), (lo, 0), (hi, 0)])
 
         def finish():
@@ -904,38 +930,33 @@ class ColumnarBackend(AcceptorBackend):
     def commit(self, rows, slots, req_ids) -> CommitRes:
         return self.commit_submit(rows, slots, req_ids).collect()
 
-    def _submit2(self, kern, n1, cols1, n2, cols2):
+    def _submit2(self, name, n1, cols1, n2, cols2):
         """Dual-input fused dispatch, chunked like :meth:`_submit1`
         with BOTH inputs sharing one bucket per chunk (bounds the
         composed kernel's jit cache to the ladder, not its square)."""
-        t0 = time.monotonic()
-        sp = RequestInstrumenter.span_begin("eng.submit",
-                                            lanes=n1 + n2, fused=True)
-        cols1 = [(np.asarray(c), f) for c, f in cols1]
-        cols2 = [(np.asarray(c), f) for c, f in cols2]
-        outs1, outs2 = [], []
-        for a, bnd in _chunks(max(n1, n2)):
-            a1, b1 = min(a, n1), min(bnd, n1)
-            a2, b2 = min(a, n2), min(bnd, n2)
-            b = _bucket(max(b1 - a1, b2 - a2))
-            with self._disp():
-                self.state, o1, o2 = kern(
-                    self.state,
-                    self._packed(b1 - a1,
-                                 *[(c[a1:b1], f) for c, f in cols1],
-                                 bucket=b),
-                    self._packed(b2 - a2,
-                                 *[(c[a2:b2], f) for c, f in cols2],
-                                 bucket=b))
-            _d2h_start(o1)
-            _d2h_start(o2)
-            outs1.append((o1, b1 - a1))
-            outs2.append((o2, b2 - a2))
-        RequestInstrumenter.span_end(sp, chunks=len(outs1))
-        DelayProfiler.update_total("eng.submit", t0, n1 + n2)
-        if self._sfx:
-            DelayProfiler.update_total("eng.submit" + self._sfx, t0,
-                                       n1 + n2)
+        kern, chunks = getattr(self._k, name), _chunks(max(n1, n2))
+        with self._submit_span(name, n1 + n2, chunks, inputs=2) as sp:
+            cols1 = [(np.asarray(c), f) for c, f in cols1]
+            cols2 = [(np.asarray(c), f) for c, f in cols2]
+            outs1, outs2 = [], []
+            for a, bnd in chunks:
+                a1, b1 = min(a, n1), min(bnd, n1)
+                a2, b2 = min(a, n2), min(bnd, n2)
+                b = _bucket(max(b1 - a1, b2 - a2))
+                with self._disp():
+                    self.state, o1, o2 = kern(
+                        self.state,
+                        self._packed(b1 - a1,
+                                     *[(c[a1:b1], f) for c, f in cols1],
+                                     bucket=b),
+                        self._packed(b2 - a2,
+                                     *[(c[a2:b2], f) for c, f in cols2],
+                                     bucket=b))
+                _d2h_start(o1)
+                _d2h_start(o2)
+                outs1.append((o1, b1 - a1))
+                outs2.append((o2, b2 - a2))
+        self._submit_done(name, sp, n1 + n2, len(chunks))
         return outs1, outs2
 
     def accept_commit_submit(self, rows_a, slots_a, bals_a, reqs_a,
@@ -955,7 +976,7 @@ class ColumnarBackend(AcceptorBackend):
         lo_a, hi_a = _split64(reqs_a)
         lo_c, hi_c = _split64(reqs_c)
         outs_a, outs_c = self._submit2(
-            self._k.accept_commit_p,
+            "accept_commit_p",
             na, [(rows_a, 0), (slots_a, NO_SLOT), (bals_a, NO_BALLOT),
                  (lo_a, 0), (hi_a, 0)],
             nc, [(rows_c, 0), (slots_c, NO_SLOT), (lo_c, 0),
@@ -985,10 +1006,10 @@ class ColumnarBackend(AcceptorBackend):
         (execution is re-derived host-side from the decision dict, so
         the device cursor is not surfaced)."""
         n = len(rows)
-        outs = self._submit1(self._k.accept_reply_commit_self_p, n, [
+        outs = self._submit1("accept_reply_commit_self_p", n, [
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
             (senders, 0), (np.asarray(acked, np.int32), 0)])
-        out = _collect_cols(outs)
+        out = self._collect_now(outs, n)
         newly = out[0] != 0
         res = AcceptReplyRes(
             newly, out[1] != 0, np.where(newly, out[3], 0),
@@ -1004,9 +1025,9 @@ class ColumnarBackend(AcceptorBackend):
         self-wave's nack reply used to carry."""
         n = len(rows)
         lo, hi = _split64(req_ids)
-        outs = self._submit1(self._k.propose_accept_self_p, n, [
+        outs = self._submit1("propose_accept_self_p", n, [
             (rows, 0), (lo, 0), (hi, 0), (self_midx, 0)])
-        out = _collect_cols(outs)
+        out = self._collect_now(outs, n)
         granted = out[0] != 0
         pr = ProposeRes(granted, out[1] != 0, out[2] != 0,
                         np.where(granted, out[3], NO_SLOT), out[4])
@@ -1023,7 +1044,7 @@ class ColumnarBackend(AcceptorBackend):
         np_, nr = len(rows_p), len(rows_r)
         lo_p, hi_p = _split64(reqs_p)
         outs_p, outs_r = self._submit2(
-            self._k.request_reply_p,
+            "request_reply_p",
             np_, [(rows_p, 0), (lo_p, 0), (hi_p, 0), (self_midx, 0)],
             nr, [(rows_r, 0), (slots_r, NO_SLOT), (bals_r, NO_BALLOT),
                  (senders_r, 0), (np.asarray(acked_r, np.int32), 0)])
@@ -1238,7 +1259,7 @@ class ColumnarBackend(AcceptorBackend):
         def z(rows_):
             return self._dev(np.zeros((rows_, b), np.int32))
 
-        prefix = "mesh." if self._mesh is not None else ""
+        prefix = self._kpfx
         sweep = [("propose_p", (z(4),)), ("accept_p", (z(6),)),
                  ("accept_reply_p", (z(6),)), ("commit_p", (z(5),)),
                  ("accept_commit_p", (z(6), z(5))),

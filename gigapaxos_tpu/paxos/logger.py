@@ -78,7 +78,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 from gigapaxos_tpu.chaos.faults import StorageChaos
 from gigapaxos_tpu.utils.logutil import get_logger
-from gigapaxos_tpu.utils.instrument import RequestInstrumenter
+from gigapaxos_tpu.utils.instrument import RequestInstrumenter, traced
 from gigapaxos_tpu.utils.profiler import DelayProfiler
 
 log = get_logger("gp.logger")
@@ -413,16 +413,13 @@ class PaxosLogger:
             raise RuntimeError("logger closed")
         t0 = time.monotonic()
         # hot-path WAL logging runs on the worker's engine stage, so
-        # this span carries that batch's wave id — the "WAL fsync"
-        # slice of a traced request's decomposition
-        sp = RequestInstrumenter.span_begin("wal", entries=n_entries,
-                                            seg=seg)
-        try:
-            with self._wal_locks[seg]:
-                off, over = self._append_locked(
-                    seg, buf, self.sync if fsync is None else fsync)
-        finally:
-            RequestInstrumenter.span_end(sp)
+        # this span carries that batch's wave id — the "WAL" slice of
+        # a traced request's decomposition: segment lock, append and
+        # the sync (which is gp.wal.fsync, inside)
+        with traced("wal", n=n_entries, entries=n_entries, seg=seg,
+                    bytes=len(buf)), self._wal_locks[seg]:
+            off, over = self._append_locked(
+                seg, buf, self.sync if fsync is None else fsync)
         bb = self.blackbox
         if bb is not None:
             bb.note_wal(RequestInstrumenter.current_wave(), seg, off,
@@ -491,7 +488,8 @@ class PaxosLogger:
                     return self._rotate_locked(seg, buf, want_sync,
                                                "injected fsync EIO")
             try:
-                os.fsync(wal.fileno())
+                with traced("wal.fsync"):
+                    os.fsync(wal.fileno())
             except OSError as exc:
                 if exc.errno == errno.ENOSPC:
                     self._note_disk_full(seg)

@@ -54,7 +54,8 @@ from gigapaxos_tpu.paxos.logger import (CheckpointRec, LogEntry, PaxosLogger,
 from gigapaxos_tpu.paxos.paxosconfig import PC
 from gigapaxos_tpu.utils.config import Config
 from gigapaxos_tpu.utils.engineledger import EngineLedger
-from gigapaxos_tpu.utils.instrument import RequestInstrumenter
+from gigapaxos_tpu.utils.instrument import (RequestInstrumenter, span,
+                                            traced)
 from gigapaxos_tpu.utils.jaxcache import cache_metrics as _cache_metrics
 from gigapaxos_tpu.utils.logutil import get_logger
 from gigapaxos_tpu.utils.profiler import DelayProfiler
@@ -93,6 +94,29 @@ def _frames_in(item) -> int:
     if type(item) is WireChunk:
         return len(item)
     return 1
+
+
+class _StampedQueue(queue_mod.Queue):
+    """The worker's intake queue.  Each item is stamped with its put
+    time under the queue's own mutex, so puts pair with gets whichever
+    thread put them and whoever drains; items come out as they went
+    in.  ``t_put`` is the put time of the item the last ``get``
+    returned: the one consumer reads it right after its blocking get,
+    which makes it the put time of the batch's oldest item."""
+
+    def _init(self, maxsize):
+        super()._init(maxsize)
+        self.t_put = 0.0
+
+    def _put(self, item):
+        self.queue.append((time.monotonic(), item))
+
+    def _get(self):
+        self.t_put, item = self.queue.popleft()
+        return item
+
+
+_STOP = object()  # _next_batch: the stop sentinel came out of the queue
 
 
 def _no_cpu_clock():
@@ -531,7 +555,7 @@ class PaxosNode:
         self._handlers: Dict[type, List] = {}
         self._tick_hooks: List = []
 
-        self._inq: "queue_mod.Queue" = queue_mod.Queue()
+        self._inq: "queue_mod.Queue" = _StampedQueue()
         # Per-processing-thread batch state (THREAD-LOCAL, see the
         # property block below): the emit hand-off queue, the batched
         # response/outbound buffers, the same-pass self-route buffer,
@@ -1461,39 +1485,14 @@ class PaxosNode:
             return self._worker_loop_pipelined()
         prev_items = 0
         while not self._stopping:
-            try:
-                first = self._inq.get(timeout=self.batch_timeout)
-            except queue_mod.Empty:
-                with self._engine_lock:
+            got = self._next_batch(prev_items)
+            if got is None:
+                with traced("w.tick", node=self.id), self._engine_lock:
                     self._tick()
                 continue
-            if first is None:
+            if got is _STOP:
                 break
-            if prev_items >= self.batch_busy and self.batch_coalesce > 0:
-                # adaptive coalescing (SURVEY §7.3.3): under load, let
-                # the batch fill before draining — fixed per-call costs
-                # amortize over ~10x more lanes.  Trickle traffic skips
-                # this (prev batch small), keeping the latency path hot.
-                time.sleep(self.batch_coalesce)
-            batch = [first]
-            # the cap counts FRAMES, not queue items: with batched
-            # intake one item can be a whole read chunk, and an
-            # uncounted fill would build multi-second mega-batches that
-            # starve _tick (elections, re-drive, catch-up)
-            n_frames = _frames_in(first)
-            while n_frames < self.batch_size:
-                try:
-                    nxt = self._inq.get_nowait()
-                except queue_mod.Empty:
-                    break
-                if nxt is None:
-                    self._stopping = True
-                    break
-                batch.append(nxt)
-                n_frames += _frames_in(nxt)
-            prev_items = n_frames
-            self._backlog_est = int(
-                self._inq.qsize() * n_frames / max(1, len(batch)))
+            batch, prev_items, qwait = got
             RequestInstrumenter.set_wave(RequestInstrumenter.next_wave())
             # wave-coherent engine clock: the decode timestamp is what
             # the flight recorder's F record carries, so every _now()
@@ -1502,23 +1501,18 @@ class PaxosNode:
             # (redrive windows, election backoff) reproduce exactly
             self._wtls.now = time.time()
             t0 = time.monotonic()
-            c0 = self._ct()
             try:
-                sp = RequestInstrumenter.span_begin(
-                    "decode", node=self.id, frames=n_frames)
-                decoded = self._decode_batch(batch)
-                RequestInstrumenter.span_end(sp)
-                t1 = time.monotonic()
-                c1 = self._ct()
-                DelayProfiler.update_total("w.decode", t0, len(batch),
-                                           cpu_t0=c0)
-                sp = RequestInstrumenter.span_begin(
-                    "engine", node=self.id, items=len(decoded))
-                with self._engine_lock:
-                    self._process(decoded)
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.process", t1, len(batch),
-                                           cpu_t0=c1)
+                with span("w.decode", node=self.id, n=len(batch),
+                          cpu=self._ct, frames=prev_items,
+                          queue_wait_s=qwait):
+                    decoded = self._decode_batch(batch)
+                with span("w.process", node=self.id, n=len(batch),
+                          cpu=self._ct, items=len(decoded)) as sp:
+                    with self._engine_lock:
+                        if sp.on:
+                            sp.note(lock_wait_s=round(
+                                time.monotonic() - sp.t0, 6))
+                        self._process(decoded)
             except Exception:
                 if not self._stopping:
                     log.exception("worker batch failed (%d items)",
@@ -1529,8 +1523,62 @@ class PaxosNode:
             # ticks run UNPINNED (real time) — each effective tick's
             # clock is captured in its own T record instead
             self._wtls.now = 0.0
-            with self._engine_lock:
+            with traced("w.tick", node=self.id), self._engine_lock:
                 self._tick()
+
+    def _next_batch(self, prev_items: int):
+        """Block for the next intake batch (all three worker loops):
+        ``(batch, n_frames, queue_wait_s)``; None when the wait timed
+        out; ``_STOP`` when the stop sentinel came first.  The wait and
+        the coalescing nap are spans of their own (no wave: they belong
+        to no batch); the queue wait is that of the batch's oldest
+        item, from its put to this dequeue."""
+        with traced("w.wait", node=self.id, wave=0) as sp:
+            try:
+                first = self._inq.get(timeout=self.batch_timeout)
+            except queue_mod.Empty:
+                sp.note(timed_out=True)
+                return None
+        if first is None:
+            return _STOP
+        qwait = time.monotonic() - self._inq.t_put
+        if prev_items >= self.batch_busy and self.batch_coalesce > 0:
+            # adaptive coalescing (SURVEY §7.3.3): under load, let
+            # the batch fill before draining — fixed per-call costs
+            # amortize over ~10x more lanes.  Trickle traffic skips
+            # this (prev batch small), keeping the latency path hot.
+            with traced("w.coalesce", node=self.id, wave=0,
+                        prev_items=prev_items):
+                time.sleep(self.batch_coalesce)
+        batch = [first]
+        # the cap counts FRAMES, not queue items: with batched
+        # intake one item can be a whole read chunk, and an
+        # uncounted fill would build multi-second mega-batches that
+        # starve _tick (elections, re-drive, catch-up)
+        n_frames = _frames_in(first)
+        while n_frames < self.batch_size:
+            try:
+                nxt = self._inq.get_nowait()
+            except queue_mod.Empty:
+                break
+            if nxt is None:
+                self._stopping = True
+                break
+            batch.append(nxt)
+            n_frames += _frames_in(nxt)
+        self._backlog_est = int(
+            self._inq.qsize() * n_frames / max(1, len(batch)))
+        DelayProfiler.add_total("w.queue_wait", qwait, n_frames)
+        return batch, n_frames, round(qwait, 6)
+
+    def _emit(self, resp: Optional[Dict], out: Optional[List]) -> None:
+        """``_emit_bundle`` under its span.  Counted BEFORE the bundle
+        appends the encoded response frames to ``out``, which would
+        double-count them."""
+        n = (len(out) if out else 0) + \
+            (sum(len(v) for v in resp.values()) if resp else 0)
+        with span("w.emit", node=self.id, n=n, frames=n):
+            self._emit_bundle(resp, out)
 
     def _worker_loop_pipelined(self) -> None:
         """Three-stage worker (PC.PIPELINE_WORKER; SURVEY §7.1 "build
@@ -1562,22 +1610,13 @@ class PaxosNode:
                 item = emitq.get()
                 if item is None:
                     return
-                t0 = time.monotonic()
                 wid, resp, out = item
                 RequestInstrumenter.set_wave(wid)
-                # count BEFORE _emit_bundle: it appends the encoded
-                # response frames to `out`, which would double-count
-                n_items = (len(out) if out else 0) + \
-                    (sum(len(v) for v in resp.values()) if resp else 0)
-                sp = RequestInstrumenter.span_begin(
-                    "emit", node=self.id, items=n_items)
                 try:
-                    self._emit_bundle(resp, out)
+                    self._emit(resp, out)
                 except Exception:
                     if not self._stopping:
                         log.exception("emit stage failed")
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.emit", t0, n_items)
 
         def proc_loop() -> None:
             # _emit_q is thread-local: bind the hand-off queue on THIS
@@ -1587,7 +1626,7 @@ class PaxosNode:
                 try:
                     item = stage.get(timeout=self.batch_timeout)
                 except queue_mod.Empty:
-                    with self._engine_lock:
+                    with traced("w.tick", node=self.id), self._engine_lock:
                         self._tick()
                     continue
                 if item is None:
@@ -1598,21 +1637,18 @@ class PaxosNode:
                 # (the F record's ts) for the whole _process pass
                 self._wtls.now = ts
                 t0 = time.monotonic()
-                sp = RequestInstrumenter.span_begin(
-                    "engine", node=self.id, items=len(decoded))
                 try:
-                    with self._engine_lock:
+                    with span("w.process", node=self.id, n=len(decoded),
+                              items=len(decoded)), self._engine_lock:
                         self._process(decoded)
                 except Exception:
                     if not self._stopping:
                         log.exception("pipelined batch failed "
                                       "(%d items)", len(decoded))
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.process", t0, len(decoded))
                 DelayProfiler.update_delay("node.batch", t0,
                                            len(decoded))
                 self._wtls.now = 0.0  # ticks run unpinned (T records)
-                with self._engine_lock:
+                with traced("w.tick", node=self.id), self._engine_lock:
                     self._tick()
 
         emit = threading.Thread(target=emit_loop, daemon=True,
@@ -1624,30 +1660,12 @@ class PaxosNode:
         prev_items = 0
         try:
             while not self._stopping:
-                try:
-                    first = self._inq.get(timeout=self.batch_timeout)
-                except queue_mod.Empty:
+                got = self._next_batch(prev_items)
+                if got is None:
                     continue  # proc thread ticks on its own timeout
-                if first is None:
+                if got is _STOP:
                     break
-                if prev_items >= self.batch_busy and \
-                        self.batch_coalesce > 0:
-                    time.sleep(self.batch_coalesce)
-                batch = [first]
-                n_frames = _frames_in(first)
-                while n_frames < self.batch_size:
-                    try:
-                        nxt = self._inq.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if nxt is None:
-                        self._stopping = True
-                        break
-                    batch.append(nxt)
-                    n_frames += _frames_in(nxt)
-                prev_items = n_frames
-                self._backlog_est = int(
-                    self._inq.qsize() * n_frames / max(1, len(batch)))
+                batch, prev_items, qwait = got
                 # one wave id per batch, handed down the pipeline with
                 # the batch itself so every stage's spans (and the
                 # trace events recorded while processing it) join up
@@ -1657,21 +1675,17 @@ class PaxosNode:
                 # batch: the process stage pins the engine clock to it
                 ts = time.time()
                 self._wtls.now = ts
-                t0 = time.monotonic()
-                sp = RequestInstrumenter.span_begin(
-                    "decode", node=self.id, frames=n_frames)
                 try:
-                    decoded = self._decode_batch(batch)
+                    with span("w.decode", node=self.id, n=len(batch),
+                              frames=prev_items, queue_wait_s=qwait):
+                        decoded = self._decode_batch(batch)
                 except Exception:
                     log.exception("pipelined decode failed (%d items)",
                                   len(batch))
                     continue
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.decode", t0, len(batch))
-                t0 = time.monotonic()
                 # blocks at depth 2: backpressure
-                stage.put((wid, ts, decoded))
-                DelayProfiler.update_total("w.decode_blocked", t0)
+                with span("w.decode_blocked", node=self.id):
+                    stage.put((wid, ts, decoded))
         finally:
             stage.put(None)
             # the process stage can legitimately sit in a 10-20s cold
@@ -1765,20 +1779,13 @@ class PaxosNode:
                 item = emitq.get()
                 if item is None:
                     return
-                t0 = time.monotonic()
                 wid, resp, out = item
                 RequestInstrumenter.set_wave(wid)
-                n_items = (len(out) if out else 0) + \
-                    (sum(len(v) for v in resp.values()) if resp else 0)
-                sp = RequestInstrumenter.span_begin(
-                    "emit", node=self.id, items=n_items)
                 try:
-                    self._emit_bundle(resp, out)
+                    self._emit(resp, out)
                 except Exception:
                     if not self._stopping:
                         log.exception("emit stage failed")
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.emit", t0, n_items)
 
         def proc_loop(k: int, procq, emitq) -> None:
             # lane identity, bound thread-locally: WAL segment + the
@@ -1786,11 +1793,12 @@ class PaxosNode:
             self._wtls.wal_seg = k
             self._emit_q = emitq
             lock = self._engine_locks[k]
+            lane_tag = f"w.process@{k}"
             while True:
                 try:
                     item = procq.get(timeout=self.batch_timeout)
                 except queue_mod.Empty:
-                    with lock:
+                    with traced("w.tick", node=self.id, shard=k), lock:
                         self._tick(k)
                     continue
                 if item is None:
@@ -1802,25 +1810,19 @@ class PaxosNode:
                 # (the F record's ts) for the whole _process pass
                 self._wtls.now = ts
                 t0 = time.monotonic()
-                sp = RequestInstrumenter.span_begin(
-                    "engine", node=self.id, items=len(decoded),
-                    shard=k)
                 try:
-                    with lock:
+                    with span("w.process", node=self.id, n=len(decoded),
+                              items=len(decoded), shard=k), lock:
                         self._process(decoded)
                 except Exception:
                     if not self._stopping:
                         log.exception("lane %d batch failed (%d items)",
                                       k, len(decoded))
-                RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.process", t0,
-                                           len(decoded))
-                DelayProfiler.update_total(f"w.process@{k}", t0,
-                                           len(decoded))
+                DelayProfiler.update_total(lane_tag, t0, len(decoded))
                 DelayProfiler.update_delay("node.batch", t0,
                                            len(decoded))
                 self._wtls.now = 0.0  # ticks run unpinned (T records)
-                with lock:
+                with traced("w.tick", node=self.id, shard=k), lock:
                     self._tick(k)
 
         for k in range(S):
@@ -1837,30 +1839,12 @@ class PaxosNode:
         prev_items = 0
         try:
             while not self._stopping:
-                try:
-                    first = self._inq.get(timeout=self.batch_timeout)
-                except queue_mod.Empty:
+                got = self._next_batch(prev_items)
+                if got is None:
                     continue  # lanes tick on their own timeouts
-                if first is None:
+                if got is _STOP:
                     break
-                if prev_items >= self.batch_busy and \
-                        self.batch_coalesce > 0:
-                    time.sleep(self.batch_coalesce)
-                batch = [first]
-                n_frames = _frames_in(first)
-                while n_frames < self.batch_size:
-                    try:
-                        nxt = self._inq.get_nowait()
-                    except queue_mod.Empty:
-                        break
-                    if nxt is None:
-                        self._stopping = True
-                        break
-                    batch.append(nxt)
-                    n_frames += _frames_in(nxt)
-                prev_items = n_frames
-                self._backlog_est = int(
-                    self._inq.qsize() * n_frames / max(1, len(batch)))
+                batch, prev_items, qwait = got
                 wid = RequestInstrumenter.next_wave()
                 RequestInstrumenter.set_wave(wid)
                 # decode timestamp rides to every lane with its
@@ -1868,28 +1852,21 @@ class PaxosNode:
                 # it, so one wave shares one clock across all lanes
                 ts = time.time()
                 self._wtls.now = ts
-                t0 = time.monotonic()
-                sp = RequestInstrumenter.span_begin(
-                    "decode", node=self.id, frames=n_frames)
                 try:
-                    decoded = self._decode_batch(batch)
-                    lanes = self._split_decoded(decoded)
+                    with span("w.decode", node=self.id, n=len(batch),
+                              frames=prev_items, queue_wait_s=qwait):
+                        decoded = self._decode_batch(batch)
+                        lanes = self._split_decoded(decoded)
                 except Exception:
                     log.exception("decode-split failed (%d items)",
                                   len(batch))
                     continue
-                finally:
-                    # end the span on the failure path too, or the
-                    # begun/ended accounting diverges forever
-                    RequestInstrumenter.span_end(sp)
-                DelayProfiler.update_total("w.decode", t0, len(batch))
-                t0 = time.monotonic()
-                for k in range(S):
-                    if lanes[k]:
-                        # blocking at depth 4: backpressure reaches the
-                        # socket exactly as the single lane's did
-                        procqs[k].put((wid, ts, lanes[k]))
-                DelayProfiler.update_total("w.decode_blocked", t0)
+                with span("w.decode_blocked", node=self.id):
+                    for k in range(S):
+                        if lanes[k]:
+                            # blocking at depth 4: backpressure reaches
+                            # the socket exactly as the single lane's did
+                            procqs[k].put((wid, ts, lanes[k]))
         finally:
             for q in procqs:
                 q.put(None)
@@ -2192,14 +2169,11 @@ class PaxosNode:
                 # run on the emit thread, overlapping the next batch's
                 # engine wave here.  Blocking at depth 2 is the same
                 # backpressure the inline flush exerted.
-                t0 = time.monotonic()
-                self._emit_q.put((RequestInstrumenter.current_wave(),
-                                  resp, out))
-                DelayProfiler.update_total("w.emit_blocked", t0)
-            else:
-                sp = RequestInstrumenter.span_begin("emit", node=self.id)
-                self._emit_bundle(resp, out)
-                RequestInstrumenter.span_end(sp)
+                with span("w.emit_blocked", node=self.id):
+                    self._emit_q.put((RequestInstrumenter.current_wave(),
+                                      resp, out))
+            elif resp or out:
+                self._emit(resp, out)
             if bb is not None:
                 ch = None
                 if self._chaos.enabled:

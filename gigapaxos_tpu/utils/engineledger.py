@@ -40,6 +40,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from gigapaxos_tpu.utils.instrument import traced
+
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -112,7 +114,10 @@ class EngineLedger:
             cls.note_trace(name)
             cls._tl.current = name
             try:
-                return fn(*args, **kwargs)
+                # under whatever dispatched: a trace inside a traced
+                # window is named where it stalls (gp.eng.compile)
+                with traced("eng.compile", kernel=name):
+                    return fn(*args, **kwargs)
             finally:
                 cls._tl.current = None
 

@@ -30,23 +30,57 @@ Request/Proposal and the accept payload blobs (old nodes ignore the
 unknown bit — the wire format is unchanged).  When sampling is off the
 hot path pays one class-attribute check per hook, nothing else.
 
-Spans (the metrics-plane extension): the 3-stage worker (``decode`` |
-``engine`` | ``emit``), the WAL (``wal``), and the columnar backend's
-submit/collect waves (``eng.submit`` / ``eng.collect``) stamp begin/end
-pairs carrying a *wave id* — one per worker batch, propagated
-thread-locally through the pipeline stages — plus per-kind attributes
-(frame/lane counts, chunk count, the submit->collect overlap).  Trace
-events record the wave they happened in, so :meth:`request_spans` /
-:meth:`request_breakdown` decompose one request into queue wait, device
-time, WAL fsync, and emit without rerunning the bench — and
+Stage spans (:class:`span`, the one primitive at every boundary of a
+served wave): ``with span(kind, node=, n=, **attrs)`` always adds wall
+seconds, a call and ``n`` items to the ``DelayProfiler`` total
+``total or kind``.  The span itself comes on in two ways, with no knob
+of its own: the operator's switch (``RequestInstrumenter.enabled``, from
+``PC.TRACE_REQUESTS`` / ``PC.TRACE_SAMPLE``), or a JAX profiler session
+(``jax.profiler.TraceAnnotation.is_enabled()``).  On, it is an event
+``gp.<kind>`` on its thread's line of the profile's ``/host:CPU`` plane —
+the same ``.xplane.pb`` and clock as the device's ``XLA Ops`` — with
+``node``, ``wave``, ``n`` and the attributes as its stats, and a record
+in the span ring: kind, node, thread, wave, ``parent`` (the id of the
+enclosing span on the thread, 0 for none), t0, t1, n and attributes.
+Off, it costs its sums and one gate check; the ring takes nothing, so
+after a profiled window it holds that window and only that.
+
+    gp.w.wait *      the worker's blocking get       timed_out
+    gp.w.coalesce *  the coalescing nap              prev_items
+    gp.w.decode      _decode_batch                   frames, queue_wait_s
+    gp.w.process     _process under the engine lock  items, lock_wait_s
+    gp.w.tick *      _tick under the engine lock     (shard)
+    gp.w.emit        _emit_bundle                    frames
+    gp.eng.submit    _submit1/_submit2   kernel, lanes, bucket, chunks,
+                                         launched
+    gp.eng.pack *    _packed (inside submit)         bytes
+    gp.eng.collect   EngineWave.collect              lanes, overlap_s
+    gp.wal *         log_raw_inline (lock, append, sync)  entries, seg,
+                                                          bytes
+    gp.wal.fsync *   the os.fsync alone (inside gp.wal)
+    gp.eng.compile * a kernel's trace (EngineLedger.traced)  kernel
+    gp.w.decode_blocked / gp.w.emit_blocked   hand-offs of the
+                     pipelined and sharded loops
+
+(* :func:`traced`: the boundaries inside a summed stage are spans with
+no sum of their own, and off they cost one gate check and no object.)
+The first five are siblings and tile a worker thread's time.  ``wait``
+and ``coalesce`` carry wave 0; the others the *wave id* of their worker
+batch — one per batch, propagated thread-locally through the pipeline
+stages, bound at submit for a collect — and trace events record the
+wave they happened in, so :meth:`request_spans` /
+:meth:`request_breakdown` decompose one request into decode, process,
+WAL, and emit without rerunning the bench — and
 :meth:`cluster_breakdown` generalizes that to the whole deployment by
 merging per-node ring exports (``export_trace`` over ``/traces/<id>``).
+``span_begin`` / ``span_end`` are the pair underneath.
 
 Hygiene: ring eviction is age-based as well as size-based
 (``max_age_s``): spans from long-dead waves no longer linger in the
 aggregate view, and spans that were begun but never ended (a stage
 crashed mid-span) age into an explicit ``orphaned`` counter instead of
-silently skewing the begun/ended pairing forever.  A bounded top-K
+silently skewing the begun/ended pairing forever; completed spans that a
+full ring pushes out are counted as ``dropped``.  A bounded top-K
 slow-request log (``slow_threshold_s`` / ``slow_k``) keeps the worst
 sampled traces for the stats dumper.
 """
@@ -55,16 +89,35 @@ from __future__ import annotations
 
 import heapq
 import itertools
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, List, NamedTuple, Optional, Tuple
+
+from gigapaxos_tpu.utils.profiler import DelayProfiler
 
 # golden-ratio multiplicative hash: the deterministic sampling verdict
 # every node computes identically from the trace id alone
 _GOLD = 0x9E3779B97F4A7C15
 _M64 = (1 << 64) - 1
 _SBITS = 24  # sampling-threshold resolution (1/2^24 granularity)
+
+_TA = None  # jax.profiler.TraceAnnotation, once jax is in the process
+
+
+def _profiling() -> bool:
+    """True while a JAX profiler session is active.  A process that
+    never imported jax (a bare client) cannot have one, and is not
+    made to import it here; once jax is there, this name is rebound to
+    ``TraceAnnotation.is_enabled`` itself (one C call on the gate)."""
+    global _TA, _profiling
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+    _TA = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
 
 
 class TraceContext(NamedTuple):
@@ -97,13 +150,16 @@ class RequestInstrumenter:
 
     _lock = threading.Lock()
     _ring: "deque" = deque(maxlen=200_000)   # (req, stage, node, t, wave)
-    _spans: "deque" = deque(maxlen=50_000)   # completed span dicts
+    # completed span dicts: six times the benchmark's traced 4 s at the
+    # served cells' 1.6-2.0K spans a second (PERF.md §6, PR 26)
+    _spans: "deque" = deque(maxlen=50_000)
     _open: Dict[int, dict] = {}              # id(span) -> span, not ended
     _tls = threading.local()
     _wave_seq = itertools.count(1)
     n_span_begun: int = 0
     n_span_ended: int = 0
     n_span_orphaned: int = 0
+    n_span_dropped: int = 0                  # pushed out of a full ring
     _slow: List[tuple] = []                  # min-heap (total, seq, id, ts)
     _slow_seq = itertools.count(1)
     _last_evict: float = 0.0
@@ -223,21 +279,36 @@ class RequestInstrumenter:
     # -- pipeline-stage spans ----------------------------------------------
 
     @classmethod
+    def tracing(cls) -> bool:
+        """Spans are on: the operator's switch, or a JAX profiler
+        session (which then carries them as ``gp.<kind>`` events)."""
+        return cls.enabled or _profiling()
+
+    @classmethod
     def span_begin(cls, kind: str, node: int = -1,
                    wave: Optional[int] = None, **attrs) -> Optional[dict]:
         """Open a span of ``kind`` on the current (or given) wave.
         Returns the span handle to pass to :meth:`span_end`, or None
-        when tracing is disabled (span_end accepts None)."""
-        if not cls.enabled:
+        when spans are off (span_end accepts None).  The pair under
+        :class:`span`, which is what the hot path calls."""
+        if not (cls.enabled or _profiling()):
             return None
+        return cls._span_open(kind, node, wave, attrs)
+
+    @classmethod
+    def _span_open(cls, kind: str, node: int, wave: Optional[int],
+                   attrs: dict, t0: Optional[float] = None) -> dict:
         sp = {"kind": kind, "node": node,
+              "tid": threading.get_ident(),
               "wave": cls.current_wave() if wave is None else wave,
-              "t0": time.monotonic(), "t1": None}
+              "parent": 0, "t0": None, "t1": None}
         if attrs:
             sp.update(attrs)
         with cls._lock:
             cls.n_span_begun += 1
+            sp["id"] = cls.n_span_begun
             cls._open[id(sp)] = sp
+        sp["t0"] = time.monotonic() if t0 is None else t0
         return sp
 
     @classmethod
@@ -251,7 +322,7 @@ class RequestInstrumenter:
         with cls._lock:
             if cls._open.pop(id(sp), None) is not None:
                 cls.n_span_ended += 1
-                cls._spans.append(sp)
+                cls._keep(sp)
             elif sp.pop("_orphaned", False):
                 # the end arrived after all, just later than the age
                 # horizon (a long compile/recovery stall): move the
@@ -261,10 +332,19 @@ class RequestInstrumenter:
                 # span breakdown
                 cls.n_span_orphaned -= 1
                 cls.n_span_ended += 1
-                cls._spans.append(sp)
+                cls._keep(sp)
             # else: the rings were clear()ed between begin and end —
             # count nothing (begun was reset too)
         cls._maybe_evict(now)
+
+    @classmethod
+    def _keep(cls, sp: dict) -> None:
+        """Append a completed span (caller holds ``_lock``), counting
+        what a full ring pushes out: a reader of a window must know
+        that its beginning is gone."""
+        if len(cls._spans) == cls._spans.maxlen:
+            cls.n_span_dropped += 1
+        cls._spans.append(sp)
 
     # -- age-based eviction (satellite: size-only eviction let spans
     # from long-dead waves linger and skewed the pairing counts) -------
@@ -311,6 +391,15 @@ class RequestInstrumenter:
         return evicted
 
     # -- span queries -------------------------------------------------------
+
+    @classmethod
+    def spans_snapshot(cls) -> List[dict]:
+        """The completed spans in the ring, oldest first (one C-level
+        copy under the lock; the dicts are the ring's own — read, do
+        not write).  With :meth:`span_stats`'s ``dropped`` at 0 this is
+        everything since spans came on."""
+        with cls._lock:
+            return list(cls._spans)
 
     @classmethod
     def wave_spans(cls, wave: int) -> List[dict]:
@@ -498,22 +587,24 @@ class RequestInstrumenter:
         counts spans currently in flight; ``orphaned`` counts spans
         whose end stamp never arrived within ``max_age_s`` (a lost end
         — without the split, pairing skew was indistinguishable from
-        live load)."""
+        live load); ``dropped`` counts completed spans a full ring
+        pushed out."""
         cls._maybe_evict(time.monotonic())
         with cls._lock:
-            agg: Dict[str, list] = {}
-            for s in cls._spans:
-                a = agg.setdefault(s["kind"], [0, 0.0])
-                a[0] += 1
-                a[1] += s["t1"] - s["t0"]
-            return {
-                "begun": cls.n_span_begun,
-                "ended": cls.n_span_ended,
-                "orphaned": cls.n_span_orphaned,
-                "open": len(cls._open),
-                "kinds": {k: {"count": c, "total_s": t}
-                          for k, (c, t) in sorted(agg.items())},
-            }
+            snap = list(cls._spans)  # scanned outside the hot-path lock
+            out = {"begun": cls.n_span_begun,
+                   "ended": cls.n_span_ended,
+                   "orphaned": cls.n_span_orphaned,
+                   "dropped": cls.n_span_dropped,
+                   "open": len(cls._open)}
+        agg: Dict[str, list] = {}
+        for s in snap:
+            a = agg.setdefault(s["kind"], [0, 0.0])
+            a[0] += 1
+            a[1] += s["t1"] - s["t0"]
+        out["kinds"] = {k: {"count": c, "total_s": t}
+                        for k, (c, t) in sorted(agg.items())}
+        return out
 
     @classmethod
     def clear(cls) -> None:
@@ -526,6 +617,7 @@ class RequestInstrumenter:
             cls.n_span_begun = 0
             cls.n_span_ended = 0
             cls.n_span_orphaned = 0
+            cls.n_span_dropped = 0
 
     @classmethod
     def reset(cls) -> None:
@@ -534,3 +626,120 @@ class RequestInstrumenter:
         cls.enabled = False
         cls.configure(sample_rate=1.0, max_age_s=300.0,
                       slow_threshold_s=0.0, slow_k=32)
+
+
+class _Off:
+    """What :func:`traced` hands out while spans are off: one shared
+    object that does nothing."""
+
+    __slots__ = ()
+    on = False
+
+    def note(self, **attrs) -> None:
+        pass
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_OFF = _Off()
+
+
+class span:
+    """One stage span: ``with span("w.decode", node=i, n=frames): ...``
+
+    Always: adds the wall seconds, one call and ``n`` items (and, with
+    ``cpu=`` a thread clock, CPU seconds) to the ``DelayProfiler``
+    total ``total or kind``.  While spans are on
+    (:meth:`RequestInstrumenter.tracing`) also: an event ``gp.<kind>``
+    on this thread's line of the profiler's ``/host:CPU`` plane, with
+    ``node``, ``wave``, ``n`` and the attributes given here as its
+    stats, and the completed span in the ring, its ``parent`` the id of
+    the enclosing ``span`` on this thread (0: none).  Off, it costs the
+    sums and one gate check.  ``n`` may be set, and :meth:`note` called,
+    inside the block; what comes that late reaches the ring only.
+    :func:`traced` is the same span without a sum of its own."""
+
+    __slots__ = ("kind", "total", "node", "n", "wave", "attrs", "t0",
+                 "t1", "_cpu", "_c0", "_sp", "_ann")
+
+    def __init__(self, kind: str, node: int = -1, n: int = 1,
+                 total: Optional[str] = None, wave: Optional[int] = None,
+                 cpu=None, **attrs):
+        self.kind = kind
+        self.total = kind if total is None else total
+        self.node = node
+        self.n = n
+        self.wave = wave
+        self.attrs = attrs
+        self._cpu = cpu
+        self._c0 = self._sp = None
+
+    @property
+    def on(self) -> bool:
+        return self._sp is not None
+
+    def note(self, **attrs) -> None:
+        """Attributes known only inside the block (ring only)."""
+        if self._sp is not None:
+            self._sp.update(attrs)
+
+    def __enter__(self) -> "span":
+        if self._cpu is not None:
+            self._c0 = self._cpu()
+        t0 = self.t0 = time.monotonic()
+        if RequestInstrumenter.enabled or _profiling():
+            self._open(t0)
+        return self
+
+    def _open(self, t0: float) -> None:
+        # inside [t0, t1]: what recording costs is the span's own, so
+        # that sibling spans tile their thread's time
+        ri = RequestInstrumenter
+        stack = getattr(ri._tls, "stack", None)
+        if stack is None:
+            stack = ri._tls.stack = []
+        wave = ri.current_wave() if self.wave is None else self.wave
+        sp = self._sp = ri._span_open(self.kind, self.node, wave,
+                                      self.attrs, t0)
+        sp["parent"] = stack[-1] if stack else 0
+        stack.append(sp["id"])
+        self._ann = None
+        if _profiling():
+            self._ann = _TA("gp." + self.kind, node=self.node, wave=wave,
+                            n=self.n, **self.attrs)
+            self._ann.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        sp = self._sp
+        if sp is None:
+            t1 = self.t1 = time.monotonic()
+        else:
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+            RequestInstrumenter._tls.stack.pop()
+            RequestInstrumenter.span_end(sp, n=self.n)
+            t1 = self.t1 = sp["t1"]
+        if self.total:
+            c0 = self._c0  # None: no thread clock (PC.PROFILE_CPU off)
+            DelayProfiler.add_total(
+                self.total, t1 - self.t0, self.n,
+                self._cpu() - c0 if c0 is not None else 0.0)
+        return False
+
+    @staticmethod
+    def traced(kind: str, node: int = -1, n: int = 1,
+               wave: Optional[int] = None, **attrs):
+        """A span with no sum of its own, for the boundaries inside a
+        summed stage (a wait, a tick, a pack, the fsync): the same
+        event and ring record while spans are on; off, one gate check
+        and no object."""
+        if RequestInstrumenter.enabled or _profiling():
+            return span(kind, node, n, "", wave, **attrs)
+        return _OFF
+
+
+traced = span.traced

@@ -237,7 +237,9 @@ class DelayProfiler:
         dt = time.monotonic() - t0
         dcpu = (time.thread_time() - cpu_t0) if cpu_t0 is not None else 0.0
         with cls._lock:
-            t = cls._totals.setdefault(tag, [0.0, 0, 0, 0.0])
+            t = cls._totals.get(tag)
+            if t is None:  # not setdefault: no list built per call
+                t = cls._totals[tag] = [0.0, 0, 0, 0.0]
             t[0] += dt
             t[1] += 1
             t[2] += n
@@ -245,17 +247,20 @@ class DelayProfiler:
 
     @classmethod
     def add_total(cls, tag: str, seconds: float, n: int = 1,
-                  cpu_seconds: float = 0.0) -> None:
+                  cpu_seconds: float = 0.0, calls: int = 1) -> None:
         """Accumulate an already-measured span under ``tag`` (the
         overlap counters — device-busy vs host-busy vs blocked — are
         computed from timestamps captured elsewhere, so there is no
-        live ``t0`` to hand update_total)."""
+        live ``t0`` to hand update_total).  ``calls`` is what one
+        invocation counts as (a chunked submit counts its chunks)."""
         if not cls.enabled:
             return
         with cls._lock:
-            t = cls._totals.setdefault(tag, [0.0, 0, 0, 0.0])
+            t = cls._totals.get(tag)
+            if t is None:  # not setdefault: no list built per call
+                t = cls._totals[tag] = [0.0, 0, 0, 0.0]
             t[0] += seconds
-            t[1] += 1
+            t[1] += calls
             t[2] += n
             t[3] += cpu_seconds
 

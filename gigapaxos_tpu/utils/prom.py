@@ -20,11 +20,17 @@ def _tag_labels(tag: str, key: str) -> Dict[str, str]:
     """Profiler tag -> label set.  Sharded engine lanes suffix their hot
     tags with ``@<shard>`` (``eng.submit@2``, ``wal.fsync@0``,
     ``w.process@1``); the suffix becomes a ``shard`` label so per-lane
-    series aggregate and filter like any other Prometheus dimension."""
+    series aggregate and filter like any other Prometheus dimension.
+    ``eng.k.<kernel>`` likewise becomes ``eng.k`` with a ``kernel``
+    label."""
     if "@" in tag:
         base, _, sh = tag.rpartition("@")
         if sh.isdigit():
             return {key: base, "shard": sh}
+    if tag.startswith("eng.k."):
+        # per-kernel submit totals (valid lanes, a call per chunk): the
+        # kernel's ledger name becomes a label, as in the compile counts
+        return {key: "eng.k", "kernel": tag[len("eng.k."):]}
     return {key: tag}
 
 
@@ -380,6 +386,11 @@ def render_prometheus(m: dict, prefix: str = "gp") -> str:
                  [(None, spans.get(
                      "open", max(0, spans.get("begun", 0)
                                  - spans.get("ended", 0))))])
+        if "dropped" in spans:
+            w.family(f"{p}_spans_dropped_total", "counter",
+                     "completed spans a full ring pushed out (a window "
+                     "read from the ring has lost its beginning)",
+                     [(None, spans.get("dropped"))])
         if "orphaned" in spans:
             w.family(f"{p}_spans_orphaned_total", "counter",
                      "spans whose end stamp never arrived within the "

@@ -99,7 +99,7 @@ def test_cluster_breakdown_stitches_cross_node_trace(tmp_path, backend):
         # the WAL span (stamped node-less by the logger) is resolved
         # through its wave to a real node
         for n in (0, 1, 2):
-            assert "engine" in bd["nodes"][n], bd["nodes"]
+            assert "w.process" in bd["nodes"][n], bd["nodes"]
         assert -1 not in bd["nodes"] or \
             not bd["nodes"][-1], "unresolved spans"
         assert any("wal" in kinds for kinds in bd["nodes"].values())
