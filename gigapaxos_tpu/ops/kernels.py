@@ -36,11 +36,20 @@ Intra-batch preconditions (enforced by the host batcher,
   to the max ballot) — mirrors ``PaxosPacketBatcher`` coalescing;
 - at most one accept-reply lane per (group, slot, sender) per batch, which
   makes scatter-add equivalent to scatter-OR on the vote bitmaps.
+
+Lane order (the optional last argument ``runs`` of ``propose_batch``,
+``accept_batch``, ``accept_reply_batch`` and ``commit_batch``): a
+composition that hands every stage the same groups — the storm step —
+sorts its lanes by group once (:func:`lane_runs`) and passes the order
+along; the bodies then derive ranks, counts and maxima per group from
+dense scans over the run structure instead of sorting and scattering for
+them.  Called without it — the packed wrappers, the mesh table, recovery —
+a body is exactly what it was.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +99,89 @@ def _run_rank(key1, key2):
     return jnp.zeros((B,), i32).at[order].set(rank_sorted)
 
 
+class LaneRuns(NamedTuple):
+    """A batch whose lanes the caller has put in group order (see
+    :func:`lane_runs`): the lanes of one group are a contiguous *run*, in
+    their original lane order."""
+    order: jnp.ndarray  # i32[B]  the lane each position came from
+    valid: jnp.ndarray  # bool[B] ``valid`` in that order (true lanes first)
+    head: jnp.ndarray   # bool[B] first lane of its run
+    tail: jnp.ndarray   # bool[B] last lane of its run
+    # the caller vouches that the valid lanes of one group carry distinct
+    # slots inside one window, so no two lanes share a (group, w) column
+    distinct_slots: bool
+
+
+def lane_runs(g, valid, *lanes, distinct_slots=False):
+    """Sort a batch's lanes by group ONCE: a stable sort, so the lanes of
+    a group keep their lane order; invalid lanes go last.  Returns
+    ``(runs, g, *lanes)`` with ``g`` and the other per-lane arrays in that
+    order (they ride the sort; ``a[runs.order]`` permutes what comes
+    later).  A composition hands ``runs`` to every body it runs; a body's
+    own ``valid`` may then be any subset of the one given here.  With an
+    order a body reads each per-group combine (rank in run, count, max)
+    off a dense scan over ``[B]`` and writes a ``[G]`` field once per run,
+    from one lane; every scatter it issues has unique indices (a dropped
+    lane gets an out-of-bounds row of its own, ``G + lane``); and it
+    gathers by the ORDER's valid lanes, so stages that read one field of
+    one state share the gather."""
+    B = g.shape[0]
+    last = jnp.iinfo(i32).max
+    key, order, *lanes = jax.lax.sort(
+        (jnp.where(valid, g, last), jnp.arange(B, dtype=i32), *lanes),
+        num_keys=1, is_stable=True)
+    edge = key[1:] != key[:-1]
+    one = jnp.ones((1,), jnp.bool_)
+    runs = LaneRuns(order=order, valid=key != last,
+                    head=jnp.concatenate([one, edge]),
+                    tail=jnp.concatenate([edge, one]),
+                    distinct_slots=distinct_slots)
+    return (runs, _gi(key, runs.valid), *lanes)
+
+
+def _run_count(runs: LaneRuns, mask, reverse=False):
+    """Lanes of ``mask`` in a lane's run up to and including itself (from
+    itself on with ``reverse``): a cumsum less its value at the run's
+    first lane, which a cummax carries along the run."""
+    first = runs.tail if reverse else runs.head
+    n = mask.astype(i32)
+    cs = jax.lax.cumsum(n, reverse=reverse)
+    return cs - jax.lax.cummax(jnp.where(first, cs - n, 0), reverse=reverse)
+
+
+def _run_last(runs: LaneRuns, mask):
+    """The last lane of ``mask`` in each run: the ONE lane that writes the
+    run's combine into a ``[G]`` field."""
+    return mask & (_run_count(runs, mask, reverse=True) == 1)
+
+
+def _run_max(runs: LaneRuns, x):
+    """Max of ``x`` over a lane's whole run, on every lane of it: log2(B)
+    doubling steps, in each of which a lane takes in the lanes 2^k before
+    and after it where they are of its run (runs are contiguous, so after
+    k steps a lane has seen the 2^k - 1 lanes on either side)."""
+    B = x.shape[0]
+    run = jnp.cumsum(runs.head.astype(i32))  # >= 1
+
+    def shifted(a, d, fill):  # a[i - d], or a[i + |d|] for d < 0
+        pad = jnp.full((abs(d),), fill, a.dtype)
+        return jnp.concatenate([pad, a[:-d]] if d > 0 else [a[-d:], pad])
+
+    d = 1
+    while d < B:
+        for s in (d, -d):
+            x = jnp.where(shifted(run, s, 0) == run,
+                          jnp.maximum(x, shifted(x, s, 0)), x)
+        d *= 2
+    return x
+
+
+def _sd(g, keep, G):
+    """Scatter index where the kept lanes' indices are unique: a dropped
+    lane gets an out-of-bounds row OF ITS OWN, so all of them are."""
+    return jnp.where(keep, g, G + jnp.arange(g.shape[0], dtype=i32))
+
+
 # --------------------------------------------------------------------------
 # accept (acceptor side)                                  ref: PaxosAcceptor
 # --------------------------------------------------------------------------
@@ -102,15 +194,21 @@ class AcceptOut(NamedTuple):
     cur_bal: jnp.ndarray      # i32[B]  promised ballot after this batch
 
 
-def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid):
+def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
+                 runs: Optional[LaneRuns] = None):
     G, W = state.G, state.W
-    gi = _gi(g, valid)
+    gi = _gi(g, valid if runs is None else runs.valid)
     act = state.active[gi]
     live = valid & act  # inactive rows must not be mutated at all
 
     item_bal = jnp.where(live, bal, NO_BALLOT)
-    new_bal = state.bal.at[_si(g, live, G)].max(item_bal, mode="drop")
-    cur_bal = new_bal[gi]
+    if runs is None:
+        new_bal = state.bal.at[_si(g, live, G)].max(item_bal, mode="drop")
+        cur_bal = new_bal[gi]
+    else:
+        cur_bal = jnp.maximum(state.bal[gi], _run_max(runs, item_bal))
+        new_bal = state.bal.at[_sd(g, _run_last(runs, live), G)].set(
+            cur_bal, mode="drop", unique_indices=True)
 
     promised_ok = live & (bal >= cur_bal)
     cursor = state.exec_cursor[gi]
@@ -119,11 +217,13 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid):
     store = promised_ok & in_win
 
     w = jnp.where(store, slot % W, 0)
-    sgw = _si(g, store, G)
     # ONE multi-component scatter for the whole stored pvalue (the
-    # scatter op, not its payload width, is what XLA:CPU serializes on)
+    # scatter op, not its payload width, is what XLA:CPU serializes on);
+    # one accept lane per (group, slot) a batch: distinct columns
+    sgw = _si(g, store, G) if runs is None else _sd(g, store, G)
     acc = state.acc.at[sgw, w].set(
-        jnp.stack([slot, bal, rlo, rhi], axis=-1), mode="drop")
+        jnp.stack([slot, bal, rlo, rhi], axis=-1), mode="drop",
+        unique_indices=runs is not None)
 
     out = AcceptOut(
         acked=store | (promised_ok & stale),
@@ -150,15 +250,20 @@ class AcceptReplyOut(NamedTuple):
 
 
 def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
-                       valid):
+                       valid, runs: Optional[LaneRuns] = None):
     """Handle (batched) accept replies.
 
     ``bal`` carries the accepted ballot on ack lanes and the acceptor's
     (higher) promised ballot on nack lanes, matching the reference's
     ``AcceptReplyPacket`` semantics.
+
+    Where ``runs`` vouches for distinct slots, a lane is alone in its
+    (group, slot) column: its vote is the whole batch's, nothing is
+    gathered again and nothing deduped, and vote and emitted bit ride ONE
+    scatter.
     """
     G, W = state.G, state.W
-    gi = _gi(g, valid)
+    gi = _gi(g, valid if runs is None else runs.valid)
     w = jnp.where(valid, slot % W, 0)
 
     coord_here = state.is_coord[gi] & state.coord_active[gi]
@@ -173,31 +278,43 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     prev = propc[:, PROP_VOTES]
     fresh = match & (jnp.bitwise_and(jnp.right_shift(prev, sender_i),
                                      1) == 0)
-    sgw = _si(g, fresh, G)
-    prop = state.prop.at[sgw, w, PROP_VOTES].add(
-        jnp.where(fresh, bit, 0), mode="drop")
 
-    # re-gather POST-scatter so every lane of a (group, slot) column sees
-    # the whole batch's votes (two fresh votes in one batch must still
-    # cross quorum); `fresh` guarantees no bit is added twice, so the
-    # add never carries into EMITTED_BIT
-    newv = prop[gi, w, PROP_VOTES]
-    cnt = jax.lax.population_count(
-        jnp.bitwise_and(newv, VOTE_MASK)).astype(i32)
-    quorum = match & (cnt >= _majority(state.members[gi]))
-    # Exactly-once emission: besides the cross-batch EMITTED_BIT, dedupe
-    # WITHIN the batch — when two replies for the same (group, slot) cross
-    # quorum in one batch, only the first lane emits the decision.
-    # Non-quorum lanes get unique sentinel keys so they never form runs.
-    B = g.shape[0]
-    iota = jnp.arange(B, dtype=i32)
-    dup_before = quorum & (_run_rank(jnp.where(quorum, g, -1),
-                                     jnp.where(quorum, slot, iota)) > 0)
-    emitted_prev = jnp.bitwise_and(prev, EMITTED_BIT) != 0
-    newly = quorum & ~emitted_prev & ~dup_before
-    # `newly` is true at most once per column ever, so the add is an OR
-    prop = prop.at[_si(g, newly, G), w, PROP_VOTES].add(
-        jnp.where(newly, EMITTED_BIT, 0), mode="drop")
+    def crossed(votes):
+        cnt = jax.lax.population_count(
+            jnp.bitwise_and(votes, VOTE_MASK)).astype(i32)
+        return match & (cnt >= _majority(state.members[gi]))
+
+    if runs is not None and runs.distinct_slots:
+        # alone in its column: a lane's vote is the whole batch's
+        vote = jnp.where(fresh, bit, 0)
+        newly = crossed(prev + vote) & (jnp.bitwise_and(prev,
+                                                        EMITTED_BIT) == 0)
+        prop = state.prop.at[_sd(g, fresh | newly, G), w, PROP_VOTES].add(
+            vote + jnp.where(newly, EMITTED_BIT, 0), mode="drop",
+            unique_indices=True)
+    else:
+        sgw = _si(g, fresh, G)
+        prop = state.prop.at[sgw, w, PROP_VOTES].add(
+            jnp.where(fresh, bit, 0), mode="drop")
+        # re-gather POST-scatter so every lane of a (group, slot) column
+        # sees the whole batch's votes (two fresh votes in one batch must
+        # still cross quorum); `fresh` guarantees no bit is added twice,
+        # so the add never carries into EMITTED_BIT
+        quorum = crossed(prop[gi, w, PROP_VOTES])
+        # Exactly-once emission: besides the cross-batch EMITTED_BIT,
+        # dedupe WITHIN the batch — when two replies for the same (group,
+        # slot) cross quorum in one batch, only the first lane emits the
+        # decision.  Non-quorum lanes get unique sentinel keys so they
+        # never form runs.
+        B = g.shape[0]
+        iota = jnp.arange(B, dtype=i32)
+        dup_before = quorum & (_run_rank(jnp.where(quorum, g, -1),
+                                         jnp.where(quorum, slot, iota)) > 0)
+        emitted_prev = jnp.bitwise_and(prev, EMITTED_BIT) != 0
+        newly = quorum & ~emitted_prev & ~dup_before
+        # `newly` is true at most once per column ever, so the add is an OR
+        prop = prop.at[_si(g, newly, G), w, PROP_VOTES].add(
+            jnp.where(newly, EMITTED_BIT, 0), mode="drop")
 
     # Preemption: a nack carrying a ballot above ours ends our reign
     # (ref: PaxosCoordinator preemption on higher-ballot accept replies).
@@ -240,39 +357,50 @@ class ProposeOut(NamedTuple):
     cbal: jnp.ndarray      # i32[B]  coordinator ballot for the accept
 
 
-def propose_batch(state: ColumnarState, g, rlo, rhi, valid):
+def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
+                  runs: Optional[LaneRuns] = None):
     """Assign contiguous slots to new requests, multiple per group per batch.
 
     Lane i's slot is ``next_slot[g] + rank_i`` where rank is the lane's
     occurrence index among same-group lanes (stable-sort run rank,
-    O(B log B) — see :func:`_run_rank`).
+    O(B log B) — see :func:`_run_rank`; its place in the run, with
+    ``runs``).
     """
     G, W = state.G, state.W
     B = g.shape[0]
-    gi = _gi(g, valid)
+    gi = _gi(g, valid if runs is None else runs.valid)
 
     can = valid & state.active[gi] & state.is_coord[gi] & \
         state.coord_active[gi]
 
-    iota = jnp.arange(B, dtype=i32)
-    rank = _run_rank(jnp.where(can, g, -1), jnp.where(can, 0, iota))
+    if runs is None:
+        iota = jnp.arange(B, dtype=i32)
+        rank = _run_rank(jnp.where(can, g, -1), jnp.where(can, 0, iota))
+    else:
+        rank = jnp.where(can, _run_count(runs, can) - 1, 0)
 
     slot = state.next_slot[gi] + rank
     in_win = slot < state.exec_cursor[gi] + W
     granted = can & in_win
 
     # advance next_slot by per-group granted count
-    sg = _si(g, granted, G)
-    next_slot = state.next_slot.at[sg].add(jnp.where(granted, 1, 0),
-                                           mode="drop")
+    if runs is None:
+        sg = _si(g, granted, G)
+        next_slot = state.next_slot.at[sg].add(jnp.where(granted, 1, 0),
+                                               mode="drop")
+    else:  # the granted lanes lead their run: the last holds the count
+        next_slot = state.next_slot.at[
+            _sd(g, _run_last(runs, granted), G)].set(
+                slot + 1, mode="drop", unique_indices=True)
 
     # initialize the proposal column for the assigned slot: slot, req id,
-    # zero votes/emitted — ONE multi-component scatter
+    # zero votes/emitted — ONE multi-component scatter; the slots of a
+    # group are distinct and inside one window: distinct columns
     w = jnp.where(granted, slot % W, 0)
-    sgw = _si(g, granted, G)
+    sgw = _si(g, granted, G) if runs is None else _sd(g, granted, G)
     prop = state.prop.at[sgw, w].set(
         jnp.stack([slot, rlo, rhi, jnp.zeros_like(slot)], axis=-1),
-        mode="drop")
+        mode="drop", unique_indices=runs is not None)
 
     out = ProposeOut(
         granted=granted,
@@ -298,9 +426,10 @@ class CommitOut(NamedTuple):
     new_cursor: jnp.ndarray  # i32[B]  group frontier after this batch
 
 
-def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid):
+def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
+                 runs: Optional[LaneRuns] = None):
     G, W = state.G, state.W
-    gi = _gi(g, valid)
+    gi = _gi(g, valid if runs is None else runs.valid)
     act = state.active[gi]
     cursor = state.exec_cursor[gi]
 
@@ -308,12 +437,16 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid):
     in_win = (slot >= cursor) & (slot < cursor + W)
     store = valid & act & in_win
     w = jnp.where(store, slot % W, 0)
-    sgw = _si(g, store, G)
+    # a commit batch may repeat a (group, slot): the columns are distinct
+    # only where the caller vouches for it
+    alone = runs is not None and runs.distinct_slots
+    sgw = _sd(g, store, G) if alone else _si(g, store, G)
 
     # ONE multi-component scatter; "decided" is DEC_SLOT == expected slot
     # (NO_SLOT never matches), so no separate flag plane exists
     dec = state.dec.at[sgw, w].set(
-        jnp.stack([slot, rlo, rhi], axis=-1), mode="drop")
+        jnp.stack([slot, rlo, rhi], axis=-1), mode="drop",
+        unique_indices=alone)
 
     # contiguity advance over the touched rows only ([B, W] gathers)
     dslotr = dec[gi, :, DEC_SLOT]
@@ -324,8 +457,13 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid):
     adv = jnp.sum(jnp.cumprod(ok.astype(i32), axis=1), axis=1)
     new_cur = cursor + adv
 
-    sg = _si(g, store, G)
-    exec_cursor = state.exec_cursor.at[sg].max(new_cur, mode="drop")
+    if runs is None:
+        sg = _si(g, store, G)
+        exec_cursor = state.exec_cursor.at[sg].max(new_cur, mode="drop")
+    else:  # a group's lanes all read the one row: one frontier, >= cursor
+        exec_cursor = state.exec_cursor.at[
+            _sd(g, _run_last(runs, store), G)].set(
+                new_cur, mode="drop", unique_indices=True)
 
     out = CommitOut(
         applied=store,

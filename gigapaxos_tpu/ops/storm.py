@@ -40,15 +40,21 @@ def decide_storm_step(states: Tuple[ColumnarState, ...], g, rlo, rhi,
     whose quorum crossed in this step (== #granted lanes in steady state).
     """
     R = len(states)
+    # ONE lane order for the whole step: every stage is handed the same
+    # groups, and the step returns states and a count, so nothing is ever
+    # permuted back.  The slots granted to a group are distinct and inside
+    # one window, which is what `distinct_slots` vouches for.
+    runs, g, rlo, rhi = kernels.lane_runs(g, valid, rlo, rhi,
+                                          distinct_slots=True)
     s0 = states[0]
-    s0, pr = kernels.propose_batch(s0, g, rlo, rhi, valid)
+    s0, pr = kernels.propose_batch(s0, g, rlo, rhi, runs.valid, runs)
     slot, bal, granted = pr.slot, pr.cbal, pr.granted
 
     acks = []
     new_states = [s0] + list(states[1:])
     for r in range(R):
         sr, ar = kernels.accept_batch(new_states[r], g, slot, bal, rlo,
-                                      rhi, granted)
+                                      rhi, granted, runs)
         new_states[r] = sr
         acks.append(ar.acked)
 
@@ -57,13 +63,13 @@ def decide_storm_step(states: Tuple[ColumnarState, ...], g, rlo, rhi,
         sender = jnp.full_like(g, r)
         s0 = new_states[0]
         s0, rr = kernels.accept_reply_batch(s0, g, slot, bal, sender,
-                                            acks[r], granted)
+                                            acks[r], granted, runs)
         new_states[0] = s0
         newly = newly | rr.newly_decided
 
     for r in range(R):
         sr, _cr = kernels.commit_batch(new_states[r], g, slot, rlo, rhi,
-                                       newly)
+                                       newly, runs)
         new_states[r] = sr
 
     return tuple(new_states), jnp.sum(newly.astype(i32))

@@ -9,6 +9,7 @@ machines.
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from gigapaxos_tpu.ops import kernels, make_state, pack_ballot
 from gigapaxos_tpu.ops.types import join_req_id, split_req_id, NO_SLOT
@@ -368,3 +369,117 @@ def test_inactive_rows_ignore_everything():
     assert not applied
     st, _, _ = n.propose(5, 9)
     assert st == "inactive"
+
+
+# --------------------------------------------------------------------------
+# bodies handed a lane order (``kernels.lane_runs``) against the same bodies
+# without one
+# --------------------------------------------------------------------------
+
+
+def _with_and_without_order(body, state, lanes, valid, order_valid=None,
+                            distinct_slots=False):
+    """Run ``body`` on the batch as given and on the batch in group order
+    (``order_valid``: the lanes the order was made from, a superset of the
+    call's own ``valid``).  The states must be equal field for field, and
+    the outputs lane for lane after un-permuting, on the lanes that count
+    (an invalid lane's outputs are padding)."""
+    s1, o1 = body(state, *lanes, valid)
+    runs, g2 = kernels.lane_runs(
+        lanes[0], valid if order_valid is None else order_valid,
+        distinct_slots=distinct_slots)
+    *lanes2, valid2 = (a[runs.order] for a in (*lanes[1:], valid))
+    lanes2.insert(0, g2)
+    s2, o2 = body(state, *lanes2, valid2, runs)
+    order, v = np.asarray(runs.order), np.asarray(valid)
+    assert sorted(order) == list(range(len(v)))
+    for f in s1._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(s2, f)),
+                                      np.asarray(getattr(s1, f)), err_msg=f)
+    for f in o1._fields:
+        back = np.empty_like(np.asarray(getattr(o1, f)))
+        back[order] = np.asarray(getattr(o2, f))
+        np.testing.assert_array_equal(back[v], np.asarray(getattr(o1, f))[v],
+                                      err_msg=f)
+    return s1, o1
+
+
+def _ordered_body_case(stage):
+    from gigapaxos_tpu.ops.storm import make_fleet
+    from gigapaxos_tpu.ops.types import NODE_BITS
+
+    Gn, Wn, Bn = 32, 4, 96
+    rng = np.random.default_rng(28)
+    s0, s1, _s2 = make_fleet(Gn, Wn, R=3)
+    # 96 lanes over 8 groups: runs of a dozen on windows of 4, so lanes
+    # are throttled; invalid lanes scattered through
+    g = jnp.asarray(rng.integers(0, 8, Bn).astype(np.int32))
+    valid = jnp.asarray(rng.random(Bn) < 0.85)
+    rlo = jnp.asarray(rng.integers(1, 1 << 30, Bn, dtype=np.int32))
+    rhi = jnp.asarray(rng.integers(1, 1 << 30, Bn, dtype=np.int32))
+
+    s0, pr = _with_and_without_order(kernels.propose_batch, s0,
+                                     (g, rlo, rhi), valid)
+    granted = np.asarray(pr.granted)
+    assert 0 < granted.sum() < np.asarray(valid).sum()  # some throttled
+    if stage == "propose":
+        return
+
+    # a fifth of the lanes carry a higher ballot: the promise is the run's
+    # max, and the lanes below it are refused
+    bal = pr.cbal + jnp.asarray(
+        (rng.random(Bn) < 0.2).astype(np.int32) << NODE_BITS)
+    if stage == "accept":
+        _s, ao = _with_and_without_order(
+            kernels.accept_batch, s1, (g, pr.slot, bal, rlo, rhi),
+            pr.granted, order_valid=valid)
+        acked = np.asarray(ao.acked)
+        assert 0 < acked.sum() < granted.sum()
+        return
+
+    def twice(a):
+        return jnp.concatenate([a, a])
+
+    if stage == "reply_two_senders_one_batch":
+        # senders 1 and 2 answer every granted lane in ONE batch: both
+        # lanes of a (group, slot) cross quorum, one emits
+        sender = jnp.concatenate([jnp.full_like(g, 1), jnp.full_like(g, 2)])
+        acked = jnp.ones((2 * Bn,), jnp.bool_)
+        _s, ro = _with_and_without_order(
+            kernels.accept_reply_batch, s0,
+            (twice(g), twice(pr.slot), twice(pr.cbal), sender, acked),
+            twice(pr.granted))
+        assert np.asarray(ro.newly_decided).sum() == granted.sum()
+        return
+
+    newly = None
+    for sender in (0, 1):  # one sender a batch: a lane alone in its column
+        s0, ro = _with_and_without_order(
+            kernels.accept_reply_batch, s0,
+            (g, pr.slot, pr.cbal, jnp.full_like(g, sender),
+             jnp.ones((Bn,), jnp.bool_)), pr.granted, order_valid=valid,
+            distinct_slots=True)
+        newly = ro.newly_decided
+    assert np.asarray(newly).sum() == granted.sum()
+    if stage == "reply_one_sender_a_batch":
+        return
+
+    if stage == "commit_repeats_a_slot":
+        _s, co = _with_and_without_order(
+            kernels.commit_batch, s1,
+            (twice(g), twice(pr.slot), twice(rlo), twice(rhi)), twice(newly))
+        assert np.asarray(co.applied).sum() == 2 * granted.sum()
+    else:
+        _s, co = _with_and_without_order(
+            kernels.commit_batch, s1, (g, pr.slot, rlo, rhi), newly,
+            order_valid=valid, distinct_slots=True)
+        assert np.asarray(co.applied).sum() == granted.sum()
+    assert int(np.asarray(_s.exec_cursor).max()) == Wn
+
+
+@pytest.mark.parametrize("stage", [
+    "propose", "accept", "reply_two_senders_one_batch",
+    "reply_one_sender_a_batch", "commit_repeats_a_slot",
+    "commit_distinct_slots"])
+def test_body_with_a_lane_order_equals_body_without(stage):
+    _ordered_body_case(stage)

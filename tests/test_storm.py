@@ -42,6 +42,122 @@ def test_storm_duplicate_groups_in_batch():
                                   np.full(G, 2))
 
 
+def _reference_step(states, g, rlo, rhi, valid):
+    """The step as it was before the lanes were put in group order: the
+    same ten stages, each body called without an order, every lane where
+    the caller put it.  The reference the ordered step must equal."""
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops import kernels
+
+    R = len(states)
+    new = list(states)
+    new[0], pr = kernels.propose_batch(new[0], g, rlo, rhi, valid)
+    slot, bal, granted = pr.slot, pr.cbal, pr.granted
+    acks = []
+    for r in range(R):
+        new[r], ar = kernels.accept_batch(new[r], g, slot, bal, rlo, rhi,
+                                          granted)
+        acks.append(ar.acked)
+    newly = jnp.zeros_like(granted)
+    for r in range(R):
+        new[0], rr = kernels.accept_reply_batch(
+            new[0], g, slot, bal, jnp.full_like(g, r), acks[r], granted)
+        newly = newly | rr.newly_decided
+    for r in range(R):
+        new[r], _ = kernels.commit_batch(new[r], g, slot, rlo, rhi, newly)
+    return tuple(new), jnp.sum(newly.astype(jnp.int32))
+
+
+# name: (G, W, B, groups the lanes fall on, share of valid lanes,
+#        lanes forced onto group 3)
+ORDER_CASES = {
+    "unsorted": (256, 8, 64, 256, 1.0, 0),
+    "duplicate_heavy": (64, 8, 128, 16, 1.0, 0),   # B lanes over B/8 groups
+    "invalid_scattered": (64, 8, 128, 16, 0.7, 0),
+    "over_window_on_one_group": (64, 8, 96, 64, 0.9, 40),
+    "all_lanes_one_group": (16, 4, 32, 1, 1.0, 0),
+    "no_valid_lane": (16, 4, 32, 16, 0.0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_ordered_step_equals_the_unordered_composition(case):
+    """Sorting the lanes by group once and running every stage in that
+    order leaves every field of every replica, and the decided count, as
+    the unordered bodies leave them: same slot per lane (lane order within
+    the window), same promises, same planes — over several steps, so
+    windows fill, throttle and wrap."""
+    import jax
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops.storm import make_fleet, storm
+
+    G, W, B, hit, p_valid, hot = ORDER_CASES[case]
+    rng = np.random.default_rng(sorted(ORDER_CASES).index(case))
+    reference = jax.jit(_reference_step)
+    want, got = make_fleet(G, W, R=3), make_fleet(G, W, R=3)
+    total = 0
+    for it in range(5):
+        g = rng.integers(0, hit, B).astype(np.int32)
+        g[rng.choice(B, hot, replace=False)] = 3
+        lanes = [jnp.asarray(a) for a in (
+            g, rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.random(B) < p_valid)]
+        want, n_want = reference(want, *lanes)
+        got, n_got = storm(got, *lanes)
+        assert int(n_got) == int(n_want), (case, it)
+        total += int(n_got)
+        for r in range(3):
+            for f in want[r]._fields:
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(got[r], f)),
+                    np.asarray(getattr(want[r], f)),
+                    err_msg=f"{case}: step {it}, replica {r}, {f}")
+    assert (total > 0) == (p_valid > 0)
+
+
+def _count_primitives(jaxpr, out, under_cond=False):
+    """Equations by primitive, those under a ``cond`` apart: a branch
+    runs only when its predicate holds."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        out.append((name, under_cond, eqn))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count_primitives(sub, out, under_cond or name == "cond")
+
+
+def test_step_sorts_once_and_keeps_its_passes_down():
+    """Counts only: every serial pass over the lanes is a sort or a
+    scatter.  One sort a step (the lane order), no scatter into a
+    lane-shaped array (what un-permuting a rank is), and 17 scatters that
+    always run: propose 2, accept 2 x R, accept-reply 1 x R, commit
+    2 x R; the 2 x R resign scatters sit under a cond.  Every one that
+    always runs states that its indices are unique."""
+    import jax
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops.storm import decide_storm_step
+    from gigapaxos_tpu.ops.types import make_state
+
+    G, W, B, R = 64, 8, 16, 3
+    st = jax.eval_shape(lambda: make_state(G, W))
+    lane = jax.ShapeDtypeStruct((B,), jnp.int32)
+    jaxpr = jax.make_jaxpr(decide_storm_step)(
+        (st,) * R, lane, lane, lane, jax.ShapeDtypeStruct((B,), jnp.bool_))
+    eqns = []
+    _count_primitives(jaxpr.jaxpr, eqns)
+    assert sum(n == "sort" for n, _, _ in eqns) == 1
+    scatters = [(c, e) for n, c, e in eqns if n.startswith("scatter")]
+    always = [e for c, e in scatters if not c]
+    assert len(always) == 2 + 5 * R, len(always)
+    assert len(scatters) - len(always) == 2 * R
+    for e in always:
+        assert e.invars[0].aval.shape[0] == G, e  # never a [B] array
+        assert e.params["unique_indices"], e
+
+
 def test_sharded_storm_on_virtual_mesh():
     import jax
     if len(jax.devices()) < 4:
