@@ -63,8 +63,10 @@ _MAX_WAVE_ROWS = 16
 
 
 def _make_app(name: str):
-    from gigapaxos_tpu.paxos.interfaces import CounterApp, KVApp, NoopApp
-    apps = {"CounterApp": CounterApp, "KVApp": KVApp, "NoopApp": NoopApp}
+    from gigapaxos_tpu.paxos.interfaces import (CounterApp, KVApp, NoopApp,
+                                                RecordApp)
+    apps = {"CounterApp": CounterApp, "KVApp": KVApp, "NoopApp": NoopApp,
+            "RecordApp": RecordApp}
     if name not in apps:
         raise CaptureError(
             f"manifest app {name!r} unknown to replay (one of "

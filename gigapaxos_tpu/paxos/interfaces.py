@@ -134,3 +134,62 @@ class KVApp(Replicable):
             else:
                 self.stores[name] = json.loads(state.decode())
             return True
+
+
+class RecordApp(Replicable):
+    """One record of ``fields`` x ``field_bytes`` bytes per group: the row
+    of a key-value store with a group per key (YCSB's 10 x 100 B).
+
+    Requests are binary: ``b"R"`` reads the whole record; ``b"U"``, one
+    byte of field index, then ``field_bytes`` bytes overwrites that field.
+    Every reply begins with the 8-byte little-endian count of requests the
+    group has executed, which is the request's place in the group's one
+    order; a read's reply then carries the record.  A malformed request
+    takes its place in the order, changes nothing and is answered with
+    the count and ``b"?"``.  A record nobody restored or wrote is zeros.
+    ``checkpoint`` / ``restore`` carry the count and the record (``b""``
+    for a group as created: nothing executed, nothing restored)."""
+
+    def __init__(self, fields: int = 10, field_bytes: int = 100):
+        self.fields, self.field_bytes = fields, field_bytes
+        self._lock = threading.Lock()
+        self.count: Dict[str, int] = {}
+        self.records: Dict[str, bytes] = {}
+        self._blank = bytes(fields * field_bytes)
+
+    def execute(self, name, req_id, payload, is_stop=False) -> bytes:
+        fb = self.field_bytes
+        with self._lock:
+            c = self.count.get(name, 0) + 1
+            self.count[name] = c
+            head = c.to_bytes(8, "little")
+            op = payload[:1]
+            if op == b"R" and len(payload) == 1:
+                return head + self.records.get(name, self._blank)
+            if op == b"U" and len(payload) == 2 + fb \
+                    and payload[1] < self.fields:
+                rec = self.records.get(name, self._blank)
+                at = payload[1] * fb
+                self.records[name] = rec[:at] + payload[2:] + rec[at + fb:]
+                return head
+            return head + b"?"
+
+    def checkpoint(self, name) -> bytes:
+        with self._lock:
+            c = self.count.get(name, 0)
+            if not c and name not in self.records:
+                return b""  # initial: a create logs no kilobyte of zeros
+            return c.to_bytes(8, "little") \
+                + self.records.get(name, self._blank)
+
+    def restore(self, name, state) -> bool:
+        with self._lock:
+            if not state:
+                self.count.pop(name, None)
+                self.records.pop(name, None)
+                return True
+            if len(state) != 8 + len(self._blank):
+                return False
+            self.count[name] = int.from_bytes(state[:8], "little")
+            self.records[name] = bytes(state[8:])
+            return True

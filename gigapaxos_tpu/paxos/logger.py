@@ -425,6 +425,7 @@ class PaxosLogger:
             bb.note_wal(RequestInstrumenter.current_wave(), seg, off,
                         n_entries)
         DelayProfiler.update_delay("wal.fsync", t0)
+        DelayProfiler.add_total("wal.bytes", 0.0, len(buf))
         if self.segments > 1:
             # per-segment tail next to the node-wide one: lane skew
             # (one hot shard fsyncing 10x the others) must be visible

@@ -445,6 +445,19 @@ class PaxosNode:
         # election is unsettled.  Flushed by _tick or on coordinator
         # install; stale entries age out (client retransmit covers).
         self._parked: Dict[int, List[Tuple[float, pkt.Proposal]]] = {}
+        # req_ids that sit in _parked because propose found their group's
+        # window full (res.throttled).  Not yet proposed: _proposed and
+        # _executed_recent do not know them, so until the flush that
+        # proposes them again a retransmit is swallowed here and takes
+        # no second place.  Kept in step with the queues.
+        self._window_parked: set = set()
+        # req_id -> first parked at, of the lanes _flush_parked is
+        # proposing again right now (None outside that call)
+        self._window_retry: Optional[Dict[int, float]] = None
+        # row -> (execute cursor, time) when propose last found its
+        # window full: the tick proposes its parked lanes again once the
+        # cursor has moved or a second has passed, not every ping interval
+        self._window_tried: Dict[int, Tuple[int, float]] = {}
         # req_id -> last bounce ts: a stale-forwarded Proposal is bounced
         # onward at most once per window — the second sighting parks it,
         # breaking forward cycles without a wire-format TTL.
@@ -636,7 +649,9 @@ class PaxosNode:
         self.n_paused = 0
         self.n_unpaused = 0
         self.n_redriven = 0       # accept re-drives (lost-Accept recovery)
-        self.n_parked = 0         # proposals parked awaiting leadership
+        self.n_parked = 0         # proposals parked (leadership, full window)
+        self.n_proposed = 0       # lanes handed to propose
+        self.n_window_full = 0    # of them, returned throttled (window full)
         self.n_park_dropped = 0   # parked proposals dropped at cap
         self.n_redrive_capped = 0  # re-drive ticks that hit the 256 cap
         self.n_wave_dups = 0      # copies of a request within one wave
@@ -716,6 +731,28 @@ class PaxosNode:
     @_self_buf.setter
     def _self_buf(self, v) -> None:
         self._wtls.self_buf = v
+
+    @property
+    def _window_moved(self) -> Optional[List]:
+        """Rows that executed during this pass while proposals of theirs
+        were parked (a full window has room again), live only inside
+        _process like _self_buf, and lane-pure for the same reason."""
+        return getattr(self._wtls, "window_moved", None)
+
+    @_window_moved.setter
+    def _window_moved(self, v) -> None:
+        self._wtls.window_moved = v
+
+    @property
+    def _acc_ahead(self) -> Optional[List]:
+        """AcceptBatches of the lanes this pass found beyond their
+        window (:meth:`_requeue_ahead`), live inside _process until they
+        have been offered again."""
+        return getattr(self._wtls, "acc_ahead", None)
+
+    @_acc_ahead.setter
+    def _acc_ahead(self, v) -> None:
+        self._wtls.acc_ahead = v
 
     @property
     def _batch_t0(self) -> float:
@@ -1028,6 +1065,7 @@ class PaxosNode:
             # waiting clients via the relay (locally-entered ones are
             # answered through _client_wait below)
             for _ts, p in self._parked.pop(row, []):
+                self._window_parked.discard(p.req_id)
                 if p.sender != self.id:
                     self._route(p.sender, pkt.Response(
                         self.id, p.gkey, p.req_id, 3, b""))
@@ -2075,13 +2113,14 @@ class PaxosNode:
                     continue
                 meta = self.table.by_row(row)
                 if meta is None:
-                    self._parked.pop(row, None)
+                    for _ts, p in self._parked.pop(row, []):
+                        self._window_parked.discard(p.req_id)
                     continue
-                coord = unpack_ballot(int(self._bal[row]))[1]
-                if row not in self._elections and \
-                        not self._mass_has(row) and coord >= 0 and \
-                        coord not in self._suspects and \
-                        row not in self._catchup_barrier:
+                tried = self._window_tried.get(row)
+                if tried is not None and tried[0] == int(self._cur[row]) \
+                        and now - tried[1] < 1.0:
+                    continue  # its window is as full as it was
+                if self._leadership_settled(row):
                     self._flush_parked(row)
         if shard == 0 and (len(self._bounced) > 10000
                            or self._last_bounce_gc + 30 < now):
@@ -2146,6 +2185,8 @@ class PaxosNode:
         self._resp_out: Optional[Dict] = {}
         self._out_buf: Optional[List] = []
         self._self_buf: Optional[List] = []
+        self._window_moved: Optional[List] = []
+        self._acc_ahead: Optional[List] = []
         self._batch_t0 = time.time()  # app-retry sleep budget anchor
         try:
             self._process_inner(batch)
@@ -2153,11 +2194,30 @@ class PaxosNode:
             # accept -> reply -> commit -> execute; prepare -> reply ->
             # install), so this converges; cap defends against bugs
             for _ in range(8):
+                if self._window_moved:
+                    # proposals parked on a full window, now that their
+                    # row has executed: proposed again before the batch
+                    # ends, not a tick later
+                    rows_m, self._window_moved = self._window_moved, []
+                    for row in dict.fromkeys(rows_m):
+                        if self._leadership_settled(row):
+                            self._flush_parked(row)
+                ahead, self._acc_ahead = self._acc_ahead, None
+                if ahead:
+                    # accepts that were beyond the window before this
+                    # batch's commits: those that fit now, once more
+                    W = self.backend.window
+                    for ab in ahead:
+                        rows_a = self._rows_for_keys(ab.gkey)
+                        if (ab.slot < self._cur[rows_a] + W)[
+                                rows_a >= 0].any():
+                            self._self_buf.append(ab)
                 if not self._self_buf:
                     break
                 wave, self._self_buf = self._self_buf, []
                 self._process_inner(wave)
         finally:
+            self._window_moved = self._acc_ahead = None
             if self._self_buf:
                 for obj in self._self_buf:  # cap hit: requeue leftovers
                     self._inq.put(obj)
@@ -2252,8 +2312,11 @@ class PaxosNode:
             by_type.setdefault(type(obj), []).append(obj)
             s = getattr(obj, "sender", None)
             # (_ReqSoA carries a sender *array*; its senders are clients,
-            # never peers, so liveness bookkeeping doesn't apply)
-            if type(s) is int and s in self.addr_map:
+            # never peers, so liveness bookkeeping doesn't apply; nor to
+            # what this node routed to itself, a re-driven accept or a
+            # parked proposal: a node that had heard from itself went on
+            # to suspect itself a failure timeout later)
+            if type(s) is int and s != self.id and s in self.addr_map:
                 self._last_heard[s] = self._now()
                 self._suspects.discard(s)
 
@@ -2487,6 +2550,8 @@ class PaxosNode:
                 "redrive_capped": self.n_redrive_capped,
                 "wave_dups": self.n_wave_dups,
                 "parked": self.n_parked,
+                "proposed": self.n_proposed,
+                "window_full": self.n_window_full,
                 "park_dropped": self.n_park_dropped,
                 "shed": self.n_shed,
                 "shed_disk": self.n_shed_disk,
@@ -2742,30 +2807,80 @@ class PaxosNode:
 
     # -- request/proposal → propose ------------------------------------
 
-    def _park(self, row: int, prop: "pkt.Proposal") -> None:
+    def _park(self, row: int, prop: "pkt.Proposal",
+              ts: Optional[float] = None) -> None:
         """Hold a proposal while the row's leadership is unsettled
         (election in flight / coordinator suspect or unknown) instead of
-        forwarding it into a black hole."""
+        forwarding it into a black hole, or while the group's window is
+        full (``ts``: when it was first parked)."""
         q = self._parked.setdefault(row, [])
         if len(q) >= 512:
-            q.pop(0)  # oldest first; its client retransmit covers it
+            # oldest first; its client retransmit covers it
+            self._window_parked.discard(q.pop(0)[1].req_id)
             with self._stat_lock:
                 self.n_park_dropped += 1
         with self._stat_lock:
             self.n_parked += 1
-        q.append((self._now(), prop))
+        q.append((self._now() if ts is None else ts, prop))
+
+    def _park_window_full(self, rows, req_ids, flags, payloads, lanes,
+                          now: float) -> None:
+        """Lanes ``propose`` returned as throttled: the group has
+        ``window`` undecided slots.  Each is parked under its row, in lane
+        order, with its payload (its waiter stays in ``_client_wait``),
+        and proposed again when the row's execute cursor moves
+        (:meth:`_process`) or at the next tick."""
+        with self._stat_lock:
+            self.n_window_full += len(lanes)
+        retry, WP, CW = self._window_retry, self._window_parked, \
+            self._client_wait
+        for row in np.unique(rows[lanes]).tolist():
+            self._window_tried[row] = (int(self._cur[row]), now)
+        for i in lanes.tolist():
+            rid, row = int(req_ids[i]), int(rows[i])
+            waiter = CW.get(rid)
+            WP.add(rid)
+            self._park(row, pkt.Proposal(
+                self.id, int(self._row_gkey[row]), rid,
+                waiter[0] if waiter is not None else self.id,
+                int(flags[i]), bytes(payloads[i])),
+                retry.get(rid, now) if retry else now)
+
+    def _leadership_settled(self, row: int) -> bool:
+        """True when a parked proposal of ``row`` has somewhere to go:
+        no election in flight, a live coordinator known, no catch-up
+        barrier."""
+        coord = unpack_ballot(int(self._bal[row]))[1]
+        return (row not in self._elections and not self._mass_has(row)
+                and coord >= 0 and coord not in self._suspects
+                and row not in self._catchup_barrier)
 
     def _flush_parked(self, row: int) -> None:
         """Re-inject parked proposals now that leadership settled (we won,
-        or a live coordinator is known): the normal path forwards or
-        proposes them."""
+        or a live coordinator is known) or the window moved: the normal
+        path forwards or proposes them, in the order they were parked.
+        What finds the window full again is parked again with the time
+        it was first parked."""
         q = self._parked.pop(row, None)
+        self._window_tried.pop(row, None)
         if not q:
             return
         now = self._now()
-        live = [p for ts, p in q if now - ts < 10.0]
+        WP = self._window_parked
+        retry: Dict[int, float] = {}
+        live = []
+        for ts, p in q:
+            if p.req_id in WP:
+                WP.discard(p.req_id)
+                retry[p.req_id] = ts
+            if now - ts < 10.0:
+                live.append(p)
         if live:
-            self._handle_requests([], live)
+            self._window_retry = retry
+            try:
+                self._handle_requests([], live)
+            finally:
+                self._window_retry = None
 
     def _intake_take(self, n: int = 1) -> bool:
         """Take n tokens from the intake bucket; False = throttled."""
@@ -2896,6 +3011,7 @@ class PaxosNode:
         pay_parts: List[bytes] = []
         now = self._now()
         ex, exo = self._executed_recent, self._executed_old
+        WP = self._window_parked
         # req_ids given a lane in THIS wave.  The _proposed dedupe below
         # only knows earlier waves (entries are registered in _req_post):
         # when the worker stalls past the clients' retransmit interval
@@ -2951,6 +3067,12 @@ class PaxosNode:
                     st_, rv = self._cached_resp(rid)
                     self._route(int(snd[i]), pkt.Response(
                         self.id, int(sb.gkey[i]), rid, st_, rv))
+                    continue
+                if rid in WP:
+                    # parked on a full window: the copy takes no second
+                    # place; the waiter is refreshed
+                    self._client_wait[rid] = (int(snd[i]), now,
+                                              int(sb.gkey[i]))
                     continue
                 if rid in self._proposed:
                     # in-flight duplicate: swallow the proposal, but
@@ -3023,6 +3145,8 @@ class PaxosNode:
                             force=bool(o.flags & FLAG_SAMPLED))
                     self._route(coord, prop)
                 continue
+            if o.req_id in WP:
+                continue  # parked on a full window: no second place
             if o.req_id in self._proposed:
                 # swallow the duplicate but keep its payload: a
                 # carryover slot may hold only a FLAG_MISSING
@@ -3095,6 +3219,8 @@ class PaxosNode:
                                 force=bool(o.flags & FLAG_SAMPLED))
                         self._route(coord, o)
                 continue
+            if o.req_id in WP:
+                continue  # parked on a full window: no second place
             if o.req_id in self._proposed:
                 # swallow the duplicate, keep its payload, and record
                 # the entry replica as waiter so the carried slot's
@@ -3144,10 +3270,15 @@ class PaxosNode:
         granted = np.asarray(res.granted)
         bal_of = self._bal[rows]
         slot_arr = np.asarray(res.slot)
+        retry = self._window_retry
+        waited, n_waited = 0.0, 0
         for i in np.flatnonzero(granted).tolist():
             rid = int(req_ids[i])
             self._proposed[rid] = _InFlight(
                 int(rows[i]), int(slot_arr[i]), int(bal_of[i]), now, now)
+            if retry and rid in retry:
+                waited += now - retry[rid]
+                n_waited += 1
             fl = int(flag_parts[i])
             if RequestInstrumenter.enabled and RequestInstrumenter \
                     .sampled(rid, bool(fl & FLAG_SAMPLED)):
@@ -3171,6 +3302,15 @@ class PaxosNode:
                 RequestInstrumenter.record(rid, "prop", self.id,
                                            force=True)
             self._store_payload(rid, fl, bytes(pay_parts[i]))
+        if n_waited:
+            # from the first parking to the proposal that was granted
+            DelayProfiler.add_total("w.window_wait", waited, n_waited)
+        with self._stat_lock:
+            self.n_proposed += len(rows)
+        thr = np.flatnonzero(np.asarray(res.throttled))
+        if len(thr):
+            self._park_window_full(rows, req_ids, flag_parts, pay_parts,
+                                   thr, now)
         rej = np.asarray(res.rejected)
         if rej.any():
             for i in np.flatnonzero(rej):
@@ -3382,6 +3522,11 @@ class PaxosNode:
                 crc=self._wal_crc) \
                 if len(ai) else None
             in_reply = keep & ~ow_m
+            if ow_m.any():
+                ow = np.flatnonzero(ow_m)
+                self._requeue_ahead(objs, gkeys[ow], ow, slots_all[ow],
+                                    bals_all[ow], reqs_all[ow],
+                                    send_all[ow])
             acked_u8 = acked_m.astype(np.uint8)
             if wal_buf is not None:
                 # durability barrier: fsync before replies leave.  If
@@ -3474,6 +3619,10 @@ class PaxosNode:
                 slots[ai], bals[ai], req_ids[ai], blobs,
                 crc=self._wal_crc)
 
+        ow = np.flatnonzero(np.asarray(res.out_window))
+        if len(ow):
+            self._requeue_ahead(objs, gkeys[idxs[ow]], idxs[ow], slots[ow],
+                                bals[ow], req_ids[ow], senders[ow])
         # group replies per coordinator sender (vectorized per dst)
         in_reply = ~np.asarray(res.out_window)
         reply_bal = np.where(acked, bals, np.asarray(res.cur_bal))
@@ -3511,6 +3660,28 @@ class PaxosNode:
                 reply_bal[m].astype(np.int32), acked_u8[m])))
         for dst, arb in out:
             self._route(dst, arb)
+
+    def _requeue_ahead(self, objs, gkeys, lanes, slots, bals, req_ids,
+                       senders) -> None:
+        """Accept lanes the engine returned as beyond the window
+        (``out_window``: "host must requeue").  A coordinator that fills
+        its window the moment its cursor moves sends the commits of one
+        round and the accepts of the next back to back, and a worker
+        batch applies its accepts before its commits: the accepts are kept
+        (``lanes``: their places in ``objs``, for the payloads) and
+        :meth:`_process` offers them once more when the batch's commits
+        have moved the cursor.  Without that the coordinator's re-drive
+        brings them, a second later."""
+        ahead = self._acc_ahead
+        if ahead is None:
+            return  # outside _process, or this was the second offer
+        pls = _lane_payloads(objs, lanes)
+        lo, hi = _split_reqs(req_ids)
+        for dst in np.unique(senders):
+            m = senders == dst
+            ahead.append(pkt.AcceptBatch(
+                int(dst), gkeys[m], slots[m], bals[m], lo[m], hi[m],
+                payloads=[pls[k] for k in np.flatnonzero(m)]))
 
     def _acc_com_pre(self, accepts: List, commits: List):
         """Shared lane gather + host pre halves for the two acceptor-
@@ -3765,8 +3936,7 @@ class PaxosNode:
         for i in ii.tolist():
             dec.setdefault(int(rows[i]), {})[int(slots[i])] = \
                 int(reqs[i])
-        for row in np.unique(rows[ii]):
-            self._execute_row(int(row))
+        self._execute_rows(np.unique(rows[ii]))
 
     # -- commits → execution -------------------------------------------
 
@@ -3798,8 +3968,7 @@ class PaxosNode:
             for i in range(len(ex_rows)):
                 dec.setdefault(int(ex_rows[i]), {})[int(ex_slots[i])] = \
                     int(ex_reqs[i])
-            for row in np.unique(ex_rows):
-                self._execute_row(int(row))
+            self._execute_rows(np.unique(ex_rows))
             for i in np.flatnonzero(ow_m):
                 self._sync_if_gap(int(rows[i]))
             return
@@ -3841,17 +4010,36 @@ class PaxosNode:
             self._dec.setdefault(int(rows_s[i]), {})[int(slots_s[i])] = \
                 int(reqs_s[i])
         # execute newly contiguous decisions per touched row
-        for row in np.unique(rows_s):
-            self._execute_row(int(row))
+        self._execute_rows(np.unique(rows_s))
         # out-of-window commits: requeue once the window advances — here
         # simply re-enqueue; window advance is driven by this same path
         for i in np.flatnonzero(np.asarray(res.out_window)):
             self._sync_if_gap(int(rows_s[i]))
 
-    def _execute_row(self, row: int) -> None:
+    def _execute_rows(self, rows) -> None:
+        """The execute loop of a worker batch: what is newly contiguous
+        on each of ``rows``, under one span.  A loop that executed
+        nothing is no call of the span's sum."""
+        moved = self._window_moved
+        with span("app.execute", node=self.id, n=0) as sp:
+            items = reply_bytes = 0
+            for row in rows.tolist():
+                n, nb = self._execute_row(row)
+                items += n
+                reply_bytes += nb
+                if n and moved is not None and row in self._parked:
+                    moved.append(row)  # its window has room again
+            sp.n = items
+            if not items:
+                sp.total = ""
+            sp.note(items=items, reply_bytes=reply_bytes)
+
+    def _execute_row(self, row: int) -> Tuple[int, int]:
+        """Execute ``row``'s decisions from its cursor on while they are
+        contiguous: (requests executed, bytes of their replies)."""
         meta = self.table.by_row(row)
         if meta is None:
-            return
+            return 0, 0
         cur = int(self._cur[row])
         dec = self._dec.get(row)
         if dec is None:
@@ -3863,7 +4051,7 @@ class PaxosNode:
         P, PO = self._payloads, self._payloads_old
         ER, RC = self._executed_recent, self._resp_cache
         CW, PR = self._client_wait, self._proposed
-        n_exec = 0
+        n_exec = n_bytes = 0
         while cur in dec:
             req_id = dec[cur]
             got = P.pop(req_id, None)
@@ -3919,6 +4107,7 @@ class PaxosNode:
                 if flags & FLAG_STOP:
                     self._group_stopped.add(row)
             n_exec += 1
+            n_bytes += len(resp)
             PR.pop(req_id, None)
             if self._forced_traces:
                 self._forced_traces.discard(req_id)
@@ -3964,6 +4153,7 @@ class PaxosNode:
         last = int(self._ckpt[row])
         if cur - 1 - last >= self.checkpoint_interval:
             self._checkpoint_row(row, cur - 1)
+        return n_exec, n_bytes
 
     def _checkpoint_row(self, row: int, upto_slot: int) -> None:
         meta = self.table.by_row(row)

@@ -33,7 +33,7 @@ import threading
 from typing import Callable, Dict
 
 from gigapaxos_tpu.paxos.interfaces import (CounterApp, KVApp, NoopApp,
-                                            Replicable)
+                                            RecordApp, Replicable)
 from gigapaxos_tpu.reconfiguration.node import NodeConfig, ReconfigurableNode
 from gigapaxos_tpu.utils.logutil import get_logger
 
@@ -43,6 +43,7 @@ _BUILTIN_APPS: Dict[str, Callable[[], Replicable]] = {
     "NoopApp": NoopApp,
     "CounterApp": CounterApp,
     "KVApp": KVApp,
+    "RecordApp": RecordApp,
 }
 
 

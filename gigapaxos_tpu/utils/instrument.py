@@ -58,6 +58,9 @@ after a profiled window it holds that window and only that.
     gp.wal *         log_raw_inline (lock, append, sync)  entries, seg,
                                                           bytes
     gp.wal.fsync *   the os.fsync alone (inside gp.wal)
+    gp.app.execute   _execute_rows: a batch's execute loop   items,
+                     (a call of its sum only where something  reply_bytes
+                     executed)
     gp.eng.compile * a kernel's trace (EngineLedger.traced)  kernel
     gp.w.decode_blocked / gp.w.emit_blocked   hand-offs of the
                      pipelined and sharded loops
