@@ -448,13 +448,15 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
         jnp.stack([slot, rlo, rhi], axis=-1), mode="drop",
         unique_indices=alone)
 
-    # contiguity advance over the touched rows only ([B, W] gathers)
+    # contiguity advance over the touched rows only (one [B, W] row
+    # gather, read where it lies): column c is d = (c - cursor) mod W
+    # ahead of the cursor and in order iff it holds slot cursor + d; the
+    # advance is the least d over the columns that are not, W if all are
     dslotr = dec[gi, :, DEC_SLOT]
-    k = jnp.arange(W, dtype=i32)[None, :]
-    want = cursor[:, None] + k
-    col = want % W
-    ok = jnp.take_along_axis(dslotr, col, axis=1) == want
-    adv = jnp.sum(jnp.cumprod(ok.astype(i32), axis=1), axis=1)
+    c = jnp.arange(W, dtype=i32)[None, :]
+    d = (c - cursor[:, None]) % W
+    ok = dslotr == cursor[:, None] + d
+    adv = jnp.min(jnp.where(ok, W, d), axis=1)
     new_cur = cursor + adv
 
     if runs is None:

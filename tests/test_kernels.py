@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import pytest
 
 from gigapaxos_tpu.ops import kernels, make_state, pack_ballot
-from gigapaxos_tpu.ops.types import join_req_id, split_req_id, NO_SLOT
+from gigapaxos_tpu.ops.types import (DEC_SLOT, NO_SLOT, join_req_id,
+                                     split_req_id)
 from gigapaxos_tpu.ops.oracle import make_oracle_group, PValue
 
 B = 4  # fixed lane count -> one jit cache entry per kernel
@@ -369,6 +370,97 @@ def test_inactive_rows_ignore_everything():
     assert not applied
     st, _, _ = n.propose(5, 9)
     assert st == "inactive"
+
+
+# --------------------------------------------------------------------------
+# the commit stage's frontier advance alone: rows set as a window can hold
+# them, one commit a row, against the oracle's frontier on the same commits
+# --------------------------------------------------------------------------
+
+_NEAR_2_30 = (1 << 30) - 2  # cursors and frontiers on both sides of 2^30
+
+
+def _advance_rows(kind, Wn):
+    """``(cursor, {column: slot it holds}, slot committed)`` a row.  A
+    column only ever holds a slot of its own residue: the slot it is
+    expected to hold, the one a lap before (stale), the one a lap ahead,
+    or ``NO_SLOT``.  Every kind but ``cursor_0`` takes a cursor at every
+    residue of ``Wn``."""
+
+    def ahead(cur, ds, lap=0):
+        return {(cur + d) % Wn: cur + d + lap * Wn for d in ds}
+
+    rows = []
+    base = {"cursor_0": [0], "cursor_near_2_30": range(
+        _NEAR_2_30, _NEAR_2_30 + Wn)}.get(kind, range(3 * Wn, 4 * Wn))
+    for cur in base:
+        for h in range(Wn):
+            rest = set(range(Wn)) - {h}
+            if kind == "all_decided":  # the last one arrives: advance W
+                rows.append((cur, ahead(cur, rest), cur + h))
+            elif kind == "none":  # 1 at the cursor, else 0
+                rows.append((cur, {}, cur + h))
+            elif kind in ("hole", "cursor_0", "cursor_near_2_30"):
+                # all but cursor + h decided, the cursor's arrives
+                rows.append((cur, ahead(cur, rest - {0}), cur))
+            elif kind == "stale":  # the hole and all behind it a lap old
+                rows.append((cur, {**ahead(cur, range(h)),
+                                   **ahead(cur, range(h, Wn), -1)}, cur))
+            elif kind == "future":  # the hole holds the next lap's slot
+                rows.append((cur, {**ahead(cur, rest), **ahead(cur, [h], 1)},
+                             cur))
+            elif kind == "no_slot":  # decided below h, nothing from h on
+                rows.append((cur, ahead(cur, range(h)), cur + h))
+        if kind == "mixed":
+            rng = np.random.default_rng(cur)
+            for _ in range(Wn):
+                cells = {}
+                for d in range(Wn):
+                    lap = rng.choice([0, 0, 0, -1, 1, None])
+                    if lap is not None:
+                        cells.update(ahead(cur, [d], int(lap)))
+                rows.append((cur, cells, cur + int(rng.integers(-1, Wn + 1))))
+    return rows
+
+
+@pytest.mark.parametrize("Wn", [4, 5, 8, 16])
+@pytest.mark.parametrize("kind", [
+    "all_decided", "none", "hole", "stale", "future", "no_slot", "cursor_0",
+    "cursor_near_2_30", "mixed"])
+def test_commit_advance_equals_oracle_frontier(kind, Wn):
+    rows = _advance_rows(kind, Wn)
+    Bn = Wn * Wn  # one shape a W, whatever the kind
+    assert 0 < len(rows) <= Bn
+    dslot = np.full((Bn, Wn), NO_SLOT, np.int32)
+    cursor = np.zeros((Bn,), np.int32)
+    slot = np.zeros((Bn,), np.int32)
+    want = []
+    for i, (cur, cells, s) in enumerate(rows):
+        cursor[i], slot[i] = cur, s
+        for c, v in cells.items():
+            assert v % Wn == c
+            dslot[i, c] = v
+        og = make_oracle_group(3, Wn, pack_ballot(0, 0), False)
+        og.exec_cursor = cur
+        # a column holds one slot: what the commit stores takes its place
+        og.decided = {int(v): 1 for c, v in cells.items()
+                      if not (c == s % Wn and cur <= s < cur + Wn)}
+        want.append(og.commit(s, 1))
+    st = make_state(Bn, Wn)
+    st = st._replace(
+        active=jnp.ones((Bn,), jnp.bool_), exec_cursor=jnp.asarray(cursor),
+        dec=st.dec.at[:, :, DEC_SLOT].set(jnp.asarray(dslot)))
+    valid = jnp.arange(Bn) < len(rows)
+    one = jnp.ones((Bn,), i32)
+    st, o = kernels.commit(st, jnp.arange(Bn, dtype=i32), jnp.asarray(slot),
+                           one, one, valid)
+    got = list(zip(*(np.asarray(a)[:len(rows)].tolist() for a in (
+        o.applied, o.stale, o.out_window, o.new_cursor))))
+    assert got == want
+    assert np.asarray(st.exec_cursor)[:len(rows)].tolist() == [
+        w[3] for w in want]
+    if kind == "all_decided":
+        assert all(w[3] == r[0] + Wn for w, r in zip(want, rows))
 
 
 # --------------------------------------------------------------------------

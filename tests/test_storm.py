@@ -135,7 +135,8 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     lane-shaped array (what un-permuting a rank is), and 17 scatters that
     always run: propose 2, accept 2 x R, accept-reply 1 x R, commit
     2 x R; the 2 x R resign scatters sit under a cond.  Every one that
-    always runs states that its indices are unique."""
+    always runs states that its indices are unique.  No gather reads a
+    lane-shaped operand and no scan runs along a window row."""
     import jax
     import jax.numpy as jnp
     from gigapaxos_tpu.ops.storm import decide_storm_step
@@ -156,6 +157,16 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     for e in always:
         assert e.invars[0].aval.shape[0] == G, e  # never a [B] array
         assert e.params["unique_indices"], e
+    # every gather reads a state array and every scan runs along the
+    # lanes: the commit stage counts its frontier's advance on the
+    # [B, W] row where it lies, with a compare and a row min
+    gathers = [e for n, _, e in eqns if n == "gather"]
+    assert gathers
+    for e in gathers:
+        assert e.invars[0].aval.shape[0] == G, e
+    for n, _, e in eqns:
+        if n.startswith("cum"):
+            assert e.invars[0].aval.shape == (B,), e
 
 
 def test_sharded_storm_on_virtual_mesh():
