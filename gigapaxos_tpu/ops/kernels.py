@@ -54,10 +54,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from gigapaxos_tpu.ops.types import (ACC_BAL, ACC_RHI, ACC_RLO, ACC_SLOT,
-                                     ColumnarState, DEC_SLOT, EMITTED_BIT,
-                                     NO_BALLOT, NO_SLOT, PROP_RHI, PROP_RLO,
-                                     PROP_SLOT, PROP_VOTES, VOTE_MASK)
+from gigapaxos_tpu.ops.types import (ColumnarState, EMITTED_BIT, NO_BALLOT,
+                                     NO_SLOT, PLANES, RowState, VOTE_MASK)
 
 i32 = jnp.int32
 u32 = jnp.uint32
@@ -182,6 +180,51 @@ def _sd(g, keep, G):
     return jnp.where(keep, g, G + jnp.arange(g.shape[0], dtype=i32))
 
 
+def _set_words(state: ColumnarState, idx, words: dict, unique: bool):
+    """One one-word set per component plane, all at the flat word index
+    ``idx`` (``row * W + w``; a dropped lane's row is out of bounds, so
+    its word is).  The v5e writes a word of a linear plane in place, on
+    the donated operand, at 6.4 ns a word where a four-word row of a
+    packed ``[G, W, 4]`` plane cost 81 ns, and nothing relays the plane
+    out for it (PERF.md §6, PR 32)."""
+    return {f: getattr(state, f).at[idx].set(
+        v, mode="drop", unique_indices=unique) for f, v in words.items()}
+
+
+def _window_words(rows, W):
+    """``[n, W]``: the flat index of every word of each row's window (out
+    of bounds for a row that is)."""
+    return rows[:, None] * W + jnp.arange(W, dtype=rows.dtype)
+
+
+_LANES = 128  # words in a row of the v5e's (8, 128) tile
+
+
+def _frontier_advance(dec_slot, gi, cursor, W):
+    """How far each lane's group frontier moves: the columns of its
+    window are read in place and counted with a compare and a row min.
+    Column c is d = (c - cursor) mod W ahead of the cursor and in order
+    iff it holds slot cursor + d; the advance is the least d over the
+    columns that are not, W if all are.
+
+    The W words of a group are consecutive in the linear plane, so they
+    are read as part of ONE row gather a lane.  The row is a whole tile
+    row (128 words, ``per`` groups) wherever groups pack into such rows:
+    seen as ``[G * W / 128, 128]`` the plane keeps its linear order (a
+    bitcast on the v5e, where ``[G, W]`` with W < 128 is another
+    physical order and costs a copy of the plane a stage), and the
+    columns of the row's other groups are masked out.  Otherwise the row
+    is the group's own W words."""
+    G = dec_slot.shape[0] // W
+    per = _LANES // W if _LANES % W == 0 and G % (_LANES // W) == 0 else 1
+    rows = dec_slot.reshape(-1, per * W)[gi // per]
+    c = jnp.arange(per * W, dtype=i32)[None, :]
+    mine = c // W == (gi % per)[:, None]
+    d = (c % W - cursor[:, None]) % W
+    ok = rows == cursor[:, None] + d
+    return jnp.min(jnp.where(mine & ~ok, d, W), axis=1)
+
+
 # --------------------------------------------------------------------------
 # accept (acceptor side)                                  ref: PaxosAcceptor
 # --------------------------------------------------------------------------
@@ -217,13 +260,12 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
     store = promised_ok & in_win
 
     w = jnp.where(store, slot % W, 0)
-    # ONE multi-component scatter for the whole stored pvalue (the
-    # scatter op, not its payload width, is what XLA:CPU serializes on);
-    # one accept lane per (group, slot) a batch: distinct columns
-    sgw = _si(g, store, G) if runs is None else _sd(g, store, G)
-    acc = state.acc.at[sgw, w].set(
-        jnp.stack([slot, bal, rlo, rhi], axis=-1), mode="drop",
-        unique_indices=runs is not None)
+    # the stored pvalue, a word a component plane; one accept lane per
+    # (group, slot) a batch: distinct columns
+    sg = _si(g, store, G) if runs is None else _sd(g, store, G)
+    acc = _set_words(state, sg * W + w, dict(
+        acc_slot=slot, acc_bal=bal, acc_rlo=rlo, acc_rhi=rhi),
+        unique=runs is not None)
 
     out = AcceptOut(
         acked=store | (promised_ok & stale),
@@ -231,7 +273,7 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
         out_window=promised_ok & ~in_win & ~stale,
         cur_bal=cur_bal,
     )
-    state = state._replace(bal=new_bal, acc=acc)
+    state = state._replace(bal=new_bal, **acc)
     return state, out
 
 
@@ -261,21 +303,25 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     (group, slot) column: its vote is the whole batch's, nothing is
     gathered again and nothing deduped, and vote and emitted bit ride ONE
     scatter.
+
+    The proposal column is read a word a plane at the flat index the
+    votes are added at; a caller that drops ``req_lo`` / ``req_hi`` (the
+    storm step) drops their two gathers with them.
     """
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
     w = jnp.where(valid, slot % W, 0)
+    idx = gi * W + w
 
     coord_here = state.is_coord[gi] & state.coord_active[gi]
     is_rel = valid & coord_here & (bal == state.cbal[gi])
-    propc = state.prop[gi, w]  # [B, 4] pre-batch proposal columns
     # slot >= 0 guards against matching uninitialized vote columns
-    # (PROP_SLOT inits to NO_SLOT = -1)
-    match = is_rel & acked & (slot >= 0) & (propc[:, PROP_SLOT] == slot)
+    # (prop_slot inits to NO_SLOT = -1)
+    match = is_rel & acked & (slot >= 0) & (state.prop_slot[idx] == slot)
 
     sender_i = sender.astype(i32)
     bit = jnp.left_shift(i32(1), sender_i)
-    prev = propc[:, PROP_VOTES]
+    prev = state.prop_votes[idx]  # pre-batch
     fresh = match & (jnp.bitwise_and(jnp.right_shift(prev, sender_i),
                                      1) == 0)
 
@@ -289,18 +335,18 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         vote = jnp.where(fresh, bit, 0)
         newly = crossed(prev + vote) & (jnp.bitwise_and(prev,
                                                         EMITTED_BIT) == 0)
-        prop = state.prop.at[_sd(g, fresh | newly, G), w, PROP_VOTES].add(
-            vote + jnp.where(newly, EMITTED_BIT, 0), mode="drop",
-            unique_indices=True)
+        votes = state.prop_votes.at[
+            _sd(g, fresh | newly, G) * W + w].add(
+                vote + jnp.where(newly, EMITTED_BIT, 0), mode="drop",
+                unique_indices=True)
     else:
-        sgw = _si(g, fresh, G)
-        prop = state.prop.at[sgw, w, PROP_VOTES].add(
+        votes = state.prop_votes.at[_si(g, fresh, G) * W + w].add(
             jnp.where(fresh, bit, 0), mode="drop")
         # re-gather POST-scatter so every lane of a (group, slot) column
         # sees the whole batch's votes (two fresh votes in one batch must
         # still cross quorum); `fresh` guarantees no bit is added twice,
         # so the add never carries into EMITTED_BIT
-        quorum = crossed(prop[gi, w, PROP_VOTES])
+        quorum = crossed(votes[idx])
         # Exactly-once emission: besides the cross-batch EMITTED_BIT,
         # dedupe WITHIN the batch — when two replies for the same (group,
         # slot) cross quorum in one batch, only the first lane emits the
@@ -313,7 +359,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         emitted_prev = jnp.bitwise_and(prev, EMITTED_BIT) != 0
         newly = quorum & ~emitted_prev & ~dup_before
         # `newly` is true at most once per column ever, so the add is an OR
-        prop = prop.at[_si(g, newly, G), w, PROP_VOTES].add(
+        votes = votes.at[_si(g, newly, G) * W + w].add(
             jnp.where(newly, EMITTED_BIT, 0), mode="drop")
 
     # Preemption: a nack carrying a ballot above ours ends our reign
@@ -336,10 +382,10 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         preempted=pre,
         dec_slot=slot,
         dec_bal=state.cbal[gi],
-        req_lo=propc[:, PROP_RLO],
-        req_hi=propc[:, PROP_RHI],
+        req_lo=state.prop_rlo[idx],
+        req_hi=state.prop_rhi[idx],
     )
-    state = state._replace(prop=prop, is_coord=is_coord,
+    state = state._replace(prop_votes=votes, is_coord=is_coord,
                            coord_active=coord_active)
     return state, out
 
@@ -394,13 +440,13 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
                 slot + 1, mode="drop", unique_indices=True)
 
     # initialize the proposal column for the assigned slot: slot, req id,
-    # zero votes/emitted — ONE multi-component scatter; the slots of a
-    # group are distinct and inside one window: distinct columns
+    # zero votes/emitted, a word a component plane; the slots of a group
+    # are distinct and inside one window: distinct columns
     w = jnp.where(granted, slot % W, 0)
-    sgw = _si(g, granted, G) if runs is None else _sd(g, granted, G)
-    prop = state.prop.at[sgw, w].set(
-        jnp.stack([slot, rlo, rhi, jnp.zeros_like(slot)], axis=-1),
-        mode="drop", unique_indices=runs is not None)
+    sg = _si(g, granted, G) if runs is None else _sd(g, granted, G)
+    prop = _set_words(state, sg * W + w, dict(
+        prop_slot=slot, prop_rlo=rlo, prop_rhi=rhi,
+        prop_votes=jnp.zeros_like(slot)), unique=runs is not None)
 
     out = ProposeOut(
         granted=granted,
@@ -410,7 +456,7 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
         slot=slot,
         cbal=state.cbal[gi],
     )
-    state = state._replace(next_slot=next_slot, prop=prop)
+    state = state._replace(next_slot=next_slot, **prop)
     return state, out
 
 
@@ -440,24 +486,15 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
     # a commit batch may repeat a (group, slot): the columns are distinct
     # only where the caller vouches for it
     alone = runs is not None and runs.distinct_slots
-    sgw = _sd(g, store, G) if alone else _si(g, store, G)
+    sg = _sd(g, store, G) if alone else _si(g, store, G)
 
-    # ONE multi-component scatter; "decided" is DEC_SLOT == expected slot
+    # a word a component plane; "decided" is dec_slot == expected slot
     # (NO_SLOT never matches), so no separate flag plane exists
-    dec = state.dec.at[sgw, w].set(
-        jnp.stack([slot, rlo, rhi], axis=-1), mode="drop",
-        unique_indices=alone)
+    dec = _set_words(state, sg * W + w, dict(
+        dec_slot=slot, dec_rlo=rlo, dec_rhi=rhi), unique=alone)
 
-    # contiguity advance over the touched rows only (one [B, W] row
-    # gather, read where it lies): column c is d = (c - cursor) mod W
-    # ahead of the cursor and in order iff it holds slot cursor + d; the
-    # advance is the least d over the columns that are not, W if all are
-    dslotr = dec[gi, :, DEC_SLOT]
-    c = jnp.arange(W, dtype=i32)[None, :]
-    d = (c - cursor[:, None]) % W
-    ok = dslotr == cursor[:, None] + d
-    adv = jnp.min(jnp.where(ok, W, d), axis=1)
-    new_cur = cursor + adv
+    # contiguity advance over the touched rows only
+    new_cur = cursor + _frontier_advance(dec["dec_slot"], gi, cursor, W)
 
     if runs is None:
         sg = _si(g, store, G)
@@ -473,7 +510,7 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
         out_window=valid & act & (slot >= cursor + W),
         new_cursor=exec_cursor[gi],
     )
-    state = state._replace(dec=dec, exec_cursor=exec_cursor)
+    state = state._replace(exec_cursor=exec_cursor, **dec)
     return state, out
 
 
@@ -506,15 +543,15 @@ def prepare_batch(state: ColumnarState, g, bal, valid):
     cur_bal = new_bal[gi]
     acked = live & (bal >= cur_bal)
 
-    accr = state.acc[gi]  # [B, W, 4]
+    widx = _window_words(gi, W)  # a cold path: a word a gather index
     out = PrepareOut(
         acked=acked,
         cur_bal=cur_bal,
         exec_cursor=state.exec_cursor[gi],
-        win_slot=accr[..., ACC_SLOT],
-        win_bal=accr[..., ACC_BAL],
-        win_req_lo=accr[..., ACC_RLO],
-        win_req_hi=accr[..., ACC_RHI],
+        win_slot=state.acc_slot[widx],
+        win_bal=state.acc_bal[widx],
+        win_req_lo=state.acc_rlo[widx],
+        win_req_hi=state.acc_rhi[widx],
     )
     return state._replace(bal=new_bal), out
 
@@ -546,13 +583,14 @@ def install_coordinator_batch(state: ColumnarState, g, cbal, next_slot,
     has = valid[:, None] & (carry_slot >= 0)
     w = jnp.where(has, carry_slot % W, 0)
     sg = jnp.where(has, g[:, None], G)
-    prop = state.prop.at[sg, w].set(
-        jnp.stack([carry_slot, carry_rlo, carry_rhi,
-                   jnp.zeros_like(carry_slot)], axis=-1), mode="drop")
+    prop = _set_words(state, (sg * W + w).reshape(-1), dict(
+        prop_slot=carry_slot.reshape(-1), prop_rlo=carry_rlo.reshape(-1),
+        prop_rhi=carry_rhi.reshape(-1),
+        prop_votes=jnp.zeros((carry_slot.size,), i32)), unique=False)
 
     state = state._replace(
         is_coord=is_coord, coord_active=coord_active, cbal=cbal_arr,
-        next_slot=ns, prop=prop,
+        next_slot=ns, **prop,
     )
     return state, None
 
@@ -575,19 +613,20 @@ def create_groups_batch(state: ColumnarState, rows, members, version,
     G, W = state.G, state.W
     si = _si(rows, valid, G)
     vT = valid
-    B = rows.shape[0]
-
-    def plane(cols):
-        return jnp.broadcast_to(jnp.asarray(cols, i32), (B, W, len(cols)))
+    # every word of a created row's window, fresh: one dense pass a plane
+    # under the created rows' mask (a creation wave is any number of rows,
+    # the whole fleet at once in the storm's set-up; a pass is the same
+    # few hundred microseconds for all of them)
+    made = jnp.zeros((G,), jnp.bool_).at[si].set(True, mode="drop")
+    made = jnp.broadcast_to(made[:, None], (G, W)).reshape(-1)
+    fresh = {f: jnp.where(made, i32(v), getattr(state, f))
+             for cols in PLANES.values() for f, v in cols}
 
     state = state._replace(
         active=state.active.at[si].set(True, mode="drop"),
         members=state.members.at[si].set(members, mode="drop"),
         version=state.version.at[si].set(version, mode="drop"),
         bal=state.bal.at[si].set(init_bal, mode="drop"),
-        acc=state.acc.at[si].set(plane([NO_SLOT, NO_BALLOT, 0, 0]),
-                                 mode="drop"),
-        dec=state.dec.at[si].set(plane([NO_SLOT, 0, 0]), mode="drop"),
         exec_cursor=state.exec_cursor.at[si].set(0, mode="drop"),
         gc_slot=state.gc_slot.at[si].set(NO_SLOT, mode="drop"),
         is_coord=state.is_coord.at[si].set(vT & self_coord, mode="drop"),
@@ -597,8 +636,7 @@ def create_groups_batch(state: ColumnarState, rows, members, version,
                                              NO_BALLOT), mode="drop"),
         next_slot=state.next_slot.at[si].set(0, mode="drop"),
         prep_votes=state.prep_votes.at[si].set(u32(0), mode="drop"),
-        prop=state.prop.at[si].set(plane([NO_SLOT, 0, 0, 0]),
-                                   mode="drop"),
+        **fresh,
     )
     return state, None
 
@@ -640,18 +678,31 @@ def gc_batch(state: ColumnarState, rows, upto, valid):
 # --------------------------------------------------------------------------
 
 
-def gather_rows(state: ColumnarState, rows):
-    """Pull full per-row state for ``rows`` to a pytree of [B,...] arrays."""
-    return jax.tree_util.tree_map(lambda a: a[rows], state)
+def gather_rows(state: ColumnarState, rows) -> RowState:
+    """Pull full per-row state for ``rows``, in the row form: ``[n]``
+    fields, and the window planes packed as ``[n, W, k]``."""
+    rows = jnp.asarray(rows)
+    widx = _window_words(rows, state.W)
+    return RowState(**{
+        f: jnp.stack([getattr(state, c)[widx] for c, _ in PLANES[f]],
+                     axis=-1) if f in PLANES else getattr(state, f)[rows]
+        for f in RowState._fields})
 
 
-def scatter_rows(state: ColumnarState, rows, row_state: ColumnarState,
-                 valid):
+def scatter_rows(state: ColumnarState, rows, row_state: RowState, valid):
     """Write previously gathered rows back (unpause)."""
-    G = state.G
+    G, W = state.G, state.W
     si = _si(rows, valid, G)
-    return jax.tree_util.tree_map(
-        lambda a, r: a.at[si].set(r, mode="drop"), state, row_state), None
+    widx = _window_words(si, W).reshape(-1)
+    new = {}
+    for f, r in row_state._asdict().items():
+        if f in PLANES:
+            for k, (c, _) in enumerate(PLANES[f]):
+                new[c] = getattr(state, c).at[widx].set(
+                    r[..., k].reshape(-1), mode="drop")
+        else:
+            new[f] = getattr(state, f).at[si].set(r, mode="drop")
+    return state._replace(**new), None
 
 
 # --------------------------------------------------------------------------
@@ -800,8 +851,10 @@ def accept_commit_packed(state: ColumnarState, acc, com):
 # --------------------------------------------------------------------------
 
 # State buffers are donated: each call consumes the old state arrays and
-# reuses them in-place (XLA aliasing), which is what keeps 1M-group state
-# resident with zero copies per batch.
+# reuses them in place (XLA aliasing).  With the window planes linear and
+# addressed a word at a time that holds for the program the v5e compiles
+# too: a plane is only ever the donated operand of a scatter, so a wave
+# copies no plane (tests/test_chip_compile.py asserts it).
 #
 # Every entry routes its traced function through the EngineLedger so the
 # flight deck counts compiles/retraces per kernel; the wrapper body runs

@@ -1,9 +1,10 @@
 """shard_map variants of the columnar kernels (device-mesh engine).
 
-Every per-group plane of :class:`~gigapaxos_tpu.ops.types.ColumnarState`
-(``acc[G, W, 4]``/``dec[G, W, 3]``/``prop[G, W, 4]``, the ballot/cursor
-mirrors, the vote bitmaps) is sharded on its leading (group) axis over a
-1-D ``Mesh`` named :data:`GROUP_AXIS`; batch lanes stay replicated.  The
+Every leaf of :class:`~gigapaxos_tpu.ops.types.ColumnarState` (the
+``[G]`` ballot/cursor mirrors and vote bitmaps, and the eleven linear
+``[G * W]`` window-plane components, which a cut on axis 0 divides into
+whole groups) is sharded on its one axis over a 1-D ``Mesh`` named
+:data:`GROUP_AXIS`; batch lanes stay replicated.  The
 per-wave kernels run as explicit ``shard_map`` programs: each shard owns
 a contiguous block of ``Gs = G / D`` rows, masks the batch down to the
 lanes it owns, rewrites their row indices to shard-local ones, and runs

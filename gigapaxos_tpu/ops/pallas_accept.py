@@ -3,8 +3,8 @@
 Reference analog: ``PaxosAcceptor.acceptAndUpdateBallot`` — the
 ballot-compare + window-store transition that every AcceptPacket hits
 (SURVEY.md §3.1).  The XLA path (``kernels.accept_batch``) expresses it
-as a ballot scatter-max plus one multi-component scatter into the
-packed ``[G, W, 4]`` acc plane; this kernel fuses
+as a ballot scatter-max plus a one-word scatter into each of the four
+linear acc component planes; this kernel fuses
 the whole transition into ONE pass that DMAs each touched 8-row block
 to VMEM once, applies every lane aimed at it, and writes it back.
 
@@ -50,8 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gigapaxos_tpu.ops.types import (ACC_BAL, ACC_RHI, ACC_RLO, ACC_SLOT,
-                                     NO_BALLOT, NO_SLOT, ColumnarState)
+from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT, ColumnarState
 
 i32 = jnp.int32
 SUB = 8  # octile height; Mosaic's sublane granule for i32
@@ -127,12 +126,17 @@ def _pid():
                    donate_argnums=(1, 10, 11, 12, 13))
 def _accept_blocks(blocks, bal, active, cursor, slotL, balL, rloL, rhiL,
                    subL, validL, abal, aslot, alo, ahi, interpret: bool):
-    """One fused pass: Rb distinct octiles, up to L lanes each."""
+    """One fused pass: Rb distinct octiles, up to L lanes each.  The
+    four acc component planes come and go as they lie (``[G * W]``); the
+    kernel's blocks see them as ``[G, W]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     Rb, L = slotL.shape
-    G, W = abal.shape
+    G = bal.shape[0]
+    abal, aslot, alo, ahi = (a.reshape(G, -1)
+                             for a in (abal, aslot, alo, ahi))
+    W = abal.shape[1]
     bal2 = bal.reshape(G, 1)
     act2 = active.astype(i32).reshape(G, 1)
     cur2 = cursor.reshape(G, 1)
@@ -173,7 +177,9 @@ def _accept_blocks(blocks, bal, active, cursor, slotL, balL, rloL, rhiL,
     )(blocks, slotL, balL, rloL, rhiL, subL, validL, bal2, act2, cur2,
       abal, aslot, alo, ahi)
     bal_n, abal_n, aslot_n, alo_n, ahi_n, lane_out = outs
-    return bal_n.reshape(G), abal_n, aslot_n, alo_n, ahi_n, lane_out
+    return (bal_n.reshape(G),
+            *(a.reshape(-1) for a in (abal_n, aslot_n, alo_n, ahi_n)),
+            lane_out)
 
 
 def group_lanes_by_block(rows: np.ndarray, L: int
@@ -266,11 +272,6 @@ class PallasAccept:
 
             blocks_p = np.pad(blocks_u.astype(np.int32), (0, pad_r),
                               constant_values=pad_block)
-            # unpack the acc plane to the kernel's per-component arrays
-            # (slices at the jit boundary; the packed layout exists for
-            # the XLA scatter path's sake — this opt-in kernel pays the
-            # split/restack instead)
-            acc = state.acc
             new = _accept_blocks(
                 jnp.asarray(blocks_p), state.bal, state.active,
                 state.exec_cursor, jnp.asarray(lanes(slot, NO_SLOT)),
@@ -278,11 +279,12 @@ class PallasAccept:
                 jnp.asarray(lanes(rlo, 0)), jnp.asarray(lanes(rhi, 0)),
                 jnp.asarray(lanes(np.asarray(g) % SUB, 0)),
                 jnp.asarray(lanes(np.ones(B, np.int32), 0)),
-                acc[:, :, ACC_BAL], acc[:, :, ACC_SLOT],
-                acc[:, :, ACC_RLO], acc[:, :, ACC_RHI], self.interpret)
+                state.acc_bal, state.acc_slot, state.acc_rlo,
+                state.acc_rhi, self.interpret)
             bal_n, abal_n, aslot_n, alo_n, ahi_n, lane_out = new
-            state = state._replace(bal=bal_n, acc=jnp.stack(
-                [aslot_n, abal_n, alo_n, ahi_n], axis=-1))
+            state = state._replace(bal=bal_n, acc_bal=abal_n,
+                                   acc_slot=aslot_n, acc_rlo=alo_n,
+                                   acc_rhi=ahi_n)
             lo = np.asarray(lane_out)[:R].reshape(R, 4, self.L)
             live = ~padded.reshape(R, self.L)
             flat = lane_index.reshape(-1)[live.reshape(-1)]

@@ -30,17 +30,26 @@ Design notes (TPU-first):
   (``EMITTED_BIT``) of the same word records "decision already emitted",
   capping groups at 30 replicas (the reference is practically ≤ ~10).
 
-- **Packed window planes.** Fields written by the same kernel stage at
-  the same (group, window) index live in ONE ``[G, W, k]`` array —
-  ``acc`` (slot, ballot, req lo/hi), ``dec`` (slot, req lo/hi) and
-  ``prop`` (slot, req lo/hi, votes|emitted) — so each stage issues ONE
-  multi-component scatter instead of 4-5 separate ones.  XLA:CPU
-  executes scatters as serial per-lane loops whose cost is per *op*,
-  not per byte (measured ~46 ms for a 256K-lane [1M, 16] scatter vs
-  ~55 ms for the same lanes into [1M, 16, 4]), so the packing cuts the
-  storm step's scatter budget ~4x.  A column's "decided" flag is
-  simply ``dec[..., DEC_SLOT] == slot`` (``NO_SLOT`` never matches a
-  real slot), which drops the old separate bool plane entirely.
+- **Window planes, one per component.** The acceptor's stored pvalue
+  (``acc``: slot, ballot, req lo/hi), the decided pvalue (``dec``: slot,
+  req lo/hi) and the coordinator's proposal (``prop``: slot, req lo/hi,
+  votes|emitted) are held as eleven LINEAR ``i32[G * W]`` planes, one per
+  component, and every hot stage addresses them by the one flat word
+  index ``g * W + (slot % W)``.  What the TPU v5e measured (PERF.md §6,
+  PRs 28-32): a four-word row write into a ``[G, W, 4]`` plane costs
+  81 ns a row in any order and under every flag, a one-word write into
+  a flat plane 6.4 ns; and no physical order of a
+  ``[G, W, k]`` array serves a row write, a one-word vote add and a
+  ``[B, k]`` read at once, so every stage had the compiler copy the
+  whole 268 MB plane into its own order and back.  A flat component
+  plane has one order (``{0:T(1024)}``) from parameter to result, and a
+  scatter updates its donated operand in place.  A column's "decided"
+  flag is simply ``dec_slot == slot`` (``NO_SLOT`` never matches a real
+  slot), so no separate bool plane exists.  The ``[G, W, k]`` packing
+  survives as the READ view (``state.acc`` / ``.dec`` / ``.prop``,
+  built on demand, cold path) and as the row exchange format
+  (:class:`RowState`: what ``gather_rows`` returns, what snapshots,
+  pause blobs and ``scatter_rows`` carry).
 
 - **Request ids.** The device stores only 64-bit request ids (two int32
   lanes); payload bytes stay host-side keyed by id, mirroring the
@@ -61,20 +70,30 @@ NODE_MASK = (1 << NODE_BITS) - 1
 NO_BALLOT = -1  # sorts below every packed ballot (packed values are >= 0)
 NO_SLOT = -1
 
-# --- packed window-plane column indices -------------------------------------
+# --- window-plane components: column indices of the [.., W, k] views -------
 
-# acc[G, W, 4]: the acceptor's stored pvalue per window column
+# acc[.., W, 4]: the acceptor's stored pvalue per window column
 ACC_SLOT, ACC_BAL, ACC_RLO, ACC_RHI = 0, 1, 2, 3
-# dec[G, W, 3]: decided pvalue per window column (decided <=> DEC_SLOT
+# dec[.., W, 3]: decided pvalue per window column (decided <=> DEC_SLOT
 # column holds the expected slot; NO_SLOT = never)
 DEC_SLOT, DEC_RLO, DEC_RHI = 0, 1, 2
-# prop[G, W, 4]: the coordinator's proposal per window column.  The
+# prop[.., W, 4]: the coordinator's proposal per window column.  The
 # PROP_VOTES word is the sender-vote bitmap (bits 0..29) with bit 30
 # recording "decision emitted" — one i32 so the reply path's vote +
 # emitted updates ride a single scatter.
 PROP_SLOT, PROP_RLO, PROP_RHI, PROP_VOTES = 0, 1, 2, 3
 EMITTED_BIT = 1 << 30
 VOTE_MASK = EMITTED_BIT - 1
+
+# the component planes of each view, in column order, with a fresh
+# column's value
+PLANES = {
+    "acc": (("acc_slot", NO_SLOT), ("acc_bal", NO_BALLOT), ("acc_rlo", 0),
+            ("acc_rhi", 0)),
+    "dec": (("dec_slot", NO_SLOT), ("dec_rlo", 0), ("dec_rhi", 0)),
+    "prop": (("prop_slot", NO_SLOT), ("prop_rlo", 0), ("prop_rhi", 0),
+             ("prop_votes", 0)),
+}
 
 
 def pack_ballot(num: int, coord: int):
@@ -92,7 +111,8 @@ def unpack_ballot(packed: int) -> Tuple[int, int]:
 
 
 class ColumnarState(NamedTuple):
-    """All-groups paxos state as device arrays.  Shapes: [G] or [G, W]."""
+    """All-groups paxos state as device arrays.  Shapes: ``[G]``, and
+    ``[G * W]`` for a window-plane component (word ``g * W + w``)."""
 
     # -- group table --
     active: jnp.ndarray        # bool[G]  row allocated
@@ -101,8 +121,13 @@ class ColumnarState(NamedTuple):
 
     # -- acceptor (ref: PaxosAcceptor.java) --
     bal: jnp.ndarray           # i32[G]   promised ballot (packed)
-    acc: jnp.ndarray           # i32[G,W,4] accepted pvalue plane (ACC_*)
-    dec: jnp.ndarray           # i32[G,W,3] decided pvalue plane (DEC_*)
+    acc_slot: jnp.ndarray      # i32[G*W] accepted pvalue: slot
+    acc_bal: jnp.ndarray       # i32[G*W]   ballot (packed)
+    acc_rlo: jnp.ndarray       # i32[G*W]   request id, low word
+    acc_rhi: jnp.ndarray       # i32[G*W]   request id, high word
+    dec_slot: jnp.ndarray      # i32[G*W] decided pvalue: slot
+    dec_rlo: jnp.ndarray       # i32[G*W]
+    dec_rhi: jnp.ndarray       # i32[G*W]
     exec_cursor: jnp.ndarray   # i32[G]   first not-known-decided contiguous slot
     gc_slot: jnp.ndarray       # i32[G]   checkpointed slot (log GC'd below)
 
@@ -112,7 +137,10 @@ class ColumnarState(NamedTuple):
     cbal: jnp.ndarray          # i32[G]   coordinator ballot (packed)
     next_slot: jnp.ndarray     # i32[G]   next slot to assign
     prep_votes: jnp.ndarray    # u32[G]   phase-1 prepare-reply bitmap
-    prop: jnp.ndarray          # i32[G,W,4] proposal plane (PROP_*)
+    prop_slot: jnp.ndarray     # i32[G*W] proposal: slot
+    prop_rlo: jnp.ndarray      # i32[G*W]
+    prop_rhi: jnp.ndarray      # i32[G*W]
+    prop_votes: jnp.ndarray    # i32[G*W]   vote bitmap | EMITTED_BIT
 
     @property
     def G(self) -> int:
@@ -120,41 +148,74 @@ class ColumnarState(NamedTuple):
 
     @property
     def W(self) -> int:
-        return self.acc.shape[1]
+        return self.acc_slot.shape[-1] // self.G
+
+    def _view(self, name):
+        return jnp.stack([getattr(self, f) for f, _ in PLANES[name]],
+                         axis=-1).reshape(self.G, self.W, -1)
+
+    # the [G, W, k] read views: a copy built on demand (cold path: tests,
+    # the storm driver's read-back); no kernel touches them
+    @property
+    def acc(self):
+        return self._view("acc")
+
+    @property
+    def dec(self):
+        return self._view("dec")
+
+    @property
+    def prop(self):
+        return self._view("prop")
+
+
+class RowState(NamedTuple):
+    """Rows of a state in the exchange form: what ``kernels.gather_rows``
+    returns and ``scatter_rows`` writes back, field for field what a
+    snapshot dict or a pause blob holds.  ``[n]``, and ``[n, W, k]`` for
+    the window planes (``ACC_*`` / ``DEC_*`` / ``PROP_*`` columns)."""
+
+    active: jnp.ndarray
+    members: jnp.ndarray
+    version: jnp.ndarray
+    bal: jnp.ndarray
+    acc: jnp.ndarray           # i32[n,W,4]
+    dec: jnp.ndarray           # i32[n,W,3]
+    exec_cursor: jnp.ndarray
+    gc_slot: jnp.ndarray
+    is_coord: jnp.ndarray
+    coord_active: jnp.ndarray
+    cbal: jnp.ndarray
+    next_slot: jnp.ndarray
+    prep_votes: jnp.ndarray
+    prop: jnp.ndarray          # i32[n,W,4]
 
 
 def make_state(G: int, W: int) -> ColumnarState:
     """Fresh all-inactive state.  G groups capacity, window width W."""
     i32 = jnp.int32
-    u32 = jnp.uint32
 
-    # NOTE: every field gets its OWN buffer — sharing one zeros array across
+    # NOTE: every field gets its OWN buffer — sharing one array across
     # fields breaks donate_argnums ("attempt to donate the same buffer
     # twice").
     def zG():
         return jnp.zeros((G,), i32)
 
-    def plane(cols):
-        # materialize (jnp.array) so each field owns its buffer — a
-        # broadcast view shared across fields breaks donate_argnums
-        return jnp.array(jnp.broadcast_to(
-            jnp.asarray(cols, i32), (G, W, len(cols))))
-
+    planes = {f: jnp.full((G * W,), fresh, i32)
+              for cols in PLANES.values() for f, fresh in cols}
     return ColumnarState(
         active=jnp.zeros((G,), jnp.bool_),
         members=zG(),
         version=zG(),
         bal=jnp.full((G,), NO_BALLOT, i32),
-        acc=plane([NO_SLOT, NO_BALLOT, 0, 0]),
-        dec=plane([NO_SLOT, 0, 0]),
         exec_cursor=zG(),
         gc_slot=jnp.full((G,), NO_SLOT, i32),
         is_coord=jnp.zeros((G,), jnp.bool_),
         coord_active=jnp.zeros((G,), jnp.bool_),
         cbal=jnp.full((G,), NO_BALLOT, i32),
         next_slot=zG(),
-        prep_votes=jnp.zeros((G,), u32),
-        prop=plane([NO_SLOT, 0, 0, 0]),
+        prep_votes=jnp.zeros((G,), jnp.uint32),
+        **planes,
     )
 
 
@@ -177,5 +238,5 @@ def join_req_id(lo: int, hi: int) -> int:
 def state_nbytes(G: int, W: int) -> int:
     """Approximate device bytes for a state of this capacity."""
     per_g = 4 * 8 + 3    # 8 i32/u32 [G] fields + 3 bool [G] fields
-    per_gw = 4 * (4 + 3 + 4)  # acc[...,4] + dec[...,3] + prop[...,4] i32
+    per_gw = 4 * (4 + 3 + 4)  # the acc, dec and prop component planes, i32
     return G * per_g + G * W * per_gw

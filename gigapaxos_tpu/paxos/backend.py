@@ -29,7 +29,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from gigapaxos_tpu.ops.oracle import OracleGroup, PValue, make_oracle_group
-from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT
+from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT, PLANES
 from gigapaxos_tpu.utils.engineledger import EngineLedger
 from gigapaxos_tpu.utils.instrument import (RequestInstrumenter, span,
                                             traced)
@@ -1157,15 +1157,16 @@ class ColumnarBackend(AcceptorBackend):
                 for i in range(len(rows))]
 
     def restore_row(self, row: int, snap: dict) -> None:
-        from gigapaxos_tpu.ops.types import ColumnarState
+        from gigapaxos_tpu.ops.types import RowState
         from gigapaxos_tpu.ops.kernels import scatter_rows
         # coerce dtypes: snapshots may round-trip through JSON (pause
         # blobs), which turns u32 vote words / bool flags into int lists
-        row_state = ColumnarState(
+        row_state = RowState(
             **{f: self._dev(
                 np.asarray(snap[f]).astype(
-                    getattr(self.state, f).dtype)[None])
-               for f in ColumnarState._fields})
+                    np.int32 if f in PLANES
+                    else getattr(self.state, f).dtype)[None])
+               for f in RowState._fields})
         with self._disp():
             self.state, _ = scatter_rows(
                 self.state, self._dev(np.asarray([row], np.int32)),
@@ -1267,10 +1268,10 @@ class ColumnarBackend(AcceptorBackend):
 
 
 # plane grouping of the ColumnarState fields for the accounting view:
-# the three [G, W, k] slabs stay individually visible; the [G] scalar
-# mirrors roll up by role
+# the window planes' components roll up into the three slabs they make
+# (acc_slot .. acc_rhi -> "acc"); the [G] scalar mirrors roll up by role
 _PLANE_OF = {
-    "acc": "acc", "dec": "dec", "prop": "prop",
+    **{c: view for view, cols in PLANES.items() for c, _ in cols},
     "bal": "ballots", "cbal": "ballots",
     "exec_cursor": "cursors", "next_slot": "cursors",
     "gc_slot": "cursors",

@@ -19,6 +19,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 CAPACITY, WINDOW, BUCKET = 1 << 20, 16, 4096
+STORM_LANES = 1 << 18  # the storm cell's step, at its own size
+SERVING_TEMP, STORM_TEMP = 32 << 20, 1 << 30
 # 739 B per group at W=16 (PR 18's slab accounting, from shapes)
 SLAB_BYTES = 739 * CAPACITY
 
@@ -75,34 +77,113 @@ def _compile(fn, *shapes):
         return fn.lower(*shapes).compile()
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+# what moves a plane and is no scatter: each of these, at a plane's size,
+# is a pass or two over 67 MB or more that a wave would pay
+_RELAYOUTS = {"copy", "reshape", "broadcast", "transpose",
+              "dynamic-update-slice"}
+# the compiler's own staging of an array in its fast memory (``S(1)``) and
+# back: asynchronous, the linear order kept
+_STAGING = {"copy-done"}
+
+
+def _plane_moves(compiled, words, ops):
+    """The instructions of the compiled program, of the kinds ``ops``,
+    that produce an array of ``words`` words or more.  Read outside the
+    fused computations: what is inside a fusion is never an array in
+    memory, the fusion's own result is."""
+    text = compiled.as_text()
+    fused = set(re.findall(r"\bfusion\(.*?calls=(%[\w.\-]+)", text))
+    found, skip = [], False
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            skip = head.group(1) in fused
+            continue
+        m = None if skip else _INSTRUCTION.match(line)
+        if m and m.group(2) in ops:
+            n = 1
+            for d in filter(None, m.group(1).split(",")):
+                n *= int(d)
+            if n >= words:
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.fixture(scope="module")
+def compiled(topo, no_persistent_cache):
+    """Each hot program compiled once for the described chip, by name:
+    the cases below read the one compile from more than one side."""
+    made = {}
+
+    def get(name, bucket=BUCKET):
+        if (name, bucket) not in made:
+            chip = SingleDeviceSharding(topo.devices[0])
+            if name == "storm":
+                from gigapaxos_tpu.ops.storm import storm
+                lane = jax.ShapeDtypeStruct((STORM_LANES,), jnp.int32,
+                                            sharding=chip)
+                valid = jax.ShapeDtypeStruct((STORM_LANES,), jnp.bool_,
+                                             sharding=chip)
+                made[name, bucket] = _compile(
+                    storm, tuple(_state_shapes(CAPACITY, WINDOW, chip)
+                                 for _ in range(3)),
+                    lane, lane, lane, valid)
+            else:
+                from gigapaxos_tpu.ops import kernels
+                made[name, bucket] = _compile(
+                    getattr(kernels, name),
+                    _state_shapes(CAPACITY, WINDOW, chip),
+                    *[_packed(k, chip, bucket) for k in SERVING[name]])
+        return made[name, bucket]
+    return get
+
+
 def _serving(name):
-    def run(topo):
-        from gigapaxos_tpu.ops import kernels
-        chip = SingleDeviceSharding(topo.devices[0])
-        c = _compile(getattr(kernels, name),
-                     _state_shapes(CAPACITY, WINDOW, chip),
-                     *[_packed(k, chip) for k in SERVING[name]])
-        ma = c.memory_analysis()
+    def run(topo, compiled):
+        ma = compiled(name).memory_analysis()
         assert SLAB_BYTES <= ma.argument_size_in_bytes < 1.01 * SLAB_BYTES
-        # slab + the wave's scratch must fit one v5e beside two more
-        # in-process nodes' slabs (16 GB; ROADMAP S6 notes the scratch)
-        assert ma.temp_size_in_bytes < 3 << 30
+        # a wave's scratch is lanes, not planes: the widest wave (two
+        # inputs of 4,096 lanes, a [4096, 128] row read) asks SERVING_TEMP
+        # beside its slab, where a program that copied ONE component
+        # plane would ask 67 MB (request_reply_p at 64 lanes asked
+        # 270,886,400 B before PR 32)
+        assert ma.temp_size_in_bytes < SERVING_TEMP
     return run
 
 
-def _storm(topo):
-    from gigapaxos_tpu.ops.storm import storm
-    chip = SingleDeviceSharding(topo.devices[0])
-    G, B = 1 << 14, 1 << 12
-    lane = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=chip)
-    valid = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=chip)
-    c = _compile(storm, tuple(_state_shapes(G, WINDOW, chip)
-                              for _ in range(3)), lane, lane, lane, valid)
-    assert c.memory_analysis().argument_size_in_bytes >= 3 * 739 * G
+def _storm(topo, compiled):
+    ma = compiled("storm").memory_analysis()
+    assert ma.argument_size_in_bytes >= 3 * 739 * CAPACITY
+    # three replicas' planes are updated in place: the step's scratch is
+    # lane arrays and the [B, 128] row reads (3,285,974,528 B before
+    # PR 32, beside 2,328,100,864 B of state)
+    assert ma.temp_size_in_bytes < STORM_TEMP
+
+
+def _no_relayout(name, bucket=BUCKET, staged=0):
+    """No copy, reshape, broadcast, transpose or dynamic-update-slice of
+    a component plane (or of anything larger) in the program: a plane is
+    only ever the operand of a scatter, in place, or of a gather, and is
+    seen as rows of 128 words through a bitcast.  ``staged``: the one
+    thing left is the compiler's to choose.  At 4,096 lanes it stages
+    ONE plane that a stage scatters into and then reads (``dec_slot``,
+    ``prop_votes``) in its fast memory, one asynchronous copy each way;
+    at the lane counts the served cells run (8-1,024) and in the storm
+    step it stages none."""
+    def run(topo, compiled):
+        c, words = compiled(name, bucket), CAPACITY * WINDOW
+        moved = _plane_moves(c, words, _RELAYOUTS)
+        assert not moved, "\n".join(moved)
+        copies = _plane_moves(c, words, _STAGING)
+        assert len(copies) <= 2 * staged, "\n".join(copies)
+    return run
 
 
 def _mesh(name):
-    def run(topo):
+    def run(topo, _compiled):
         from gigapaxos_tpu.ops.meshkernels import GROUP_AXIS, MeshKernels
         mesh = Mesh(topo.devices, (GROUP_AXIS,))
         assert mesh.size == 4
@@ -119,7 +200,7 @@ def _mesh(name):
     return run
 
 
-def _pallas(topo):
+def _pallas(topo, _compiled):
     from gigapaxos_tpu.ops.pallas_accept import _accept_blocks
     chip = SingleDeviceSharding(topo.devices[0])
     G, Rb, L = 1 << 14, 4096, 16
@@ -129,17 +210,24 @@ def _pallas(topo):
 
     c = _accept_blocks.lower(
         s((Rb,)), s((G,)), s((G,), jnp.bool_), s((G,)),
-        *[s((Rb, L))] * 6, *[s((G, WINDOW))] * 4, False).compile()
+        *[s((Rb, L))] * 6, *[s((G * WINDOW,))] * 4, False).compile()
     assert "tpu_custom_call" in c.as_text()
 
 
 CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
          "storm.decide_storm_step": _storm,
+         # the window planes are linear and addressed a word at a time:
+         # no program copies, reshapes or broadcasts one (PR 32)
+         **{f"no_plane_relayout.serving.{n}": _no_relayout(n, staged=1)
+            for n in SERVING},
+         **{f"no_plane_relayout.serving.{n}.bucket64": _no_relayout(n, 64)
+            for n in ("request_reply_p", "accept_commit_p")},
+         "no_plane_relayout.storm.decide_storm_step": _no_relayout("storm"),
          "mesh.accept_p": _mesh("accept_p"),
          "mesh.accept_commit_p": _mesh("accept_commit_p"),
          "pallas._accept_blocks": _pallas}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_compiles_for_v5e(case, topo, no_persistent_cache):
-    CASES[case](topo)
+def test_compiles_for_v5e(case, topo, compiled):
+    CASES[case](topo, compiled)

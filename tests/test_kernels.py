@@ -12,8 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from gigapaxos_tpu.ops import kernels, make_state, pack_ballot
-from gigapaxos_tpu.ops.types import (DEC_SLOT, NO_SLOT, join_req_id,
-                                     split_req_id)
+from gigapaxos_tpu.ops.types import NO_SLOT, join_req_id, split_req_id
 from gigapaxos_tpu.ops.oracle import make_oracle_group, PValue
 
 B = 4  # fixed lane count -> one jit cache entry per kernel
@@ -449,7 +448,7 @@ def test_commit_advance_equals_oracle_frontier(kind, Wn):
     st = make_state(Bn, Wn)
     st = st._replace(
         active=jnp.ones((Bn,), jnp.bool_), exec_cursor=jnp.asarray(cursor),
-        dec=st.dec.at[:, :, DEC_SLOT].set(jnp.asarray(dslot)))
+        dec_slot=jnp.asarray(dslot).reshape(-1))
     valid = jnp.arange(Bn) < len(rows)
     one = jnp.ones((Bn,), i32)
     st, o = kernels.commit(st, jnp.arange(Bn, dtype=i32), jnp.asarray(slot),
@@ -575,3 +574,470 @@ def _ordered_body_case(stage):
     "commit_distinct_slots"])
 def test_body_with_a_lane_order_equals_body_without(stage):
     _ordered_body_case(stage)
+
+
+# --------------------------------------------------------------------------
+# the six bodies against ops/oracle.py on seeded random batches, outputs lane
+# by lane and the [G, W, k] views column by column; the row exchange form
+# --------------------------------------------------------------------------
+
+SOUP_G, SOUP_W, SOUP_B = 16, 8, 32  # 128 words a plane: one tile row
+
+
+class _Soup:
+    """A seeded random history driven through the jitted kernels and, lane
+    by lane in the batch's own linearization, through one OracleGroup a
+    row: repeated groups, full windows, stale and out-of-window slots,
+    lower and higher ballots, holes in the decided window, invalid lanes,
+    inactive rows and rows another node coordinates.  The body under test
+    may be handed a lane order (``runs``); its outputs are compared on
+    every batch, the state's views once at the end."""
+
+    def __init__(self, seed, check, runs):
+        from gigapaxos_tpu.ops.types import NODE_BITS
+        self.rng = np.random.default_rng(seed)
+        self.check, self.runs, self.checked = check, runs, 0
+        self.nb = NODE_BITS
+        G, W = SOUP_G, SOUP_W
+        st = make_state(G, W)
+        rows = np.arange(G, dtype=np.int32)
+        self.coord0 = rows % 4 != 3          # rows 3, 7, ..: node 1 leads
+        self.live = rows < G - 2             # the last two never created
+        init = np.where(self.coord0, pack_ballot(0, 0), pack_ballot(0, 1))
+        st, _ = kernels.create_groups(
+            st, jnp.asarray(rows), jnp.full((G,), 3, i32),
+            jnp.zeros((G,), i32), jnp.asarray(init, i32),
+            jnp.asarray(self.coord0), jnp.asarray(self.live))
+        self.st = st
+        self.og = {int(g): make_oracle_group(3, W, int(init[g]),
+                                             bool(self.coord0[g]))
+                   for g in rows[self.live]}
+        self.req = 1 << 33  # request ids with both words in use
+        self.inflight = {}   # (g, slot) -> (cbal, req): granted, undecided
+        self.undecided = []  # (g, slot, req) decided, commit not yet sent
+
+    # -- plumbing ----------------------------------------------------------
+
+    def lanes(self, *cols, n=None):
+        """Pad python columns to SOUP_B lanes; the padding is invalid."""
+        n = len(cols[0]) if n is None else n
+        assert n <= SOUP_B, n
+        valid = np.zeros(SOUP_B, bool)
+        valid[:n] = True
+        out = []
+        for c in cols:
+            a = np.zeros(SOUP_B, np.int64)
+            a[:n] = c
+            out.append(a)
+        # invalid lanes scattered through, not only at the end
+        perm = self.rng.permutation(SOUP_B)
+        return [a[perm] for a in out], valid[perm]
+
+    def call(self, name, lanes, valid, distinct=False):
+        """The body: jitted as the runtime calls it, or handed a lane
+        order where it is the one under test with ``runs``."""
+        arrs = [jnp.asarray(a, jnp.bool_ if a.dtype == bool else i32)
+                for a in lanes]
+        v = jnp.asarray(valid)
+        if not (self.runs and name == self.check):
+            self.st, o = getattr(kernels, name)(self.st, *arrs, v)
+            return {f: np.asarray(x) for f, x in o._asdict().items()}
+        runs, g2 = kernels.lane_runs(arrs[0], v, distinct_slots=distinct)
+        rest = [a[runs.order] for a in arrs[1:]]
+        self.st, o = getattr(kernels, name + "_batch")(
+            self.st, g2, *rest, v[runs.order], runs)
+        order, back = np.asarray(runs.order), {}
+        for f, x in o._asdict().items():
+            b = np.empty_like(np.asarray(x))
+            b[order] = np.asarray(x)
+            back[f] = b
+        return back
+
+    def expect(self, name, got, want, valid):
+        """``want``: per output field, a dict lane -> value (lanes left
+        out are padding or don't-care)."""
+        if name != self.check:
+            return
+        for f, by_lane in want.items():
+            for i, v in by_lane.items():
+                assert valid[i]
+                assert got[f][i] == v, (name, f, i, got[f][i], v)
+                self.checked += 1
+
+    def new_req(self):
+        self.req += 0x100000003
+        return self.req
+
+    # -- the stages ----------------------------------------------------------
+
+    def propose(self):
+        n = SOUP_B - 4
+        g = self.rng.integers(0, SOUP_G, n)
+        g[: n // 2] = self.rng.integers(0, 3, n // 2)  # runs fill windows
+        req = [self.new_req() for _ in range(n)]
+        lo, hi = zip(*(split_req_id(r) for r in req))
+        (g, lo, hi, req), valid = self.lanes(g, lo, hi, req)
+        got = self.call("propose", (g, lo, hi), valid)
+        want = {f: {} for f in ("granted", "rejected", "throttled", "slot",
+                                "cbal")}
+        granted = []
+        for i in np.flatnonzero(valid):
+            og = self.og.get(int(g[i]))
+            status, slot, cbal = og.propose(int(req[i])) if og else (
+                "inactive", None, None)
+            for f in ("granted", "rejected", "throttled"):
+                want[f][i] = status == f
+            if status == "granted":
+                want["slot"][i], want["cbal"][i] = slot, cbal
+                granted.append((int(g[i]), slot, cbal, int(req[i])))
+        self.expect("propose", got, want, valid)
+        return granted
+
+    def accept(self, granted):
+        """The new proposals and the ones still undecided (oldest first: a
+        lost accept or reply is sent again, as the host does), a tenth of
+        them lost, and the odd ones."""
+        self.inflight.update({(g, s): (b, r) for g, s, b, r in granted})
+        again = sorted(self.inflight, key=lambda k: k[1])[:SOUP_B - 8]
+        lanes = {k: self.inflight[k] for k in again
+                 if self.rng.random() < 0.9}
+        for g in self.rng.integers(0, SOUP_G, 8):  # the odd ones
+            og = self.og.get(int(g))
+            cur = og.exec_cursor if og else 0
+            top = og.bal if og else 0
+            if self.coord0[g]:  # its own ballot: stale, or past the window
+                slot = int(self.rng.choice([cur - 1, cur - 2, cur + SOUP_W,
+                                            cur + SOUP_W + 3]))
+                bal = top
+            else:  # node 1's rows: any slot at any ballot around the promise
+                slot = int(self.rng.choice([cur - 1, cur + SOUP_W, cur + 2,
+                                            cur + 5]))
+                bal = int(self.rng.choice([top, top - (1 << self.nb),
+                                           top + (1 << self.nb)]))
+            if slot >= 0 and bal >= 0:
+                lanes.setdefault((int(g), slot), (bal, self.new_req()))
+        keys = list(lanes)[:SOUP_B]
+        g, slot = zip(*keys)
+        bal, req = zip(*(lanes[k] for k in keys))
+        lo, hi = zip(*(split_req_id(r) for r in req))
+        (g, slot, bal, lo, hi, req), valid = self.lanes(g, slot, bal, lo, hi,
+                                                        req)
+        got = self.call("accept", (g, slot, bal, lo, hi), valid)
+        # the batch's linearization: a group's promise is the max over the
+        # batch, then its lanes
+        for gg in set(g[valid]):
+            if int(gg) in self.og:
+                self.og[int(gg)].prepare(int(bal[valid & (g == gg)].max()))
+        want = {f: {} for f in ("acked", "stale", "out_window", "cur_bal")}
+        acks = []
+        for i in np.flatnonzero(valid):
+            og = self.og.get(int(g[i]))
+            if og is None:
+                want["acked"][i] = want["stale"][i] = False
+                want["out_window"][i] = False
+                continue
+            r = og.accept(int(slot[i]), int(bal[i]), int(req[i]))
+            for f, v in zip(want, r):
+                want[f][i] = v
+            acks.append((int(g[i]), int(slot[i]), int(bal[i]), r[0], r[3]))
+        self.expect("accept", got, want, valid)
+        return acks
+
+    def accept_reply(self, acks, sender):
+        """One lane a (group, slot) a batch; a nack that preempts only on
+        a group with no other lane in it."""
+        lanes = {}
+        for g, slot, bal, acked, cur_bal in acks:
+            if self.coord0[g] and self.rng.random() < 0.85:
+                lanes[g, slot] = (bal if acked else cur_bal, acked)
+        for g in self.rng.integers(0, SOUP_G, 6):
+            og = self.og.get(int(g))
+            if og is None or any(k[0] == g for k in lanes):
+                continue
+            kind = self.rng.integers(0, 3)
+            if kind == 0:    # a slot nobody proposed
+                lanes[int(g), og.next_slot + 1] = (og.cbal, True)
+            elif kind == 1:  # an older ballot's ack
+                lanes[int(g), max(og.next_slot - 1, 0)] = (
+                    og.cbal - (1 << self.nb), True)
+            elif og.is_coord and self.rng.random() < 0.15:  # preempted
+                lanes[int(g), max(og.next_slot - 1, 0)] = (
+                    og.cbal + (1 << self.nb) + 1, False)
+        keys = list(lanes)[:SOUP_B]
+        if not keys:
+            return []
+        g, slot = zip(*keys)
+        bal, acked = zip(*(lanes[k] for k in keys))
+        (g, slot, bal, snd, acked), valid = self.lanes(
+            g, slot, bal, [sender] * len(keys), acked)
+        acked = acked.astype(bool)
+        got = self.call("accept_reply", (g, slot, bal, snd, acked), valid,
+                        distinct=True)
+        want = {f: {} for f in ("newly_decided", "preempted", "req_lo",
+                                "req_hi", "dec_slot")}
+        decided = []
+        # the batch's linearization: the acks count on the coordinator the
+        # batch found, then every nack above its ballot resigns it
+        led = {g: og.is_coord for g, og in self.og.items()}
+        for i in sorted(np.flatnonzero(valid), key=lambda i: not acked[i]):
+            og = self.og.get(int(g[i]))
+            newly, pre, req = og.accept_reply(
+                int(slot[i]), int(bal[i]), sender, bool(acked[i])) if og \
+                else (False, False, None)
+            if og and not acked[i]:
+                pre = led[int(g[i])] and int(bal[i]) > og.cbal
+            want["newly_decided"][i], want["preempted"][i] = newly, pre
+            if newly:
+                lo, hi = split_req_id(req)
+                want["req_lo"][i], want["req_hi"][i] = lo, hi
+                want["dec_slot"][i] = int(slot[i])
+                decided.append((int(g[i]), int(slot[i]), req))
+                self.inflight.pop((int(g[i]), int(slot[i])), None)
+        self.expect("accept_reply", got, want, valid)
+        return decided
+
+    def commit(self):
+        """Decisions not yet committed, a fifth of them held back a round
+        (holes), with stale lanes and, on groups with no other lane,
+        lanes beyond the window.  ``new_cursor`` is the group's frontier
+        after the whole batch."""
+        self.rng.shuffle(self.undecided)
+        hold = [d for d in self.undecided if self.rng.random() < 0.2]
+        send = [d for d in self.undecided if d not in hold][:SOUP_B - 6]
+        hold += [d for d in self.undecided
+                 if d not in hold and d not in send]
+        self.undecided = hold
+        lanes = {(g, s): r for g, s, r in send}
+        for g in self.rng.integers(0, SOUP_G, 6):
+            og = self.og.get(int(g))
+            cur = og.exec_cursor if og else 0
+            if any(k[0] == g for k in lanes):
+                if cur > 0:
+                    lanes[int(g), cur - 1] = self.new_req()
+            else:
+                lanes[int(g), cur + SOUP_W + int(g) % 2] = self.new_req()
+        keys = list(lanes)[:SOUP_B]
+        if not keys:
+            return
+        g, slot = zip(*keys)
+        req = [lanes[k] for k in keys]
+        lo, hi = zip(*(split_req_id(r) for r in req))
+        (g, slot, lo, hi, req), valid = self.lanes(g, slot, lo, hi, req)
+        got = self.call("commit", (g, slot, lo, hi), valid, distinct=True)
+        want = {f: {} for f in ("applied", "stale", "out_window",
+                                "new_cursor")}
+        for i in np.flatnonzero(valid):
+            og = self.og.get(int(g[i]))
+            r = og.commit(int(slot[i]), int(req[i])) if og else (
+                False, False, False, None)
+            for f, v in zip(("applied", "stale", "out_window"), r):
+                want[f][i] = v
+        for i in np.flatnonzero(valid):
+            if int(g[i]) in self.og:
+                want["new_cursor"][i] = self.og[int(g[i])].exec_cursor
+        self.expect("commit", got, want, valid)
+
+    def prepare(self):
+        g = self.rng.integers(0, SOUP_G, 12)
+        bal = [(self.og[int(x)].bal if int(x) in self.og else 0) +
+               int(self.rng.choice([-1, 0, 0, 0, 0, 1])) * (1 << self.nb)
+               for x in g]
+        bal = np.maximum(bal, 0)
+        (g, bal), valid = self.lanes(g, bal)
+        got = self.call("prepare", (g, bal), valid)
+        for gg in set(g[valid]):  # the promise: the batch's max
+            if int(gg) in self.og:
+                self.og[int(gg)].prepare(int(bal[valid & (g == gg)].max()))
+        want = {f: {} for f in ("acked", "cur_bal", "exec_cursor")}
+        for i in np.flatnonzero(valid):
+            og = self.og.get(int(g[i]))
+            if og is None:
+                want["acked"][i] = False
+                continue
+            acked, cur_bal, cursor, window = og.prepare(int(bal[i]))
+            want["acked"][i], want["cur_bal"][i] = acked, cur_bal
+            want["exec_cursor"][i] = cursor
+            if self.check == "prepare":
+                have = {}
+                for w in range(SOUP_W):
+                    s = int(got["win_slot"][i, w])
+                    if s >= cursor:
+                        have[s] = (int(got["win_bal"][i, w]), join_req_id(
+                            int(got["win_req_lo"][i, w]),
+                            int(got["win_req_hi"][i, w])))
+                newest = {pv.slot: (pv.bal, pv.req_id) for pv in window
+                          if pv.slot + SOUP_W not in og.accepted}
+                assert have == newest, (int(g[i]), have, newest)
+        self.expect("prepare", got, want, valid)
+
+    def install_coordinator(self):
+        """Node 0 takes over the rows it does not lead (or lost): a
+        ballot above the promise, the accepted window carried over."""
+        rows = [g for g, og in self.og.items()
+                if not (og.is_coord and og.coord_active)][:4]
+        if not rows:
+            return
+        n = len(rows)
+        cs = np.full((SOUP_B, SOUP_W), NO_SLOT, np.int32)
+        cl = np.zeros((SOUP_B, SOUP_W), np.int32)
+        ch = np.zeros((SOUP_B, SOUP_W), np.int32)
+        (g, lane_of), valid = self.lanes(rows, np.arange(n))
+        cbal, nxt = np.zeros(SOUP_B, np.int64), np.zeros(SOUP_B, np.int64)
+        for i in np.flatnonzero(valid):
+            og = self.og[int(g[i])]
+            cbal[i] = pack_ballot((og.bal >> self.nb) + 1, 0)
+            og.prepare(int(cbal[i]))
+            carry = [pv for s, pv in sorted(og.accepted.items())
+                     if s >= og.exec_cursor and s + SOUP_W not in og.accepted]
+            nxt[i] = max([og.exec_cursor] + [pv.slot + 1 for pv in carry])
+            for k, pv in enumerate(carry):
+                cs[i, k] = pv.slot
+                cl[i, k], ch[i, k] = split_req_id(pv.req_id)
+                # re-proposed at the new ballot, as the host sends it
+                self.inflight[int(g[i]), pv.slot] = (int(cbal[i]), pv.req_id)
+            og.install_coordinator(int(cbal[i]), int(nxt[i]), carry)
+            self.coord0[int(g[i])] = True
+        self.st, _ = kernels.install_coordinator(
+            self.st, jnp.asarray(g, i32), jnp.asarray(cbal, i32),
+            jnp.asarray(nxt, i32), jnp.asarray(cs), jnp.asarray(cl),
+            jnp.asarray(ch), jnp.asarray(valid))
+        # the promise rides a prepare, as the host sends one first
+        self.st, _ = kernels.prepare(
+            self.st, jnp.asarray(g, i32), jnp.asarray(cbal, i32),
+            jnp.asarray(valid))
+        self.checked += n
+
+    def run(self, rounds=12):
+        for r in range(rounds):
+            granted = self.propose()
+            acks = self.accept(granted)
+            for sender in (0, 1, 2):
+                self.undecided += self.accept_reply(acks, sender)
+            self.commit()
+            self.prepare()
+            if r % 2:
+                self.install_coordinator()
+
+    # -- the views -----------------------------------------------------------
+
+    def views_equal_oracle(self):
+        st, W = self.st, SOUP_W
+        acc, dec, prop = (np.asarray(v) for v in (st.acc, st.dec, st.prop))
+        assert acc.shape == (SOUP_G, W, 4) and dec.shape == (SOUP_G, W, 3)
+        assert prop.shape == (SOUP_G, W, 4)
+        for g, og in self.og.items():
+            for f in ("bal", "exec_cursor", "next_slot", "cbal", "is_coord",
+                      "coord_active"):
+                assert int(getattr(st, f)[g]) == int(getattr(og, f)), (g, f)
+            want_acc = np.tile(np.array([NO_SLOT, -1, 0, 0]), (W, 1))
+            for s in sorted(og.accepted):
+                pv = og.accepted[s]
+                want_acc[s % W] = (s, pv.bal, *split_req_id(pv.req_id))
+            np.testing.assert_array_equal(acc[g], want_acc, err_msg=f"acc {g}")
+            want_dec = np.tile(np.array([NO_SLOT, 0, 0]), (W, 1))
+            for s in sorted(og.decided):
+                want_dec[s % W] = (s, *split_req_id(og.decided[s]))
+            np.testing.assert_array_equal(dec[g], want_dec, err_msg=f"dec {g}")
+            want_prop = np.tile(np.array([NO_SLOT, 0, 0, 0]), (W, 1))
+            for s in sorted(og.votes):
+                want_prop[s % W] = (
+                    s, *split_req_id(og.prop_req[s]),
+                    og.votes[s] | (og.emitted[s] << 30))
+            np.testing.assert_array_equal(prop[g], want_prop,
+                                          err_msg=f"prop {g}")
+        for g in np.flatnonzero(~self.live):  # never created: fresh
+            assert (acc[g, :, 0] == NO_SLOT).all() and not acc[g, :, 2:].any()
+            assert (dec[g, :, 0] == NO_SLOT).all()
+            assert (prop[g, :, 0] == NO_SLOT).all()
+
+
+@pytest.mark.parametrize("body,runs", [
+    ("propose", False), ("propose", True), ("accept", False),
+    ("accept", True), ("accept_reply", False), ("accept_reply", True),
+    ("commit", False), ("commit", True), ("prepare", False),
+    ("install_coordinator", False)])
+def test_body_equals_oracle_lane_by_lane(body, runs):
+    soup = _Soup(seed=32, check=body, runs=runs)
+    soup.run()
+    assert soup.checked > 100 or body == "install_coordinator", soup.checked
+    assert soup.checked > 0
+    soup.views_equal_oracle()
+    # the soup reached what it is for
+    cursors = [og.exec_cursor for og in soup.og.values()]
+    assert max(cursors) > SOUP_W, cursors  # windows wrapped
+
+
+def _some_history():
+    soup = _Soup(seed=7, check=None, runs=False)
+    soup.run(rounds=4)
+    return soup.st
+
+
+def test_rows_round_trip_through_the_row_form():
+    """gather_rows -> scatter_rows into a fresh state -> gather_rows: the
+    row form carries everything, with the planes as [n, W, k]."""
+    st = _some_history()
+    rows = jnp.asarray([0, 1, 2, 5, 9], i32)
+    got = kernels.gather_rows(st, rows)
+    assert got.acc.shape == (5, SOUP_W, 4) and got.dec.shape == (5, SOUP_W, 3)
+    assert got.prop.shape == (5, SOUP_W, 4)
+    assert (np.asarray(got.acc)[..., 0] != NO_SLOT).any()
+    for f in ("acc", "dec", "prop"):  # the row form IS the view's rows
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(st, f))[rows])
+    # into other rows of a fresh state, one lane of them invalid
+    to = jnp.asarray([3, 4, 8, 10, 11], i32)
+    valid = jnp.asarray([True, True, True, False, True])
+    fresh, _ = kernels.scatter_rows(make_state(SOUP_G, SOUP_W), to, got,
+                                    valid)
+    back = kernels.gather_rows(fresh, to)
+    keep = np.asarray(valid)
+    for f in got._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(back, f))[keep],
+                                      np.asarray(getattr(got, f))[keep],
+                                      err_msg=f)
+    untouched = kernels.gather_rows(make_state(SOUP_G, SOUP_W), to)
+    for f in got._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(back, f))[~keep],
+                                      np.asarray(getattr(untouched, f))[~keep],
+                                      err_msg=f)
+
+
+def test_restore_row_takes_a_snapshot_in_the_old_row_form():
+    """A snapshot dict as a pause blob written before PR 32 holds it:
+    ``acc`` [W, 4], ``dec`` [W, 3], ``prop`` [W, 4] and the [G] fields by
+    name, through JSON (lists of ints, flags as ints)."""
+    import json
+    from gigapaxos_tpu.paxos.backend import ColumnarBackend
+    W = 8
+    old = {
+        "active": 1, "members": 3, "version": 2, "bal": pack_ballot(1, 2),
+        "acc": [[s, pack_ballot(1, 2), 100 + s, 7] if s in (4, 5, 9)
+                else [NO_SLOT, -1, 0, 0] for s in (8, 9, 2, 3, 4, 5, 6, 7)],
+        "dec": [[s, 100 + s, 7] if s == 4 else [NO_SLOT, 0, 0]
+                for s in (8, 9, 2, 3, 4, 5, 6, 7)],
+        "exec_cursor": 5, "gc_slot": 3, "is_coord": 1, "coord_active": 0,
+        "cbal": pack_ballot(1, 2), "next_slot": 10, "prep_votes": 5,
+        "prop": [[9, 109, 7, 3 | (1 << 30)] if s == 9 else [NO_SLOT, 0, 0, 0]
+                 for s in (8, 9, 2, 3, 4, 5, 6, 7)],
+    }
+    be = ColumnarBackend(capacity=16, window=W, mesh="off")
+    be.restore_row(6, json.loads(json.dumps(old)))
+    st = be.state
+    assert np.asarray(st.acc)[6].tolist() == old["acc"]
+    assert np.asarray(st.dec)[6].tolist() == old["dec"]
+    assert np.asarray(st.prop)[6].tolist() == old["prop"]
+    assert int(st.acc_rlo[6 * W + 1]) == 109 and int(st.dec_slot[6 * W + 4]) == 4
+    assert int(st.prop_votes[6 * W + 1]) == 3 | (1 << 30)
+    for f in ("members", "version", "bal", "exec_cursor", "gc_slot", "cbal",
+              "next_slot", "prep_votes"):
+        assert int(getattr(st, f)[6]) == old[f], f
+    assert bool(st.active[6]) and bool(st.is_coord[6])
+    assert not bool(st.coord_active[6])
+    # its neighbours are as fresh as before
+    assert (np.asarray(st.acc)[[5, 7], :, 0] == NO_SLOT).all()
+    # and what the backend snapshots now is the same form, key for key
+    snap = be.snapshot_rows([6])[0]
+    assert set(snap) == set(old)
+    for f, v in old.items():
+        assert np.asarray(snap[f]).tolist() == v, f

@@ -132,11 +132,14 @@ def _count_primitives(jaxpr, out, under_cond=False):
 def test_step_sorts_once_and_keeps_its_passes_down():
     """Counts only: every serial pass over the lanes is a sort or a
     scatter.  One sort a step (the lane order), no scatter into a
-    lane-shaped array (what un-permuting a rank is), and 17 scatters that
-    always run: propose 2, accept 2 x R, accept-reply 1 x R, commit
-    2 x R; the 2 x R resign scatters sit under a cond.  Every one that
-    always runs states that its indices are unique.  No gather reads a
-    lane-shaped operand and no scan runs along a window row."""
+    lane-shaped array (what un-permuting a rank is), and 35 scatters that
+    always run, each of ONE word a lane: propose 1 + 4 (``next_slot`` and
+    the four proposal components), accept (1 + 4) x R, accept-reply
+    1 x R (the vote add), commit (3 + 1) x R; the 2 x R resign scatters
+    sit under a cond.  Every one that always runs states that its indices
+    are unique, and writes a ``[G]`` field or a linear ``[G * W]``
+    component plane.  No gather reads a lane-shaped operand and no scan
+    runs along a window row."""
     import jax
     import jax.numpy as jnp
     from gigapaxos_tpu.ops.storm import decide_storm_step
@@ -152,18 +155,19 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     assert sum(n == "sort" for n, _, _ in eqns) == 1
     scatters = [(c, e) for n, c, e in eqns if n.startswith("scatter")]
     always = [e for c, e in scatters if not c]
-    assert len(always) == 2 + 5 * R, len(always)
+    assert len(always) == 5 + 10 * R, len(always)
     assert len(scatters) - len(always) == 2 * R
     for e in always:
-        assert e.invars[0].aval.shape[0] == G, e  # never a [B] array
+        assert e.invars[0].aval.shape in ((G,), (G * W,)), e  # never [B]
+        assert e.invars[2].aval.shape == (B,), e  # a word a lane
         assert e.params["unique_indices"], e
     # every gather reads a state array and every scan runs along the
     # lanes: the commit stage counts its frontier's advance on the
     # [B, W] row where it lies, with a compare and a row min
     gathers = [e for n, _, e in eqns if n == "gather"]
     assert gathers
-    for e in gathers:
-        assert e.invars[0].aval.shape[0] == G, e
+    for e in gathers:  # the plane may be seen as rows of 128 words
+        assert e.invars[0].aval.size in (G, G * W), e
     for n, _, e in eqns:
         if n.startswith("cum"):
             assert e.invars[0].aval.shape == (B,), e
