@@ -193,6 +193,11 @@ class AcceptorBackend(abc.ABC):
         backends without device-resident slabs (scalar/native)."""
         return None
 
+    def warm_elections(self) -> None:
+        """Load whatever a takeover would otherwise compile the first
+        time a leader dies.  Nothing to load on the engines that
+        compile nothing."""
+
     def row_ownership(self) -> Optional[dict]:
         """Active-row counts per mesh device; None when not
         applicable."""
@@ -670,15 +675,20 @@ class ColumnarBackend(AcceptorBackend):
         self._warm_kernels()
 
     def _warm_kernels(self) -> None:
-        """Compile the hot serving kernels on all-padding inputs at the
-        smallest bucket NOW, at construction, instead of mid-serving:
-        a cold first-touch compile (~2-20 s at serving capacities on a
-        one-core host) landing inside a request window reads as a
-        multi-second latency spike or a client timeout.  All-invalid
-        lanes make every warm call a state no-op; with the persistent
-        cache this is a disk load after the first process on a
-        machine.  Larger buckets still compile on first use — the load
-        ramp, not the trickle path, absorbs those."""
+        """Compile the eight hot SERVING kernels on all-padding inputs
+        at the smallest bucket NOW, at construction, instead of
+        mid-serving: a cold first-touch compile (~2-20 s at serving
+        capacities on a one-core host) landing inside a request window
+        reads as a multi-second latency spike or a client timeout.
+        All-invalid lanes make every warm call a state no-op; with the
+        persistent cache this is a disk load after the first process on
+        a machine.  Larger serving buckets still compile on first use —
+        the load ramp, not the trickle path, absorbs those.  What a
+        takeover runs (``prepare``, ``install_coordinator``, and
+        ``propose_accept_self_p`` for what was orphaned or parked) has
+        no ramp: a leader's death is its first use, so a node with
+        peers loads those at every bucket at boot
+        (:meth:`warm_elections`, called by ``PaxosNode``)."""
         k, b = self._k, _bucket(0)
 
         def z(rows_):
@@ -700,6 +710,40 @@ class ColumnarBackend(AcceptorBackend):
             st, _, _ = k.request_reply_p(st, z(5), z(6))
             self.state = st
         EngineLedger.mark_warm()
+
+    def warm_elections(self) -> None:
+        """Load ``prepare``, ``install_coordinator`` and the new
+        coordinator's re-proposal wave (``propose_accept_self_p``:
+        orphaned and parked requests proposed under the new ballot, as
+        many in one call as were waiting) at every bucket a takeover
+        can dispatch: a mass election runs in ``_BUCKET_CAP`` chunks
+        and its stragglers (rows with accepted pvalues, re-drives) one
+        or a few rows at a time, so every step of the ladder up to the
+        capacity's own.  All-invalid lanes, a state no-op, under the
+        ledger's warming bracket like :meth:`_warm_kernels`; what it
+        saves is a compile (seconds at serving capacity) inside the
+        seconds in which the dead leader's groups have no
+        coordinator."""
+        k, W = self._k, self._window
+        top = _bucket(self.capacity)
+        with EngineLedger.warming():
+            st, b = self.state, _bucket(0)
+            while True:
+                z, no = np.zeros(b, np.int32), np.zeros(b, bool)
+                zw = np.zeros((b, W), np.int32)
+                with self._disp():
+                    st, _ = k.prepare(st, self._dev(z), self._dev(z),
+                                      self._dev(no))
+                    st, _ = k.install_coordinator(
+                        st, self._dev(z), self._dev(z), self._dev(z),
+                        self._dev(zw), self._dev(zw), self._dev(zw),
+                        self._dev(no))
+                    st, _ = k.propose_accept_self_p(
+                        st, self._dev(np.zeros((5, b), np.int32)))
+                if b >= top:
+                    break
+                b <<= 3
+            self.state = st
 
     @property
     def window(self) -> int:
@@ -1052,18 +1096,44 @@ class ColumnarBackend(AcceptorBackend):
             rows_p, reqs_p, self_midx, rows_r, slots_r, bals_r,
             senders_r, acked_r).collect()
 
+    def _election_span(self, kind: str, name: str, n: int, chunks,
+                       back: int):
+        """The span of one election dispatch (``gp.eng.prepare`` /
+        ``gp.eng.install`` and the total of the same name): the kernel
+        it launches and the jitted function's own name (``program``: a
+        device trace lists its runs as ``jit_<program>``), valid lanes,
+        first chunk's bucket, chunks, and the ``bytes`` its results copy
+        back to the host (``back`` a padded lane)."""
+        return span(kind, n=n, kernel=self._kpfx + name,
+                    program=getattr(getattr(self._k, name), "__name__",
+                                    name), lanes=n,
+                    bucket=_bucket(chunks[0][1] - chunks[0][0]),
+                    chunks=len(chunks),
+                    bytes=back * sum(_bucket(b - a) for a, b in chunks))
+
     def prepare(self, rows, bals) -> PrepareRes:
         rows, bals = np.asarray(rows), np.asarray(bals)
         n = len(rows)
-        parts = []
-        for a, b in _chunks(n):
-            with self._disp():
-                self.state, o = self._k.prepare(
-                    self.state, self._pad1(rows[a:b], 0),
-                    self._pad1(bals[a:b], NO_BALLOT), self._valid(b - a))
+        chunks = _chunks(n)
+        # copied back a lane: acked (a byte), the promise and the
+        # cursor, and the four [W] window columns
+        with self._election_span("eng.prepare", "prepare", n, chunks,
+                                 9 + 16 * self._window):
+            outs = []
+            for a, b in chunks:
+                with self._disp():
+                    self.state, o = self._k.prepare(
+                        self.state, self._pad1(rows[a:b], 0),
+                        self._pad1(bals[a:b], NO_BALLOT),
+                        self._valid(b - a))
+                for x in o:
+                    _d2h_start(x)
+                outs.append((o, b - a))
             # materialize OUTSIDE the dispatch lock (the lock's job is
             # serializing sharded program dispatch, not d2h transfers)
-            parts.append(self._np(o, b - a))
+            # and behind the last launch: a chunk's copy runs while the
+            # next chunk computes
+            parts = [self._np(o, m) for o, m in outs]
         acked, cur_bal, cursor, ws, wb, wl, wh = parts[0] \
             if len(parts) == 1 else \
             tuple(np.concatenate(f) for f in zip(*parts))
@@ -1090,21 +1160,24 @@ class ColumnarBackend(AcceptorBackend):
         lo, hi = _split64(carry_req.reshape(-1))
         lo = lo.reshape(len(rows), m)
         hi = hi.reshape(len(rows), m)
-        for a, bnd in _chunks(len(rows)):
-            n = bnd - a
-            b = _bucket(n)
-            cs = np.full((b, W), NO_SLOT, np.int32)
-            cl = np.zeros((b, W), np.int32)
-            ch = np.zeros((b, W), np.int32)
-            cs[:n, :m] = carry_slot[a:bnd]
-            cl[:n, :m] = lo[a:bnd]
-            ch[:n, :m] = hi[a:bnd]
-            with self._disp():
-                self.state, _ = self._k.install_coordinator(
-                    self.state, self._pad1(rows[a:bnd], 0),
-                    self._pad1(cbals[a:bnd], NO_BALLOT),
-                    self._pad1(next_slots[a:bnd], 0), self._dev(cs),
-                    self._dev(cl), self._dev(ch), self._valid(n))
+        chunks = _chunks(len(rows))
+        with self._election_span("eng.install", "install_coordinator",
+                                 len(rows), chunks, 0):
+            for a, bnd in chunks:
+                n = bnd - a
+                b = _bucket(n)
+                cs = np.full((b, W), NO_SLOT, np.int32)
+                cl = np.zeros((b, W), np.int32)
+                ch = np.zeros((b, W), np.int32)
+                cs[:n, :m] = carry_slot[a:bnd]
+                cl[:n, :m] = lo[a:bnd]
+                ch[:n, :m] = hi[a:bnd]
+                with self._disp():
+                    self.state, _ = self._k.install_coordinator(
+                        self.state, self._pad1(rows[a:bnd], 0),
+                        self._pad1(cbals[a:bnd], NO_BALLOT),
+                        self._pad1(next_slots[a:bnd], 0), self._dev(cs),
+                        self._dev(cl), self._dev(ch), self._valid(n))
 
     def set_cursor(self, rows, cursors, next_slots) -> None:
         rows, cursors = np.asarray(rows), np.asarray(cursors)

@@ -330,6 +330,10 @@ class PaxosNode:
                 self.backend = ScalarBackend(win)
         else:
             self.backend = ScalarBackend(win)
+        if len(self.addr_map) > 1:
+            # a node with peers can lose a leader: the election programs
+            # are loaded now, not inside the first takeover
+            self.backend.warm_elections()
         # fused C stage handlers (native backend only): one C call per
         # worker batch per stage, updating the numpy mirrors in place —
         # the per-batch numpy assembly cost (~1ms/batch chain at small
@@ -642,6 +646,9 @@ class PaxosNode:
         self.n_redrive_capped = 0  # re-drive ticks that hit the 256 cap
         self.n_wave_dups = 0      # copies of a request within one wave
         self.n_installs = 0       # coordinator installs won (failover)
+        self.n_elections_started = 0    # rows phase 1 was begun for
+        self.n_elections_won = 0        # of them, a quorum promised
+        self.n_elections_preempted = 0  # of them, a higher ballot won
         self.n_shed_disk = 0      # proposals shed status 5 (WAL impaired)
         self.n_wal_nacked = 0     # accepts nacked because WAL failed
         # one-shot latch so the degraded-mode blackbox trigger and log
@@ -2090,6 +2097,9 @@ class PaxosNode:
                 "shed_disk": self.n_shed_disk,
                 "wal_nacked": self.n_wal_nacked,
                 "installs": self.n_installs,
+                "elections_started": self.n_elections_started,
+                "elections_won": self.n_elections_won,
+                "elections_preempted": self.n_elections_preempted,
                 "ballot_changes": self.n_ballot_changes,
                 "groups": len(self.table),
                 "backlog_est": self._backlog_est,
@@ -3818,7 +3828,8 @@ class PaxosNode:
         self._last_heard.pop(node, None)
         self._suspects.add(node)
         log.info("node %d: peer %d suspected dead", self.id, node)
-        self._elect_rows_led_by(node, self._now())
+        with traced("fo.suspect", node=self.id, dead=node):
+            self._elect_rows_led_by(node, self._now())
 
     def _elect_rows_led_by(self, dead: int, now: float) -> None:
         """Vectorized replacement for the per-meta scan (SURVEY §3.5:
@@ -3827,11 +3838,29 @@ class PaxosNode:
         finds every row led by ``dead``; the next-in-line decision is
         computed once per DISTINCT member set (interned tuples — a
         million-group fleet typically has a handful)."""
-        t0 = time.monotonic()
         cand = np.flatnonzero((self._bal >= 0)
                               & ((self._bal & NODE_MASK) == dead))
         if not len(cand):
             return
+        with span("fo.scan", node=self.id, n=len(cand), dead=dead) as sp:
+            by_mems = self._rows_to_elect(cand, dead, now)
+            n_elect = sum(len(r) for r in by_mems.values())
+            sp.note(elect=n_elect)
+        if not n_elect:
+            return
+        if n_elect < 64:
+            by_row = self.table._by_row
+            for rows_ in by_mems.values():
+                for row in rows_:
+                    self._start_election(row, by_row[row])
+        else:
+            self._start_elections_batch(by_mems, now)
+
+    def _rows_to_elect(self, cand: np.ndarray, dead: int, now: float
+                       ) -> Dict[Tuple[int, ...], List[int]]:
+        """Of the rows ``cand`` led by ``dead``, those this node is next
+        in line for and has no fresh election open on, by member set."""
+        by_mems: Dict[Tuple[int, ...], List[int]] = {}
         if self._mass_el is not None and self._mass_el.n_live:
             # skip rows whose SoA-cohort election is fresher than the
             # re-drive backoff (the dict check below can't see them;
@@ -3846,14 +3875,12 @@ class PaxosNode:
                                   < backoff)
             cand = cand[~fresh]
             if not len(cand):
-                return
+                return by_mems
         by_row = self.table._by_row
         nxt_cache: Dict[Tuple[int, ...], Optional[int]] = {}
-        by_mems: Dict[Tuple[int, ...], List[int]] = {}
         els = self._elections
         check_els = bool(els)
         my_id = self.id
-        n_elect = 0
         for row in cand.tolist():
             meta = by_row[row]
             if meta is None:
@@ -3873,16 +3900,7 @@ class PaxosNode:
                 nxt_cache[mems] = nxt
             if nxt == my_id:
                 by_mems.setdefault(mems, []).append(row)
-                n_elect += 1
-        if not n_elect:
-            return
-        DelayProfiler.update_total("fo.scan", t0, len(cand))
-        if n_elect < 64:
-            for rows_ in by_mems.values():
-                for row in rows_:
-                    self._start_election(row, by_row[row])
-        else:
-            self._start_elections_batch(by_mems, now)
+        return by_mems
 
     def _next_in_line(self, members: Tuple[int, ...], dead: int,
                       now: float) -> Optional[int]:
@@ -3932,35 +3950,39 @@ class PaxosNode:
         SoA cohort bookkeeping instead of one `_Election` per row.
         Takes rows pre-grouped by (interned) member set — the scan that
         found them already knows it."""
-        t0 = time.monotonic()
         if self._mass_el is None:
             self._mass_el = _MassElections(len(self._bal))
         total = 0
         CH = 1 << 16
-        for mems, rows_list in by_mems.items():
-            arr = np.asarray(rows_list, np.int64)
-            bals = self._bal[arr].astype(np.int64)
-            nums = np.where(bals >= 0, bals >> NODE_BITS, 0)
-            new_bals = ((nums + 1) << NODE_BITS
-                        | self.id).astype(np.int32)
-            gkeys = self._row_gkey[arr]
-            # a row re-driven out of the dict path must not be tracked
-            # twice (dict wins the reply merge; the SoA entry would
-            # rot).  Intersect from the SMALL side: dict elections are
-            # few, the cohort can be a million rows.
-            if self._elections:
-                rowset = set(rows_list)
-                for row in [r for r in self._elections if r in rowset]:
-                    self._elections.pop(row, None)
-            self._mass_el.start(arr, new_bals,
-                                len(mems) // 2 + 1, now)
-            total += len(rows_list)
-            for at in range(0, len(arr), CH):
-                fg = np.ascontiguousarray(gkeys[at:at + CH])
-                fb = np.ascontiguousarray(new_bals[at:at + CH])
-                for m in mems:
-                    self._route(m, pkt.PrepareBatch(self.id, fg, fb))
-        DelayProfiler.update_total("fo.elect_start", t0, total)
+        with span("fo.elect_start", node=self.id, n=0) as sp:
+            for mems, rows_list in by_mems.items():
+                arr = np.asarray(rows_list, np.int64)
+                bals = self._bal[arr].astype(np.int64)
+                nums = np.where(bals >= 0, bals >> NODE_BITS, 0)
+                new_bals = ((nums + 1) << NODE_BITS
+                            | self.id).astype(np.int32)
+                gkeys = self._row_gkey[arr]
+                # a row re-driven out of the dict path must not be
+                # tracked twice (dict wins the reply merge; the SoA
+                # entry would rot).  Intersect from the SMALL side: dict
+                # elections are few, the cohort can be a million rows.
+                if self._elections:
+                    rowset = set(rows_list)
+                    for row in [r for r in self._elections
+                                if r in rowset]:
+                        self._elections.pop(row, None)
+                self._mass_el.start(arr, new_bals,
+                                    len(mems) // 2 + 1, now)
+                total += len(rows_list)
+                for at in range(0, len(arr), CH):
+                    fg = np.ascontiguousarray(gkeys[at:at + CH])
+                    fb = np.ascontiguousarray(new_bals[at:at + CH])
+                    for m in mems:
+                        self._route(m, pkt.PrepareBatch(self.id, fg, fb))
+            sp.n = total
+            sp.note(items=total)
+        with self._stat_lock:
+            self.n_elections_started += total
         log.info("node %d: batch election for %d groups", self.id, total)
 
     def _run_if_next_in_line(self, meta, dead: int, now: float) -> None:
@@ -3983,6 +4005,8 @@ class PaxosNode:
             return
         bal = pack_ballot(num + 1, self.id)
         self._elections[row] = _Election(bal=bal, started=self._now())
+        with self._stat_lock:
+            self.n_elections_started += 1
         for m in meta.members:
             self._route(m, pkt.Prepare(self.id, meta.gkey, bal))
 
@@ -3998,6 +4022,12 @@ class PaxosNode:
         if not best:
             return
         rows = list(best.keys())
+        with span("fo.prepare", node=self.id, n=len(rows),
+                  lanes=len(rows)):
+            self._answer_prepares(rows, best)
+
+    def _answer_prepares(self, rows: List[int],
+                         best: Dict[int, Tuple[int, int]]) -> None:
         res = self.backend.prepare(
             np.asarray(rows, np.int32),
             np.asarray([best[r][0] for r in rows], np.int32))
@@ -4032,51 +4062,63 @@ class PaxosNode:
         PrepareReplyBatch back.  Windows are flattened ragged — idle
         groups (the mass-takeover common case) contribute zero entries."""
         for o in objs:
-            gkeys = np.ascontiguousarray(o.gkey)
-            rows = self._rows_for_keys(gkeys).astype(np.int64)
-            ok = rows >= 0
-            if not ok.any():
-                continue
-            rows_ok = rows[ok]
-            bals_ok = np.ascontiguousarray(o.bal[ok], np.int32)
-            res = self.backend.prepare(rows_ok.astype(np.int32), bals_ok)
-            cur = np.asarray(res.cur_bal)
-            self._note_ballot_change(rows_ok[cur > self._bal[rows_ok]])
-            np.maximum.at(self._bal, rows_ok, cur)
-            live = np.asarray(res.win_slot) >= 0  # compacted-left (SPI)
-            counts = live.sum(axis=1).astype(np.int32)
-            total = int(counts.sum())
-            if total:
-                flat = np.flatnonzero(live.reshape(-1))
-                slots_f = np.asarray(res.win_slot).reshape(-1)[flat]
-                wbals_f = np.asarray(res.win_bal).reshape(-1)[flat]
-                rlo_f = np.asarray(res.win_req_lo).reshape(-1)[flat]
-                rhi_f = np.asarray(res.win_req_hi).reshape(-1)[flat]
-                pls = []
-                for j in range(total):
-                    req = _join_req(int(rlo_f[j]), int(rhi_f[j]))
-                    got = self._payload_get(req)
-                    fl, pl = got if got is not None else (FLAG_MISSING,
-                                                         b"")
-                    pls.append(bytes([fl]) + pl)
-            else:
-                slots_f = wbals_f = rlo_f = rhi_f = np.zeros(0, np.int32)
-                pls = []
-            acked = np.asarray(res.acked)
-            self._route(o.sender, pkt.PrepareReplyBatch(
-                self.id, np.ascontiguousarray(gkeys[ok]),
-                np.where(acked, bals_ok,
-                         np.asarray(res.cur_bal)).astype(np.int32),
-                acked.astype(np.uint8),
-                np.asarray(res.exec_cursor, np.int32), counts,
-                slots_f.astype(np.int32), wbals_f.astype(np.int32),
-                rlo_f.astype(np.int32), rhi_f.astype(np.int32), pls))
+            with span("fo.prepare", node=self.id, n=len(o.gkey),
+                      lanes=len(o.gkey)):
+                self._answer_prepare_batch(o)
+
+    def _answer_prepare_batch(self, o) -> None:
+        gkeys = np.ascontiguousarray(o.gkey)
+        rows = self._rows_for_keys(gkeys).astype(np.int64)
+        ok = rows >= 0
+        if not ok.any():
+            return
+        rows_ok = rows[ok]
+        bals_ok = np.ascontiguousarray(o.bal[ok], np.int32)
+        res = self.backend.prepare(rows_ok.astype(np.int32), bals_ok)
+        cur = np.asarray(res.cur_bal)
+        self._note_ballot_change(rows_ok[cur > self._bal[rows_ok]])
+        np.maximum.at(self._bal, rows_ok, cur)
+        live = np.asarray(res.win_slot) >= 0  # compacted-left (SPI)
+        counts = live.sum(axis=1).astype(np.int32)
+        total = int(counts.sum())
+        if total:
+            flat = np.flatnonzero(live.reshape(-1))
+            slots_f = np.asarray(res.win_slot).reshape(-1)[flat]
+            wbals_f = np.asarray(res.win_bal).reshape(-1)[flat]
+            rlo_f = np.asarray(res.win_req_lo).reshape(-1)[flat]
+            rhi_f = np.asarray(res.win_req_hi).reshape(-1)[flat]
+            pls = []
+            for j in range(total):
+                req = _join_req(int(rlo_f[j]), int(rhi_f[j]))
+                got = self._payload_get(req)
+                fl, pl = got if got is not None else (FLAG_MISSING,
+                                                     b"")
+                pls.append(bytes([fl]) + pl)
+        else:
+            slots_f = wbals_f = rlo_f = rhi_f = np.zeros(0, np.int32)
+            pls = []
+        acked = np.asarray(res.acked)
+        self._route(o.sender, pkt.PrepareReplyBatch(
+            self.id, np.ascontiguousarray(gkeys[ok]),
+            np.where(acked, bals_ok,
+                     np.asarray(res.cur_bal)).astype(np.int32),
+            acked.astype(np.uint8),
+            np.asarray(res.exec_cursor, np.int32), counts,
+            slots_f.astype(np.int32), wbals_f.astype(np.int32),
+            rlo_f.astype(np.int32), rhi_f.astype(np.int32), pls))
 
     def _handle_prepare_reply_batch(self, o) -> None:
         """Counterpart at the would-be coordinator.  The empty-window
         acked rows (idle fleet) take a vectorized fast path straight to
         ONE batched install; windowed/nacked rows reuse the per-row
         merge machinery."""
+        with span("fo.reply", node=self.id, n=len(o.gkey),
+                  lanes=len(o.gkey)) as sp:
+            sp.note(slow_rows=self._merge_prepare_replies(o))
+
+    def _merge_prepare_replies(self, o) -> int:
+        """One ``PrepareReplyBatch`` merged into the open elections;
+        returns how many of its lanes left the vectorised path."""
         gkeys = np.ascontiguousarray(o.gkey)
         rows = self.table.rows_for_keys(gkeys).astype(np.int64)
         counts = np.asarray(o.counts)
@@ -4087,7 +4129,7 @@ class PaxosNode:
             if handled is not None:
                 lanes = np.flatnonzero(~handled).tolist()
                 if not lanes:
-                    return
+                    return 0
         install_rows: List[int] = []
         by_row = self.table._by_row
         for i in lanes:
@@ -4105,6 +4147,8 @@ class PaxosNode:
                         self._note_ballot_change(row)
                         self._bal[row] = bal
                     del self._elections[row]
+                    with self._stat_lock:
+                        self.n_elections_preempted += 1
                 continue
             if bal != el.bal:
                 continue
@@ -4129,7 +4173,9 @@ class PaxosNode:
             if len(el.acks) >= len(meta.members) // 2 + 1:
                 install_rows.append(row)
         if not install_rows:
-            return
+            return len(lanes)
+        with self._stat_lock:
+            self.n_elections_won += len(install_rows)
         # split: rows with carryover state or a catch-up need go through
         # the full per-row install; idle rows (no merged pvalues, cursor
         # already reached) install in ONE batched backend call
@@ -4143,6 +4189,7 @@ class PaxosNode:
                 simple.append(row)
         if simple:
             self._install_simple_batch(simple)
+        return len(lanes)
 
     def _mass_reply_frame(self, o, rows: np.ndarray,
                           counts: np.ndarray) -> Optional[np.ndarray]:
@@ -4170,6 +4217,8 @@ class PaxosNode:
                 r = rows[hi]
                 np.maximum.at(self._bal, r, bals[hi])
                 mass.kill(r)
+                with self._stat_lock:
+                    self.n_elections_preempted += len(r)
             handled |= nack
         match = in_mass & acked & (bals == mass.bal[idx0])
         handled |= in_mass & acked & ~match  # stale-ballot ack: ignore
@@ -4199,6 +4248,8 @@ class PaxosNode:
         if ready.any():
             r_rows = rows[match][ready]
             r_idx = iv[ready]
+            with self._stat_lock:
+                self.n_elections_won += len(r_rows)
             r_bals = mass.bal[r_idx].copy()
             behind = mass.cursor[r_idx] > self._cur[r_rows]
             if behind.any():
@@ -4228,7 +4279,14 @@ class PaxosNode:
 
     def _install_simple_rows(self, arr: np.ndarray,
                              bals: np.ndarray) -> None:
-        t0 = time.monotonic()
+        with span("fo.install", node=self.id, n=len(arr),
+                  items=len(arr), carried=0):
+            self._install_idle_rows(arr, bals)
+        log.info("node %d: batch-installed coordinator for %d groups",
+                 self.id, len(arr))
+
+    def _install_idle_rows(self, arr: np.ndarray,
+                           bals: np.ndarray) -> None:
         n = len(arr)
         W = self.backend.window
         next_slots = self._cur[arr].astype(np.int32)
@@ -4266,9 +4324,6 @@ class PaxosNode:
                 self._flush_parked(row)
         if reprops:
             self._handle_requests([], reprops)
-        DelayProfiler.update_total("fo.install", t0, n)
-        log.info("node %d: batch-installed coordinator for %d groups",
-                 self.id, n)
 
     def _handle_prepare_reply(self, o) -> None:
         meta = self.table.by_key(o.gkey)
@@ -4288,6 +4343,8 @@ class PaxosNode:
                     self._note_ballot_change(row)
                     self._bal[row] = o.bal
                 del self._elections[row]
+                with self._stat_lock:
+                    self.n_elections_preempted += 1
             return
         if o.bal != el.bal:
             return
@@ -4316,9 +4373,17 @@ class PaxosNode:
             return
         # majority: install + re-propose carryover, fill holes with noops
         del self._elections[row]
+        with self._stat_lock:
+            self.n_elections_won += 1
         self._install_as_coordinator(row, meta, el)
 
     def _install_as_coordinator(self, row: int, meta, el: _Election) -> None:
+        with span("fo.install", node=self.id, n=1, items=1) as sp:
+            sp.note(carried=self._install_with_carry(row, meta, el))
+
+    def _install_with_carry(self, row: int, meta, el: _Election) -> int:
+        """Install for one row with what a quorum reported accepted and
+        undecided (the per-row path); returns the slots carried over."""
         cursor = max(el.cursor, int(self._cur[row]))
         carry = {s: v for s, v in (el.merged or {}).items()
                  if s >= cursor}
@@ -4401,6 +4466,7 @@ class PaxosNode:
                     np.asarray([el.bal] * len(items), np.int32),
                     *_split_reqs([v[1] for _, v in items]),
                     payloads=[bytes([v[2]]) + v[3] for _, v in items]))
+        return len(carry)
 
     # ------------------------------------------------------------------
     # failure-detection ping task (event loop side)

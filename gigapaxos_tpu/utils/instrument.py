@@ -61,6 +61,15 @@ after a profiled window it holds that window and only that.
                      (a call of its sum only where something  reply_bytes
                      executed)
     gp.eng.compile * a kernel's trace (EngineLedger.traced)  kernel
+    gp.fo.suspect *  _on_node_dead (with its scan)   dead
+    gp.fo.scan       rows led by a suspect, by member set    dead, elect
+    gp.fo.elect_start  _start_elections_batch        items
+    gp.fo.prepare    an acceptor's answer to Prepare(Batch)  lanes
+    gp.fo.reply      a PrepareReplyBatch merged      lanes, slow_rows
+    gp.fo.install    coordinator install (batch or one row)  items,
+                                                     carried
+    gp.eng.prepare   backend.prepare     kernel, program, lanes, bucket,
+    gp.eng.install   backend.install_coordinator     chunks, bytes
 
 (* :func:`traced`: the boundaries inside a summed stage are spans with
 no sum of their own, and off they cost one gate check and no object.)

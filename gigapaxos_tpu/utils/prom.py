@@ -127,6 +127,9 @@ def render_prometheus(m: dict, prefix: str = "gp") -> str:
             ("wal_nacked", "accept votes withdrawn (nacked) because "
              "the WAL durability barrier failed"),
             ("installs", "coordinator installs won (failover)"),
+            ("elections_started", "rows phase 1 was begun for"),
+            ("elections_won", "elections a quorum promised"),
+            ("elections_preempted", "elections lost to a higher ballot"),
             ("ballot_changes",
              "ballot/leader churn: new ballots adopted across groups "
              "(elections won, preemptions, higher-ballot promises)")):
