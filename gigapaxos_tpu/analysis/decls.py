@@ -389,6 +389,8 @@ def project_decls() -> Decls:
                 "RTT sample fed to Transport.note_rtt (metrics only)",
             "PaxosNode._process_inner::monotonic":
                 "per-wave handler-latency profiler span (metrics only)",
+            "PaxosNode._handle_hot_split::monotonic":
+                "per-stage handler-latency profiler spans (metrics only)",
             "PaxosNode._execute_row::_batch_t0":
                 "app-retry sleep budget: wall elapsed vs the batch's "
                 "wall anchor gates a retry SLEEP, never a frame field",

@@ -49,6 +49,7 @@ a body is exactly what it was.
 
 from __future__ import annotations
 
+import itertools
 from typing import NamedTuple, Optional
 
 import jax
@@ -846,6 +847,47 @@ def accept_commit_packed(state: ColumnarState, acc, com):
     return state, aout, cout
 
 
+# rows of the four sections of a node wave: what it takes in (the valid
+# mask is each section's last row) and what it gives back, in the order
+# the stages run
+WAVE_SECTIONS = ("req", "rep", "acc", "com")
+WAVE_IN = (5, 6, 6, 5)
+WAVE_OUT = (9, 9, 4, 4)
+
+
+def _cuts(widths):
+    """[lo, hi) row slices of sections stacked in ``widths`` order."""
+    ends = list(itertools.accumulate(widths))
+    return tuple(zip([0] + ends[:-1], ends))
+
+
+WAVE_IN_CUTS, WAVE_OUT_CUTS = _cuts(WAVE_IN), _cuts(WAVE_OUT)
+
+
+def node_wave_packed(state: ColumnarState, packed):
+    """ONE worker batch's hot frames in ONE device dispatch:
+    packed[22, B] holds the four sections :data:`WAVE_SECTIONS` stacked
+    (requests as :func:`propose_accept_self_packed` takes them, accept
+    replies as :func:`accept_reply_commit_self_packed`, accepts as
+    :func:`accept_packed`, commits as :func:`commit_packed`, each with
+    its own valid row) -> out[26, B], their four outputs stacked.
+
+    Sequential composition of the same four packed bodies in the order
+    the manager's handlers run them (coordinator's stages, then the
+    acceptor's), so the state transition is bit-identical to the four
+    calls, or to :func:`request_reply_packed` then
+    :func:`accept_commit_packed`.  All four sections share ONE bucket
+    (the caller pads to the longest), which bounds this program's jit
+    cache to the ladder; a section with no lanes is all padding."""
+    outs = []
+    for body, (lo, hi) in zip(
+            (propose_accept_self_packed, accept_reply_commit_self_packed,
+             accept_packed, commit_packed), WAVE_IN_CUTS):
+        state, out = body(state, packed[lo:hi])
+        outs.append(out)
+    return state, jnp.concatenate(outs)
+
+
 # --------------------------------------------------------------------------
 # jit entry points
 # --------------------------------------------------------------------------
@@ -880,6 +922,7 @@ accept_reply_p = _jit("accept_reply_p", accept_reply_packed)
 commit_p = _jit("commit_p", commit_packed)
 accept_commit_p = _jit("accept_commit_p", accept_commit_packed)
 request_reply_p = _jit("request_reply_p", request_reply_packed)
+node_wave_p = _jit("node_wave_p", node_wave_packed)
 prepare = _jit("prepare", prepare_batch)
 install_coordinator = _jit("install_coordinator",
                            install_coordinator_batch)

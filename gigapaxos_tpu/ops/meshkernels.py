@@ -101,6 +101,22 @@ def _packed2(body):
     return local
 
 
+def _wave_local(state, packed):
+    """Local program for :func:`kernels.node_wave_packed`: each of the
+    four stacked sections masked down to the lanes this shard owns, as
+    :func:`_packed1` does for one, and the stacked output merged under
+    its section's mask in ONE ``psum``."""
+    secs, masks = [], []
+    for (lo, hi), k in zip(_K.WAVE_IN_CUTS, _K.WAVE_OUT):
+        p = packed[lo:hi]
+        mine, lg = _own(state, p[0], p[-1] != 0)
+        secs.append(p.at[0].set(lg).at[-1].set(mine.astype(_i32)))
+        masks.append(jnp.broadcast_to(mine[None, :], (k,) + mine.shape))
+    state, out = _K.node_wave_packed(state, jnp.concatenate(secs))
+    return state, jax.lax.psum(
+        jnp.where(jnp.concatenate(masks), out, 0), GROUP_AXIS)
+
+
 def _rowcall(body):
     """Local program for the unpacked row ops whose first batch array is
     the row index and last is the valid mask, returning state only
@@ -163,6 +179,8 @@ class MeshKernels:
         self.request_reply_p = jit1(
             "request_reply_p", _packed2(_K.request_reply_packed), 2,
             (sh, rp, rp))
+        # one worker batch's four sections in one program
+        self.node_wave_p = jit1("node_wave_p", _wave_local, 1, (sh, rp))
         # unpacked cold/control ops
         self.prepare = jit1("prepare", _prepare_local, 3, (sh, rp))
         self._install = jit1(

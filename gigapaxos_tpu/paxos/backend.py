@@ -28,6 +28,8 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from gigapaxos_tpu.ops.kernels import (WAVE_IN, WAVE_IN_CUTS,
+                                       WAVE_OUT_CUTS, WAVE_SECTIONS)
 from gigapaxos_tpu.ops.oracle import OracleGroup, PValue, make_oracle_group
 from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT, PLANES
 from gigapaxos_tpu.utils.engineledger import EngineLedger
@@ -186,6 +188,7 @@ class AcceptorBackend(abc.ABC):
 
     engine_platform = "cpu"  # overridden by device-resident backends
     engine_mesh = "off"  # device-mesh size when group-axis sharded
+    launches = 0  # hot programs launched (a chunk each); columnar only
 
     def memory_info(self) -> Optional[dict]:
         """Slab memory accounting (``GET /engine``): per-plane bytes,
@@ -488,6 +491,7 @@ class NativeBackend(AcceptorBackend):
 
 
 _BUCKET_CAP = 4096  # largest jit bucket; bigger batches dispatch chunked
+_WAVE_ROWS = sum(WAVE_IN)  # rows of node_wave_p's one packed input
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -504,6 +508,15 @@ def _bucket(n: int, lo: int = 8) -> int:
     while b < n and b < _BUCKET_CAP:
         b <<= 3
     return b
+
+
+def _ladder(top: int = _BUCKET_CAP):
+    """The buckets :func:`_bucket` can return, up to ``top``'s own."""
+    b = _bucket(0)
+    while b < top:
+        yield b
+        b <<= 3
+    yield b
 
 
 def _chunks(n: int) -> List[Tuple[int, int]]:
@@ -568,6 +581,37 @@ def _d2h_start(out) -> None:
     """Begin the async device->host copy of a kernel output (JAX async
     dispatch)."""
     out.copy_to_host_async()
+
+
+def _accept_res(out) -> AcceptRes:
+    return AcceptRes(out[0] != 0, out[1] != 0, out[2] != 0, out[3])
+
+
+def _commit_res(out) -> CommitRes:
+    return CommitRes(out[0] != 0, out[1] != 0, out[2] != 0, out[3])
+
+
+def _propose_self_res(out):
+    """``propose_accept_self_p``'s [9, n] -> (ProposeRes, self_acked,
+    newly_decided, preempted, acc_cur_bal)."""
+    granted = out[0] != 0
+    return (ProposeRes(granted, out[1] != 0, out[2] != 0,
+                       np.where(granted, out[3], NO_SLOT), out[4]),
+            out[5] != 0, out[6] != 0, out[7] != 0, out[8])
+
+
+def _reply_res(out) -> AcceptReplyRes:
+    newly = out[0] != 0
+    # decision fields only meaningful on newly-decided lanes
+    return AcceptReplyRes(
+        newly, out[1] != 0, np.where(newly, out[3], 0),
+        np.where(newly, out[4], 0), np.where(newly, out[2], NO_BALLOT))
+
+
+def _reply_self_res(out):
+    """``accept_reply_commit_self_p``'s [9, n] -> (AcceptReplyRes,
+    applied, stale): the last two are the coordinator's own commit."""
+    return _reply_res(out), out[6] != 0, out[7] != 0
 
 
 def _collect_cols(outs: List[Tuple[object, int]]) -> np.ndarray:
@@ -675,15 +719,20 @@ class ColumnarBackend(AcceptorBackend):
         self._warm_kernels()
 
     def _warm_kernels(self) -> None:
-        """Compile the eight hot SERVING kernels on all-padding inputs
-        at the smallest bucket NOW, at construction, instead of
-        mid-serving: a cold first-touch compile (~2-20 s at serving
-        capacities on a one-core host) landing inside a request window
-        reads as a multi-second latency spike or a client timeout.
-        All-invalid lanes make every warm call a state no-op; with the
-        persistent cache this is a disk load after the first process on
-        a machine.  Larger serving buckets still compile on first use —
-        the load ramp, not the trickle path, absorbs those.  What a
+        """Compile the hot SERVING kernels on all-padding inputs NOW,
+        at construction, instead of mid-serving: a cold first-touch
+        compile (~2-20 s at serving capacities on a one-core host)
+        landing inside a request window reads as a multi-second latency
+        spike or a client timeout.  All-invalid lanes make every warm
+        call a state no-op; with the persistent cache this is a disk
+        load after the first process on a machine.  ``node_wave_p``,
+        the one program a node's worker batches launch where whole
+        waves fuse, is loaded at EVERY bucket of the ladder: which
+        buckets a warm-up's traffic reaches is chance, and a program
+        first used inside a window compiles there.  The eight split
+        and pair kernels at the smallest bucket only: their larger
+        buckets still compile on first use, and the load ramp, not the
+        trickle path, absorbs those.  What a
         takeover runs (``prepare``, ``install_coordinator``, and
         ``propose_accept_self_p`` for what was orphaned or parked) has
         no ramp: a leader's death is its first use, so a node with
@@ -691,7 +740,7 @@ class ColumnarBackend(AcceptorBackend):
         (:meth:`warm_elections`, called by ``PaxosNode``)."""
         k, b = self._k, _bucket(0)
 
-        def z(rows_):
+        def z(rows_, b=b):
             return self._dev(np.zeros((rows_, b), np.int32))
 
         # the warming bracket tells the ledger these traces define the
@@ -708,6 +757,9 @@ class ColumnarBackend(AcceptorBackend):
             st, _ = k.accept_reply_commit_self_p(st, z(6))
             st, _, _ = k.accept_commit_p(st, z(6), z(5))
             st, _, _ = k.request_reply_p(st, z(5), z(6))
+            for b in _ladder():
+                with self._disp():
+                    st, _ = k.node_wave_p(st, z(_WAVE_ROWS, b))
             self.state = st
         EngineLedger.mark_warm()
 
@@ -725,10 +777,9 @@ class ColumnarBackend(AcceptorBackend):
         seconds in which the dead leader's groups have no
         coordinator."""
         k, W = self._k, self._window
-        top = _bucket(self.capacity)
         with EngineLedger.warming():
-            st, b = self.state, _bucket(0)
-            while True:
+            st = self.state
+            for b in _ladder(_bucket(self.capacity)):
                 z, no = np.zeros(b, np.int32), np.zeros(b, bool)
                 zw = np.zeros((b, W), np.int32)
                 with self._disp():
@@ -740,9 +791,6 @@ class ColumnarBackend(AcceptorBackend):
                         self._dev(no))
                     st, _ = k.propose_accept_self_p(
                         st, self._dev(np.zeros((5, b), np.int32)))
-                if b >= top:
-                    break
-                b <<= 3
             self.state = st
 
     @property
@@ -815,6 +863,7 @@ class ColumnarBackend(AcceptorBackend):
         lanes launched (:meth:`_submit_done` adds the valid ones per
         kernel)."""
         launched = inputs * sum(_bucket(b - a) for a, b in chunks)
+        self.launches += len(chunks)
         DelayProfiler.add_total("eng.lanes_dispatched", 0.0, launched,
                                 calls=len(chunks))
         return span("eng.submit", n=n, kernel=self._kpfx + name, lanes=n,
@@ -900,11 +949,7 @@ class ColumnarBackend(AcceptorBackend):
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT), (lo, 0),
             (hi, 0)])
 
-        def finish():
-            out = _collect_cols(outs)
-            return AcceptRes(out[0] != 0, out[1] != 0, out[2] != 0,
-                             out[3])
-        return EngineWave(finish, n)
+        return EngineWave(lambda: _accept_res(_collect_cols(outs)), n)
 
     def accept(self, rows, slots, bals, req_ids) -> AcceptRes:
         return self.accept_submit(rows, slots, bals, req_ids).collect()
@@ -916,15 +961,7 @@ class ColumnarBackend(AcceptorBackend):
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
             (senders, 0), (np.asarray(acked, np.int32), 0)])
 
-        def finish():
-            out = _collect_cols(outs)
-            newly = out[0] != 0
-            # decision fields only meaningful on newly-decided lanes
-            return AcceptReplyRes(
-                newly, out[1] != 0, np.where(newly, out[3], 0),
-                np.where(newly, out[4], 0),
-                np.where(newly, out[2], NO_BALLOT))
-        return EngineWave(finish, n)
+        return EngineWave(lambda: _reply_res(_collect_cols(outs)), n)
 
     def accept_reply(self, rows, slots, bals, senders, acked
                      ) -> AcceptReplyRes:
@@ -947,11 +984,7 @@ class ColumnarBackend(AcceptorBackend):
         outs = self._submit1("commit_p", n, [
             (rows, 0), (slots, NO_SLOT), (lo, 0), (hi, 0)])
 
-        def finish():
-            out = _collect_cols(outs)
-            return CommitRes(out[0] != 0, out[1] != 0, out[2] != 0,
-                             out[3])
-        return EngineWave(finish, n)
+        return EngineWave(lambda: _commit_res(_collect_cols(outs)), n)
 
     def commit(self, rows, slots, req_ids) -> CommitRes:
         return self.commit_submit(rows, slots, req_ids).collect()
@@ -1008,12 +1041,9 @@ class ColumnarBackend(AcceptorBackend):
             nc, [(rows_c, 0), (slots_c, NO_SLOT), (lo_c, 0),
                  (hi_c, 0)])
 
-        def finish():
-            a = _collect_cols(outs_a)
-            c = _collect_cols(outs_c)
-            return (AcceptRes(a[0] != 0, a[1] != 0, a[2] != 0, a[3]),
-                    CommitRes(c[0] != 0, c[1] != 0, c[2] != 0, c[3]))
-        return EngineWave(finish, na + nc)
+        return EngineWave(lambda: (_accept_res(_collect_cols(outs_a)),
+                                   _commit_res(_collect_cols(outs_c))),
+                          na + nc)
 
     def accept_commit(self, rows_a, slots_a, bals_a, reqs_a,
                       rows_c, slots_c, reqs_c
@@ -1035,13 +1065,7 @@ class ColumnarBackend(AcceptorBackend):
         outs = self._submit1("accept_reply_commit_self_p", n, [
             (rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
             (senders, 0), (np.asarray(acked, np.int32), 0)])
-        out = self._collect_now(outs, n)
-        newly = out[0] != 0
-        res = AcceptReplyRes(
-            newly, out[1] != 0, np.where(newly, out[3], 0),
-            np.where(newly, out[4], 0),
-            np.where(newly, out[2], NO_BALLOT))
-        return res, out[6] != 0, out[7] != 0
+        return _reply_self_res(self._collect_now(outs, n))
 
     def propose_self(self, rows, req_ids, self_midx):
         """Fused propose + own accept + own vote (ONE device call per
@@ -1053,11 +1077,7 @@ class ColumnarBackend(AcceptorBackend):
         lo, hi = _split64(req_ids)
         outs = self._submit1("propose_accept_self_p", n, [
             (rows, 0), (lo, 0), (hi, 0), (self_midx, 0)])
-        out = self._collect_now(outs, n)
-        granted = out[0] != 0
-        pr = ProposeRes(granted, out[1] != 0, out[2] != 0,
-                        np.where(granted, out[3], NO_SLOT), out[4])
-        return pr, out[5] != 0, out[6] != 0, out[7] != 0, out[8]
+        return _propose_self_res(self._collect_now(outs, n))
 
     def propose_self_reply_submit(self, rows_p, reqs_p, self_midx,
                                   rows_r, slots_r, bals_r, senders_r,
@@ -1075,26 +1095,108 @@ class ColumnarBackend(AcceptorBackend):
             nr, [(rows_r, 0), (slots_r, NO_SLOT), (bals_r, NO_BALLOT),
                  (senders_r, 0), (np.asarray(acked_r, np.int32), 0)])
 
-        def finish():
-            p = _collect_cols(outs_p)
-            r = _collect_cols(outs_r)
-            granted = p[0] != 0
-            pres = (ProposeRes(granted, p[1] != 0, p[2] != 0,
-                               np.where(granted, p[3], NO_SLOT), p[4]),
-                    p[5] != 0, p[6] != 0, p[7] != 0, p[8])
-            newly = r[0] != 0
-            rres = (AcceptReplyRes(
-                newly, r[1] != 0, np.where(newly, r[3], 0),
-                np.where(newly, r[4], 0),
-                np.where(newly, r[2], NO_BALLOT)), r[6] != 0, r[7] != 0)
-            return pres, rres
-        return EngineWave(finish, np_ + nr)
+        return EngineWave(
+            lambda: (_propose_self_res(_collect_cols(outs_p)),
+                     _reply_self_res(_collect_cols(outs_r))), np_ + nr)
 
     def propose_self_reply(self, rows_p, reqs_p, self_midx,
                            rows_r, slots_r, bals_r, senders_r, acked_r):
         return self.propose_self_reply_submit(
             rows_p, reqs_p, self_midx, rows_r, slots_r, bals_r,
             senders_r, acked_r).collect()
+
+    def wave_submit(self, req=None, rep=None, acc=None, com=None
+                    ) -> EngineWave:
+        """ONE worker batch's hot frames in ONE device dispatch a chunk
+        (``kernels.node_wave_p``): ``req`` = (rows, req_ids, self_midx)
+        as :meth:`propose_self` takes them, ``rep`` = (rows, slots,
+        bals, senders, acked) as :meth:`accept_reply_commit_self`,
+        ``acc`` = (rows, slots, bals, req_ids) as :meth:`accept`,
+        ``com`` = (rows, slots, req_ids) as :meth:`commit`; a role the
+        batch does not have is None and rides as padding, so every
+        batch launches the same program.  One staged buffer, one
+        host->device copy, one launch and one copy back a chunk: the
+        four sections share the bucket of the longest.  ``collect()``
+        returns what those four calls return, in that order, None for
+        a section that was not given."""
+        given = (req, rep, acc, com)
+        if self._pallas is not None:
+            # the Pallas accept path owns accepts: the calls stay split
+            calls = (self.propose_self, self.accept_reply_commit_self,
+                     self.accept, self.commit)
+            res = tuple(None if sec is None else call(*sec)
+                        for call, sec in zip(calls, given))
+            return EngineWave(lambda: res, sum(
+                len(sec[0]) for sec in given if sec is not None))
+        # each section's columns as node_wave_packed stacks them; the
+        # fills are _packed's (what a padding lane holds is never read)
+        secs: list = [(), (), (), ()]
+        if req is not None:
+            rows, reqs, midx = req
+            secs[0] = ((rows, 0), *((c, 0) for c in _split64(reqs)),
+                       (midx, 0))
+        if rep is not None:
+            rows, slots, bals, senders, acked = rep
+            secs[1] = ((rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
+                       (senders, 0), (np.asarray(acked, np.int32), 0))
+        if acc is not None:
+            rows, slots, bals, reqs = acc
+            secs[2] = ((rows, 0), (slots, NO_SLOT), (bals, NO_BALLOT),
+                       *((c, 0) for c in _split64(reqs)))
+        if com is not None:
+            rows, slots, reqs = com
+            secs[3] = ((rows, 0), (slots, NO_SLOT),
+                       *((c, 0) for c in _split64(reqs)))
+        ns = [len(cols[0][0]) if cols else 0 for cols in secs]
+        n, chunks = sum(ns), _chunks(max(ns))
+        with self._submit_span("node_wave_p", n, chunks,
+                               inputs=len(secs)) as sp:
+            sp.note(sections="+".join(
+                name for name, m in zip(WAVE_SECTIONS, ns) if m))
+            secs = [[(np.asarray(c), f) for c, f in cols]
+                    for cols in secs]
+            outs = []
+            for a, bnd in chunks:
+                b = _bucket(bnd - a)
+                ms = [min(bnd, m) - min(a, m) for m in ns]
+                with traced("eng.pack", n=sum(ms),
+                            bytes=4 * _WAVE_ROWS * b):
+                    buf = np.empty((_WAVE_ROWS, b), np.int32)
+                    for (lo, hi), cols, m, n_s in zip(
+                            WAVE_IN_CUTS, secs, ms, ns):
+                        if not m:  # no lane of this role in the chunk
+                            buf[lo:hi] = 0
+                            continue
+                        at = min(a, n_s)
+                        for row, (col, fill) in zip(buf[lo:hi], cols):
+                            row[:m] = col[at:at + m]
+                            row[m:] = fill
+                        buf[hi - 1, :m] = 1  # valid mask
+                        buf[hi - 1, m:] = 0
+                    dev = self._dev(buf)
+                with self._disp():
+                    self.state, o = self._k.node_wave_p(self.state, dev)
+                _d2h_start(o)
+                outs.append((o, ms))
+        self._submit_done("node_wave_p", sp, n, len(chunks))
+
+        # finish holds the outputs and which roles were given, not the
+        # batch's columns
+        decode = [None if sec is None else dec for sec, dec in zip(
+            given, (_propose_self_res, _reply_self_res, _accept_res,
+                    _commit_res))]
+
+        def finish():
+            hosts = [(np.asarray(o), ms) for o, ms in outs]
+            res = []
+            for s, ((lo, hi), dec) in enumerate(zip(WAVE_OUT_CUTS,
+                                                    decode)):
+                cut = [h[lo:hi, :ms[s]] for h, ms in hosts]
+                res.append(dec and dec(
+                    cut[0] if len(cut) == 1
+                    else np.concatenate(cut, axis=1)))
+            return tuple(res)
+        return EngineWave(finish, n)
 
     def _election_span(self, kind: str, name: str, n: int, chunks,
                        back: int):
@@ -1319,7 +1421,8 @@ class ColumnarBackend(AcceptorBackend):
         sweep = [("propose_p", (z(4),)), ("accept_p", (z(6),)),
                  ("accept_reply_p", (z(6),)), ("commit_p", (z(5),)),
                  ("accept_commit_p", (z(6), z(5))),
-                 ("request_reply_p", (z(5), z(6)))]
+                 ("request_reply_p", (z(5), z(6))),
+                 ("node_wave_p", (z(_WAVE_ROWS),))]
         out: Dict[str, dict] = {}
         with EngineLedger.warming():
             for name, args in sweep:

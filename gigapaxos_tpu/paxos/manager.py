@@ -346,14 +346,15 @@ class PaxosNode:
         # i.e. two fewer host<->device round trips.
         self._col_self = self.backend \
             if isinstance(self.backend, ColumnarBackend) else None
-        # whole-wave fusion (accepts+commits, requests+replies — one
-        # engine dispatch per node per wave): a dispatch-tax trade.  On
+        # whole-wave fusion (a worker batch's requests, replies, accepts
+        # and commits — ONE engine dispatch per batch,
+        # _handle_node_wave): a dispatch-tax trade.  On
         # host XLA a dispatch is ~0.25 ms and the shared-bucket padding
         # costs more than it saves (CPU timing: knee 4.9K -> 3.2K req/s
         # fused), so "auto" fuses only when the engine device is an
         # accelerator, where every dispatch is a host<->device round
-        # trip.  What that trade is worth on the chip is not measured
-        # (ROADMAP D4 decides it on the ledger).
+        # trip (one wave a batch against two: commit_rate +20% in
+        # served-100k-d256, PERF.md §6, PR 35).
         fw = str(Config.get(PC.FUSE_WAVES))
         self._fuse_waves = self._col_self is not None and (
             fw == "on" or (fw == "auto" and
@@ -561,6 +562,12 @@ class PaxosNode:
         self._self_buf: Optional[List] = None
         self._window_moved: Optional[List] = None
         self._acc_ahead: Optional[List] = None
+        # whether the batch held a hot frame (hot_batches counts it)
+        self._batch_hot = False
+        # rows _reset_row freed while _handle_node_wave's coordinator
+        # posts ran (None outside them): their accept and commit lanes
+        # were resolved before, and leave the posts that follow
+        self._rows_freed: Optional[Set[int]] = None
         # per-batch start stamp (the app-retry sleep budget anchor)
         self._batch_t0 = 0.0
         # The one value two threads of a node read differently: the
@@ -645,6 +652,8 @@ class PaxosNode:
         self.n_park_dropped = 0   # parked proposals dropped at cap
         self.n_redrive_capped = 0  # re-drive ticks that hit the 256 cap
         self.n_wave_dups = 0      # copies of a request within one wave
+        self.n_hot_batches = 0    # worker batches that held a hot frame
+        self.n_one_wave_batches = 0  # of them, served by ONE engine launch
         self.n_installs = 0       # coordinator installs won (failover)
         self.n_elections_started = 0    # rows phase 1 was begun for
         self.n_elections_won = 0        # of them, a quorum promised
@@ -991,6 +1000,8 @@ class PaxosNode:
         self._row_gkey[row] = 0
         self._dec.pop(row, None)
         self._catchup_barrier.pop(row, None)
+        if self._rows_freed is not None:
+            self._rows_freed.add(row)
 
     def _touch(self, row: int) -> None:
         self._la[row] = self._now()
@@ -1741,6 +1752,8 @@ class PaxosNode:
         self._window_moved = []
         self._acc_ahead = []
         self._batch_t0 = time.time()  # app-retry sleep budget anchor
+        self._batch_hot = False
+        launches0 = self.backend.launches
         try:
             self._process_inner(batch)
             # follow-up waves: protocol chains are finite (request ->
@@ -1777,6 +1790,13 @@ class PaxosNode:
             left, resp, out = self._self_buf, self._resp_out, self._out_buf
             self._self_buf = self._resp_out = self._out_buf = None
             self._window_moved = self._acc_ahead = None
+            if self._batch_hot:
+                # one launch: no chunked wave over the bucket cap, no
+                # flush of parked proposals, no re-offered accept
+                with self._stat_lock:
+                    self.n_hot_batches += 1
+                    self.n_one_wave_batches += \
+                        self.backend.launches - launches0 == 1
             for obj in left or ():  # cap hit: requeue leftovers
                 self._inq.put(obj)
             if resp or out:
@@ -1934,95 +1954,23 @@ class PaxosNode:
         accepts = by_type.pop(pkt.AcceptBatch, [])
         commits = by_type.pop(pkt.CommitBatch, [])
         replies = by_type.pop(pkt.AcceptReplyBatch, [])
-        # fused coordinator wave (columnar): requests + replies in one
-        # device dispatch.  Reply-side state (votes/cbal) and accept-
-        # side state (bal/acc) are disjoint on device, and in steady
-        # state a node only receives accepts for groups it does NOT
-        # coordinate and replies for groups it does, so hoisting
-        # replies past accepts cannot reorder same-group work.
-        # Coordinator HANDOFF is the exception worth spelling out: for
-        # a beat after an election, a node can see BOTH accepts and
-        # replies for the SAME group in one batch — the dying
-        # coordinator's in-flight accepts arrive alongside replies to
-        # the accepts we re-drove at our new ballot.  The hoist is
-        # still safe then: (a) the reply kernel counts votes only at
-        # bal == cbal, and stale-regime replies carry the OLD ballot,
-        # so they are ignored regardless of order; (b) the accept
-        # kernel's only write shared with the reply path is the
-        # promised-ballot max, which is monotone — applying the old
-        # coordinator's accept before or after our reply wave yields
-        # the same max and the same ack/nack decision for every lane
-        # (a lower-ballot accept nacks either way once our install
-        # raised the promise); (c) the self-accept inside the fused
-        # request kernel writes our OWN row's acc window, which the
-        # foreign accept cannot touch in the same batch — the manager's
-        # (row, slot) coalesce keeps one lane per slot and a foreign
-        # coordinator of the same row would be a second regime whose
-        # lower ballot loses the max either way.
-        fuse_coord = bool(replies) and (reqs or props or soas) \
-            and self._fuse_waves
-        if fuse_coord:
+        n_hot = (len(reqs) + len(props) + len(replies) + len(accepts)
+                 + len(commits) + sum(len(s.gkey) for s in soas))
+        if n_hot:
+            self._batch_hot = True
+        if n_hot and self._fuse_waves:
+            # whole-wave fusion: every hot frame of the batch, whatever
+            # roles it holds, in ONE engine wave (_handle_node_wave has
+            # the ordering argument)
             t0 = time.monotonic()
             c0 = self._ct()
-            self._handle_requests_replies(reqs, props, soas, replies)
-            DelayProfiler.update_total(
-                "w.req_rep", t0,
-                len(reqs) + len(props) + len(replies)
-                + sum(len(s.gkey) for s in soas), cpu_t0=c0)
-        elif reqs or props or soas:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_requests(reqs, props, soas)
-            DelayProfiler.update_total(
-                "w.requests", t0,
-                len(reqs) + len(props) + sum(len(s.gkey) for s in soas),
-                cpu_t0=c0)
-        fuse_wave = accepts and commits and self._fuse_waves
-        # async overlapped acceptor wave (columnar, fusion off — the
-        # host-XLA operating point): submit the accept wave AND the
-        # commit wave back-to-back, then run the host halves in split-
-        # handler order, so the commit wave's device time overlaps the
-        # accept half's WAL fsync + reply build.  Same hoist-safety
-        # argument as fuse_wave (commit writes dec/exec only; both
-        # waves' pres touch only commutative mirror maxes).
-        overlap_wave = bool(accepts) and bool(commits) \
-            and not fuse_wave and self._col_self is not None
-        if fuse_wave or overlap_wave:
-            # fused acceptor wave: both types -> ONE device dispatch
-            # (or one submit+submit overlap).  Safe to hoist commits
-            # past replies: the commit kernel writes dec/exec state
-            # only, the reply kernel reads vote/coordinator state only
-            # (they commute), and commits in this batch are from prior
-            # waves.  The C-engine path keeps the split handlers (its
-            # per-stage calls are sub-ms).
-            t0 = time.monotonic()
-            c0 = self._ct()
-            if fuse_wave:
-                self._handle_accepts_commits(accepts, commits)
-            else:
-                self._handle_accepts_commits_overlapped(accepts,
-                                                        commits)
-            DelayProfiler.update_total(
-                "w.acc_com", t0, len(accepts) + len(commits),
-                cpu_t0=c0)
-        elif accepts:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_accepts(accepts)
-            DelayProfiler.update_total("w.accepts", t0, len(accepts),
+            self._handle_node_wave(reqs, props, soas, replies, accepts,
+                                   commits)
+            DelayProfiler.update_total("w.node_wave", t0, n_hot,
                                        cpu_t0=c0)
-        if replies and not fuse_coord:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_accept_replies(replies)
-            DelayProfiler.update_total("w.replies", t0, len(replies),
-                                       cpu_t0=c0)
-        if commits and not fuse_wave and not overlap_wave:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_commits(commits)
-            DelayProfiler.update_total("w.commits", t0, len(commits),
-                                       cpu_t0=c0)
+        elif n_hot:
+            self._handle_hot_split(reqs, props, soas, replies, accepts,
+                                   commits)
         for t, objs in by_type.items():
             handlers = self._handlers.get(t)
             if not handlers:
@@ -2038,6 +1986,59 @@ class PaxosNode:
                         log.exception("handler %r failed", h)
             DelayProfiler.update_total(f"w.upper.{t.__name__}", t0,
                                        len(objs))
+
+    def _handle_hot_split(self, reqs: List, props: List, soas: List,
+                          replies: List, accepts: List,
+                          commits: List) -> None:
+        """A batch's hot frames through the split handlers, a wave a
+        role in pipeline order (every engine but the columnar one with
+        whole-wave fusion on, which takes :meth:`_handle_node_wave`)."""
+        if reqs or props or soas:
+            t0 = time.monotonic()
+            c0 = self._ct()
+            self._handle_requests(reqs, props, soas)
+            DelayProfiler.update_total(
+                "w.requests", t0,
+                len(reqs) + len(props) + sum(len(s.gkey) for s in soas),
+                cpu_t0=c0)
+        # async overlapped acceptor wave (columnar, fusion off — the
+        # host-XLA operating point): submit the accept wave AND the
+        # commit wave back-to-back, then run the host halves in split-
+        # handler order, so the commit wave's device time overlaps the
+        # accept half's WAL fsync + reply build.  Safe to hoist commits
+        # past replies: the commit kernel writes dec/exec state only,
+        # the reply kernel reads vote/coordinator state only (they
+        # commute), commits in this batch are from prior waves, and
+        # both waves' pres touch only commutative mirror maxes.  The
+        # C-engine path keeps the split handlers (its per-stage calls
+        # are sub-ms).
+        overlap_wave = bool(accepts) and bool(commits) \
+            and self._col_self is not None
+        if overlap_wave:
+            t0 = time.monotonic()
+            c0 = self._ct()
+            self._handle_accepts_commits_overlapped(accepts, commits)
+            DelayProfiler.update_total(
+                "w.acc_com", t0, len(accepts) + len(commits),
+                cpu_t0=c0)
+        elif accepts:
+            t0 = time.monotonic()
+            c0 = self._ct()
+            self._handle_accepts(accepts)
+            DelayProfiler.update_total("w.accepts", t0, len(accepts),
+                                       cpu_t0=c0)
+        if replies:
+            t0 = time.monotonic()
+            c0 = self._ct()
+            self._handle_accept_replies(replies)
+            DelayProfiler.update_total("w.replies", t0, len(replies),
+                                       cpu_t0=c0)
+        if commits and not overlap_wave:
+            t0 = time.monotonic()
+            c0 = self._ct()
+            self._handle_commits(commits)
+            DelayProfiler.update_total("w.commits", t0, len(commits),
+                                       cpu_t0=c0)
 
     def register_handler(self, ptype: type, fn) -> None:
         """Register an upper-layer handler for a packet class (called on
@@ -2089,6 +2090,8 @@ class PaxosNode:
                 "redriven": self.n_redriven,
                 "redrive_capped": self.n_redrive_capped,
                 "wave_dups": self.n_wave_dups,
+                "hot_batches": self.n_hot_batches,
+                "one_wave_batches": self.n_one_wave_batches,
                 "parked": self.n_parked,
                 "proposed": self.n_proposed,
                 "window_full": self.n_window_full,
@@ -3204,55 +3207,32 @@ class PaxosNode:
                 payloads=[pls[k] for k in np.flatnonzero(m)]))
 
     def _acc_com_pre(self, accepts: List, commits: List):
-        """Shared lane gather + host pre halves for the two acceptor-
-        wave handlers (fused single-dispatch and async-overlapped), so
-        the coalesce keys and hoist-safety invariants live in ONE
-        place.  Returns (a_gkeys, apre, c_gkeys, cpre)."""
-        a_gkeys = _cat(accepts, lambda o: np.asarray(o.gkey, np.uint64))
-        a_slots = _cat(accepts, lambda o: np.asarray(o.slot, np.int32))
-        a_bals = _cat(accepts, lambda o: np.asarray(o.bal, np.int32))
-        a_reqs = _cat(accepts, lambda o: _merge_req(o.req_lo, o.req_hi))
-        a_send = _cat(accepts, lambda o: np.full(len(o.gkey), o.sender,
-                                                 np.int32))
-        apre = self._acc_pre(self._rows_for_keys(a_gkeys), a_slots,
-                             a_bals, a_reqs, a_send)
-        c_gkeys = _cat(commits, lambda o: np.asarray(o.gkey, np.uint64))
-        c_slots = _cat(commits, lambda o: np.asarray(o.slot, np.int32))
-        c_bals = _cat(commits, lambda o: np.asarray(o.bal, np.int32))
-        c_reqs = _cat(commits, lambda o: _merge_req(o.req_lo, o.req_hi))
-        cpre = self._commit_pre(self._rows_for_keys(c_gkeys), c_slots,
-                                c_bals, c_reqs, self._now())
+        """Shared lane gather + host pre halves for the acceptor-wave
+        handlers (the node wave's acceptor sections and the async-
+        overlapped pair), so the coalesce keys and hoist-safety
+        invariants live in ONE place.  Returns (a_gkeys, apre, c_gkeys,
+        cpre); a role with no frame gives (None, None)."""
+        a_gkeys = apre = c_gkeys = cpre = None
+        if accepts:
+            a_gkeys = _cat(accepts,
+                           lambda o: np.asarray(o.gkey, np.uint64))
+            apre = self._acc_pre(
+                self._rows_for_keys(a_gkeys),
+                _cat(accepts, lambda o: np.asarray(o.slot, np.int32)),
+                _cat(accepts, lambda o: np.asarray(o.bal, np.int32)),
+                _cat(accepts, lambda o: _merge_req(o.req_lo, o.req_hi)),
+                _cat(accepts, lambda o: np.full(len(o.gkey), o.sender,
+                                                np.int32)))
+        if commits:
+            c_gkeys = _cat(commits,
+                           lambda o: np.asarray(o.gkey, np.uint64))
+            cpre = self._commit_pre(
+                self._rows_for_keys(c_gkeys),
+                _cat(commits, lambda o: np.asarray(o.slot, np.int32)),
+                _cat(commits, lambda o: np.asarray(o.bal, np.int32)),
+                _cat(commits, lambda o: _merge_req(o.req_lo, o.req_hi)),
+                self._now())
         return a_gkeys, apre, c_gkeys, cpre
-
-    def _handle_accepts_commits(self, accepts: List,
-                                commits: List) -> None:
-        """Fused acceptor wave: the accepts and commits of one worker
-        batch go to the engine in ONE device dispatch
-        (``backend.accept_commit`` → ``kernels.accept_commit_p``),
-        with the host halves unchanged and in the split handlers'
-        order — accept post (payload store + WAL durability barrier +
-        replies) runs before commit post (install + execute)."""
-        a_gkeys, apre, c_gkeys, cpre = self._acc_com_pre(accepts,
-                                                         commits)
-        if apre is not None and cpre is not None:
-            idxs, rows, slots, bals, req_ids, senders, now = apre
-            sel, rows_s, slots_s, reqs_s = cpre
-            ares, cres = self.backend.accept_commit(
-                rows, slots, bals, req_ids, rows_s, slots_s, reqs_s)
-            self._acc_post(accepts, a_gkeys, idxs, rows, slots, bals,
-                           req_ids, senders, now, ares)
-            self._commit_post(c_gkeys, sel, rows_s, slots_s, reqs_s,
-                              cres)
-        elif apre is not None:
-            idxs, rows, slots, bals, req_ids, senders, now = apre
-            res = self.backend.accept(rows, slots, bals, req_ids)
-            self._acc_post(accepts, a_gkeys, idxs, rows, slots, bals,
-                           req_ids, senders, now, res)
-        elif cpre is not None:
-            sel, rows_s, slots_s, reqs_s = cpre
-            res = self.backend.commit(rows_s, slots_s, reqs_s)
-            self._commit_post(c_gkeys, sel, rows_s, slots_s, reqs_s,
-                              res)
 
     def _handle_accepts_commits_overlapped(self, accepts: List,
                                            commits: List) -> None:
@@ -3285,44 +3265,129 @@ class PaxosNode:
             self._commit_post(c_gkeys, sel, rows_s, slots_s, reqs_s,
                               cwave.collect())
 
-    def _handle_requests_replies(self, reqs: List, props: List,
-                                 soas: Tuple, replies: List) -> None:
-        """Fused coordinator wave: new proposals + accept replies of
-        one worker batch in ONE device dispatch
-        (``backend.propose_self_reply`` → ``kernels.request_reply_p``),
-        host halves unchanged and in split-handler order (request post
-        — with its fused-self WAL barrier — before reply post's
-        decision fan-out)."""
-        rpre = self._req_pre(reqs, props, soas)
-        r_gkeys = _cat(replies, lambda o: np.asarray(o.gkey, np.uint64))
-        r_slots = _cat(replies, lambda o: np.asarray(o.slot, np.int32))
-        r_bals = _cat(replies, lambda o: np.asarray(o.bal, np.int32))
-        r_acked = _cat(replies, lambda o: np.asarray(o.acked, np.uint8))
-        r_send = _cat(replies, lambda o: np.full(len(o.gkey), o.sender,
-                                                 np.int32))
-        ppre = self._rep_pre(self._rows_for_keys(r_gkeys), r_slots,
-                             r_bals, r_send, r_acked)
-        if rpre is not None and ppre is not None:
-            rows, req_ids, flag_parts, pay_parts, now = rpre
-            sel, rr, rs, rb, sidx_s, acked_s = ppre
-            (pres, sa, sn, sp, sc), (rres, c_app, c_st) = \
-                self.backend.propose_self_reply(
-                    rows, req_ids, self._self_midx(rows),
-                    rr, rs, rb, sidx_s, acked_s)
-            self._req_post(rows, req_ids, flag_parts, pay_parts, now,
-                           pres, sa, sn, sp, sc)
-            self._rep_post(r_gkeys, sel, rr, rs, rb, rres, c_app, c_st)
-        elif rpre is not None:
-            rows, req_ids, flag_parts, pay_parts, now = rpre
-            res, sa, sn, sp, sc = self.backend.propose_self(
-                rows, req_ids, self._self_midx(rows))
-            self._req_post(rows, req_ids, flag_parts, pay_parts, now,
-                           res, sa, sn, sp, sc)
-        elif ppre is not None:
-            sel, rr, rs, rb, sidx_s, acked_s = ppre
-            res, c_app, c_st = self.backend.accept_reply_commit_self(
-                rr, rs, rb, sidx_s, acked_s)
-            self._rep_post(r_gkeys, sel, rr, rs, rb, res, c_app, c_st)
+    def _handle_node_wave(self, reqs: List, props: List, soas: Tuple,
+                          replies: List, accepts: List,
+                          commits: List) -> None:
+        """Whole-wave fusion: every hot frame of one worker batch in
+        ONE engine wave (``backend.wave_submit`` ->
+        ``kernels.node_wave_p``).  The four pre halves, one launch and
+        one copy back, then the four post halves in the order of the
+        split handlers' own: request post (with its fused-self WAL
+        barrier) before reply post's decision fan-out, accept post
+        (payload store + WAL durability barrier + replies) before
+        commit post (install + execute).  A role the batch does not
+        hold rides as padding, so every batch launches the same
+        program.
+
+        On the DEVICE the stages run propose, reply (+ own commit),
+        accept, commit.  Replies ahead of accepts is safe: reply-side
+        state (votes/cbal) and accept-side state (bal/acc) are
+        disjoint, and in steady state a node receives accepts for
+        groups it does NOT coordinate and replies for groups it does.
+        Coordinator HANDOFF is the exception worth spelling out: for a
+        beat after an election a node can see BOTH accepts and replies
+        for the SAME group in one batch, the dying coordinator's
+        in-flight accepts beside replies to the accepts we re-drove at
+        our new ballot.  Still safe: (a) the reply kernel counts votes
+        only at bal == cbal, and stale-regime replies carry the OLD
+        ballot, so they are ignored regardless of order; (b) the accept
+        kernel's only write shared with the reply path is the
+        promised-ballot max, which is monotone: the old coordinator's
+        accept before or after our reply wave yields the same max and
+        the same ack/nack for every lane; (c) the self-accept inside
+        the request stage writes our OWN regime's window entries, and
+        a foreign coordinator of the same row is a second regime whose
+        lower ballot loses the max either way.  Commits behind replies
+        commute too: the commit kernel writes dec/exec state only, the
+        reply kernel reads vote/coordinator state only, and commits in
+        this batch are from prior waves.
+
+        On the HOST ``_acc_pre`` / ``_commit_pre`` now run BEFORE
+        ``_req_post`` / ``_rep_post`` (the pair handlers ran them
+        after).  That is exact because the two pres write only ``_la``
+        stamps and the monotone ``_bal`` mirror (``np.maximum.at``),
+        and the two posts write ``_bal`` the same way: the maxes
+        commute, so every mirror ends where it ended.  What a post may
+        READ earlier than before is a commit's higher ballot in
+        ``_bal``: ``_req_post`` then stamps the in-flight entry with a
+        ballot that is not ours, and the re-drive (its only reader)
+        skips an entry whose ballot is not ours exactly as it skips one
+        whose ballot no longer matches the mirror; a rejected lane
+        starts no election against a coordinator the batch already
+        names; the churn counter may count that ballot change at the
+        commit instead of at the nack.  All three are what the same
+        frames give when the commit arrives a batch earlier.
+
+        What a post EXECUTES can remake a row the batch's accepts or
+        commits were resolved to: ``_rep_post`` runs the app, and an
+        app may delete, create or page out groups.  The split order
+        looked those rows up after the posts and found nothing; here
+        the lanes already ran on the device, on the row as it was, and
+        what remade the row followed them there (a delete leaves it
+        deleted, a create rewrites every word of it), so only their
+        host halves are at stake: ``_rows_freed`` collects such rows
+        while the coordinator's posts run and their lanes are taken
+        out of ``_acc_post`` / ``_commit_post``, which is the dropped
+        frame the split order made of them.  Nothing else in a post
+        launches on the engine but a checkpoint's ``gc`` (a monotone
+        ``gc_slot`` no stage reads); a flush of parked proposals or a
+        re-offered accept is a wave of its own after the batch
+        (:meth:`_process`), as before."""
+        qpre = ppre = None
+        if reqs or props or soas:
+            qpre = self._req_pre(reqs, props, soas)
+        if replies:
+            r_gkeys = _cat(replies,
+                           lambda o: np.asarray(o.gkey, np.uint64))
+            ppre = self._rep_pre(
+                self._rows_for_keys(r_gkeys),
+                _cat(replies, lambda o: np.asarray(o.slot, np.int32)),
+                _cat(replies, lambda o: np.asarray(o.bal, np.int32)),
+                _cat(replies, lambda o: np.full(len(o.gkey), o.sender,
+                                                np.int32)),
+                _cat(replies, lambda o: np.asarray(o.acked, np.uint8)))
+        a_gkeys, apre, c_gkeys, cpre = self._acc_com_pre(accepts, commits)
+        if qpre is ppre is apre is cpre is None:
+            return
+        req = rep = acc = com = None
+        if qpre is not None:
+            rows, req_ids, flag_parts, pay_parts, q_now = qpre
+            req = (rows, req_ids, self._self_midx(rows))
+        if ppre is not None:
+            r_sel, rep = ppre[0], ppre[1:]
+        if apre is not None:
+            idxs, a_rows, a_slots, a_bals, a_reqs, senders, a_now = apre
+            acc = (a_rows, a_slots, a_bals, a_reqs)
+        if cpre is not None:
+            c_sel, com = cpre[0], cpre[1:]
+        qres, pres, ares, cres = self.backend.wave_submit(
+            req, rep, acc, com).collect()
+        freed = self._rows_freed = set()
+        try:
+            if qres is not None:
+                self._req_post(rows, req_ids, flag_parts, pay_parts,
+                               q_now, *qres)
+            if pres is not None:
+                self._rep_post(r_gkeys, r_sel, *rep[:3], *pres)
+        finally:
+            self._rows_freed = None
+
+        def still(rows_, cols, res):
+            """The lanes (columns and results) whose row the
+            coordinator's posts left alone."""
+            if not freed:
+                return cols, res
+            keep = ~np.isin(rows_, list(freed))
+            return ([c[keep] for c in cols],
+                    type(res)(*(np.asarray(x)[keep] for x in res)))
+
+        if ares is not None:
+            cols, ares = still(a_rows, (idxs, a_rows, a_slots, a_bals,
+                                        a_reqs, senders), ares)
+            self._acc_post(accepts, a_gkeys, *cols, a_now, ares)
+        if cres is not None:
+            cols, cres = still(com[0], (c_sel, *com), cres)
+            self._commit_post(c_gkeys, *cols, cres)
 
     # -- accept replies (coordinator side) ------------------------------
 

@@ -46,8 +46,9 @@ class PC(ConfigKey):
     # (falls back to single-device with a warning when the host has
     # fewer).  Replaces the PR-3 COLUMNAR_MESH knob (see MIGRATING).
     ENGINE_MESH = "auto"
-    # whole-wave fusion (accepts+commits / requests+replies in one
-    # engine dispatch): "auto" = only when the engine device is an
+    # whole-wave fusion (a worker batch's requests, replies, accepts
+    # and commits in ONE engine dispatch): "auto" = only when the engine
+    # device is an
     # accelerator (every dispatch there is a host<->device round trip;
     # on host XLA the shared-bucket padding outweighs the saved
     # dispatch); "on"/"off" force it either way

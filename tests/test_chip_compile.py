@@ -24,13 +24,13 @@ SERVING_TEMP, STORM_TEMP = 32 << 20, 1 << 30
 # 739 B per group at W=16 (PR 18's slab accounting, from shapes)
 SLAB_BYTES = 739 * CAPACITY
 
-# the eight packed serving kernels ColumnarBackend._warm_kernels warms,
+# the nine packed serving kernels ColumnarBackend._warm_kernels warms,
 # with the row count of each packed [k, bucket] input
 SERVING = {
     "propose_p": (4,), "accept_p": (6,), "accept_reply_p": (6,),
     "commit_p": (5,), "propose_accept_self_p": (5,),
     "accept_reply_commit_self_p": (6,), "accept_commit_p": (6, 5),
-    "request_reply_p": (5, 6),
+    "request_reply_p": (5, 6), "node_wave_p": (22,),
 }
 
 
@@ -221,7 +221,7 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
          **{f"no_plane_relayout.serving.{n}": _no_relayout(n, staged=1)
             for n in SERVING},
          **{f"no_plane_relayout.serving.{n}.bucket64": _no_relayout(n, 64)
-            for n in ("request_reply_p", "accept_commit_p")},
+            for n in ("request_reply_p", "accept_commit_p", "node_wave_p")},
          "no_plane_relayout.storm.decide_storm_step": _no_relayout("storm"),
          "mesh.accept_p": _mesh("accept_p"),
          "mesh.accept_commit_p": _mesh("accept_commit_p"),
@@ -231,3 +231,42 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_v5e(case, topo, compiled):
     CASES[case](topo, compiled)
+
+
+def test_boot_loads_node_wave_at_every_bucket():
+    """``_warm_kernels`` leaves ``node_wave_p`` traced at all four
+    buckets of the ladder and hot, so that no batch, whatever roles and
+    however many lanes it holds, traces it again: a program first used
+    inside a window compiles there (``tests/test_failover_five.py`` holds
+    the election programs to the same)."""
+    import numpy as np
+
+    from gigapaxos_tpu.paxos.backend import ColumnarBackend, _ladder
+    from gigapaxos_tpu.utils.engineledger import EngineLedger
+
+    def ledger():
+        return EngineLedger.kernels().get(
+            "node_wave_p", {"compiles": 0, "retraces": 0})
+
+    before = ledger()["compiles"]
+    G = 1064  # a state shape of this test's own
+    bk = ColumnarBackend(G, WINDOW, mesh="off")
+    booted = ledger()
+    assert booted["compiles"] - before == len(list(_ladder())) == 4
+    assert booted["hot"]
+    rows = np.arange(G, dtype=np.int32)
+    bk.create(rows, np.full(G, 3, np.int32), np.zeros(G, np.int32),
+              np.zeros(G, np.int32), np.ones(G, bool))
+    rng = np.random.default_rng(35)
+    for n in (3, 40, 300, 2000, 5000):  # every bucket, and a chunked wave
+        r = rng.integers(0, G, n).astype(np.int32)
+        ids = rng.integers(1, 1 << 40, n).astype(np.uint64)
+        z = np.zeros(n, np.int32)
+        secs = [(r, ids, z), (r, z, z, z + 1, np.ones(n, bool)),
+                (r, z, z, ids), (r, z, ids)]
+        for mask in ((1, 1, 1, 1), (1, 0, 0, 0), (0, 1, 1, 0), (0, 0, 0, 1)):
+            bk.wave_submit(*[s if on else None
+                             for s, on in zip(secs, mask)]).collect()
+    after = ledger()
+    assert after["compiles"] == booted["compiles"], after
+    assert after["retraces"] == 0

@@ -176,7 +176,7 @@ def test_engine_endpoints_schema(tmp_path):
         assert set(ks["costs"]) == {
             _kname(node, n) for n in
             ("propose_p", "accept_p", "accept_reply_p", "commit_p",
-             "accept_commit_p", "request_reply_p")}
+             "accept_commit_p", "request_reply_p", "node_wave_p")}
         for row in ks["costs"].values():
             assert {"flops", "bytes_accessed"} == set(row)
 

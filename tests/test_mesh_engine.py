@@ -133,6 +133,20 @@ def test_mesh_propose_self_parity():
 
 
 @pytest.mark.smoke
+@pytest.mark.parametrize("case", ["all", "rep+com", "handoff", "chunked"])
+def test_mesh_node_wave_parity(case):
+    """The whole worker batch in one shard_map program
+    (``mesh.node_wave_p``: four sections, each masked to the lanes its
+    shard owns, one psum) against the unsharded pair calls it replaces:
+    results and every row's state bit-identical, the chunked wave too."""
+    from tests.test_fused_wave import check_wave_submit
+    Config.set(PC.ENGINE_MESH, MESH)
+    try:
+        check_wave_submit(case, mesh=None)
+    finally:
+        Config.unset(PC.ENGINE_MESH)
+
+
 def test_engine_mesh_knob_resolution():
     """Knob authority (resolve_engine_mesh): an explicit N beyond this
     host's devices degrades to single-device (a big-mesh capture must

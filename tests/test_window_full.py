@@ -74,9 +74,22 @@ def _counters(nodes, key: str) -> int:
                for nd in nodes)
 
 
-@pytest.mark.parametrize("backend", ["columnar", "scalar"])
+def _cluster(tmp_path, backend):
+    """``columnar-fused``: PC.FUSE_WAVES on, one engine wave a worker
+    batch as on the chip; a flush of parked lanes and a re-offered accept
+    stay waves of their own behind it."""
+    if backend == "columnar-fused":
+        from gigapaxos_tpu.paxos.paxosconfig import PC
+        from gigapaxos_tpu.utils.config import Config
+        Config.set(PC.FUSE_WAVES, "on")
+        backend = "columnar"
+    return make_cluster(tmp_path, backend=backend)
+
+
+@pytest.mark.parametrize("backend",
+                         ["columnar", "scalar", "columnar-fused"])
 def test_a_full_window_parks_and_answers_every_request(tmp_path, backend):
-    nodes, addr_map = make_cluster(tmp_path, backend=backend)
+    nodes, addr_map = _cluster(tmp_path, backend)
     try:
         for nd in nodes:
             nd.create_group("hot", (0, 1, 2))
@@ -105,6 +118,11 @@ def test_a_full_window_parks_and_answers_every_request(tmp_path, backend):
         assert _counters(nodes, "parked") > parked0
         assert _counters(nodes, "proposed") >= N + _counters(
             nodes, "window_full")
+        if backend == "columnar-fused":
+            # a batch is one launch but where it flushed parked lanes or
+            # offered an accept again: those are launches of their own
+            hot = _counters(nodes, "hot_batches")
+            assert 0 < _counters(nodes, "one_wave_batches") < hot
 
         def grew(tag, field):  # wall_s, calls, items of a total
             return DelayProfiler.totals()[tag][field] - totals0.get(
@@ -138,10 +156,11 @@ def test_a_full_window_parks_and_answers_every_request(tmp_path, backend):
         shutdown(nodes)
 
 
-@pytest.mark.parametrize("backend", ["columnar", "scalar"])
+@pytest.mark.parametrize("backend",
+                         ["columnar", "scalar", "columnar-fused"])
 def test_a_retransmit_of_a_parked_request_does_not_run_it_twice(tmp_path,
                                                                  backend):
-    nodes, addr_map = make_cluster(tmp_path, backend=backend)
+    nodes, addr_map = _cluster(tmp_path, backend)
     try:
         for nd in nodes:
             nd.create_group("hot", (0, 1, 2))

@@ -318,6 +318,136 @@ def test_worker_loop_per_group_order_exactly_once(tmp_path):
         emu.stop()
 
 
+# -- one engine wave a worker batch (PC.FUSE_WAVES on) -----------------------
+
+
+def _fused_load(tmp_path, fuse):
+    """120 requests over 8 groups on three columnar nodes: each node's
+    counters and each replica's per-group journal."""
+    Config.set(PC.FUSE_WAVES, fuse)
+    emu = PaxosEmulation(str(tmp_path / fuse), n_nodes=3, n_groups=8,
+                         backend="columnar", app_cls=_RecordingApp)
+    try:
+        assert [nd._fuse_waves for nd in emu.nodes.values()] \
+            == [fuse == "on"] * 3
+        n = 120
+        stats = emu.run_load_fast(n, concurrency=24, timeout=tscale(40))
+        assert stats["ok"] == n, stats
+        apps = [emu.nodes[i].app for i in range(3)]
+        deadline = time.time() + tscale(25)
+        while time.time() < deadline and not all(
+                sum(map(len, a.seq.values())) == n for a in apps):
+            time.sleep(0.05)
+        ctr = [nd.metrics(include_profiler=False)["counters"]
+               for nd in emu.nodes.values()]
+        return ctr, [{g: list(v) for g, v in a.seq.items()} for a in apps]
+    finally:
+        emu.stop()
+
+
+def test_every_hot_batch_is_one_wave_and_ends_where_the_split_run_ends(
+        tmp_path):
+    """Whole-wave fusion: every worker batch that holds a hot frame,
+    whatever roles it holds, is ONE engine launch (``one_wave_batches`` ==
+    ``hot_batches``: this load parks nothing and offers no accept twice),
+    and the three replicas end with the per-group journals the same load
+    leaves under the split handlers."""
+    fused, seq_on = _fused_load(tmp_path, "on")
+    split, seq_off = _fused_load(tmp_path, "off")
+    for c in fused:
+        assert c["hot_batches"] > 0
+        assert c["one_wave_batches"] == c["hot_batches"], c
+    for c in split:
+        assert c["hot_batches"] > 0
+    assert seq_on[0] == seq_on[1] == seq_on[2]
+    assert seq_off[0] == seq_off[1] == seq_off[2]
+    # the same requests on each group, each once (a request id's low
+    # word is its place in the load; their order within a group is the
+    # arrival order of a concurrent load)
+    def places(seq):
+        return {g: sorted(r & 0xFFFFFFFF for r in v) for g, v in seq.items()}
+
+    assert places(seq_on[0]) == places(seq_off[0])
+    assert all(len(set(v)) == len(v) for v in seq_on[0].values())
+
+
+class _DroppingApp(_RecordingApp):
+    """Deletes its own group when it executes ``drop``: what an app may
+    do from inside ``execute`` (the engine lock is re-entrant)."""
+
+    node = None
+
+    def execute(self, name, req_id, payload, is_stop=False) -> bytes:
+        super().execute(name, req_id, payload, is_stop)
+        if payload == b"drop":
+            self.node.delete_group(name)
+        return b"ok"
+
+
+def test_a_group_deleted_inside_the_batch_drops_its_accept_lanes(tmp_path):
+    """One wave a batch resolves an accept's row BEFORE the coordinator's
+    posts run; a request that executes in ``_rep_post`` and deletes the
+    group frees that row under the accept.  The lane ran on the device
+    on the row as it was (the delete followed it there); its host half
+    must leave the freed row's mirrors alone and answer nothing, as the
+    split order's later row lookup did.  The group ends deleted on all
+    three replicas."""
+    from gigapaxos_tpu.ops.types import NO_BALLOT, pack_ballot
+    from gigapaxos_tpu.paxos import packets as pkt
+    from tests.test_e2e import make_cluster, shutdown
+    import numpy as np
+
+    Config.set(PC.FUSE_WAVES, "on")
+    nodes, _addr = make_cluster(tmp_path, app_cls=_DroppingApp)
+    try:
+        for nd in nodes:
+            nd.app.node = nd
+            assert nd.create_group("g", (0, 1, 2))
+        gkey = pkt.group_key("g")
+        c = nodes[gkey % 3]
+        peers = [i for i in range(3) if i != c.id]
+        row = c.table.by_name("g").row
+        rid = 77 << 32
+        keys = np.asarray([gkey], np.uint64)
+
+        def i32(x):
+            return np.asarray([x], np.int32)
+
+        with c._engine_lock:  # the worker waits; these two are its batches
+            c._process([pkt.Request(4242, gkey, rid, 0, b"drop")])
+            assert rid in c._proposed and c.table.by_name("g") is not None
+            sent = c.transport.metrics()["tx_bytes"]
+            hot0 = c.n_hot_batches
+            # a peer's ack decides slot 0; beside it another regime's
+            # accept for slot 1 of the same group
+            c._process([
+                pkt.AcceptReplyBatch(peers[0], keys, i32(0),
+                                     i32(pack_ballot(0, c.id)),
+                                     np.asarray([1], np.uint8)),
+                pkt.AcceptBatch(peers[1], keys, i32(1),
+                                i32(pack_ballot(1, peers[1])), i32(9),
+                                i32(0), payloads=[b"\x00late"])])
+            assert c.n_hot_batches == hot0 + 1
+            assert c.n_one_wave_batches >= 1
+            assert c.table.by_name("g") is None
+            assert c.app.seq["g"] == [rid]
+            # the freed row is as a free row is: the accept's post did
+            # not stamp it, store its payload or keep it in flight
+            assert c._bal[row] == NO_BALLOT and c._acc_hi[row] == -1
+            assert row not in c._dec and not c._proposed
+            assert c._payload_get(9) is None
+        deadline = time.time() + tscale(15)
+        while time.time() < deadline and any(
+                nd.table.by_name("g") is not None for nd in nodes):
+            time.sleep(0.05)
+        for nd in nodes:
+            assert nd.table.by_name("g") is None, nd.id
+            assert nd.app.seq["g"] == [rid]
+        assert sent <= c.transport.metrics()["tx_bytes"]
+    finally:
+        shutdown(nodes)
+
+
 # -- settings that chose another loop or engine ------------------------------
 
 
