@@ -405,6 +405,11 @@ def project_decls() -> Decls:
                 "mass-install profiler span (metrics only)",
             "PaxosLogger.log_raw_inline::monotonic":
                 "WAL-append latency profiler span (metrics only)",
+            "PaxosNode._frontier_ask::monotonic":
+                "start of the rec.catchup profiler span (metrics only; "
+                "the exchange's timers read _now())",
+            "PaxosNode._frontier_end::monotonic":
+                "length of the rec.catchup profiler span (metrics only)",
             "_Rate.*":
                 "DelayProfiler's internal rate window — the "
                 "measurement plane's own clock",

@@ -201,6 +201,25 @@ class AcceptorBackend(abc.ABC):
         time a leader dies.  Nothing to load on the engines that
         compile nothing."""
 
+    def warm_recovery(self) -> None:
+        """Load whatever a recovery (the install of every row, the
+        checkpoints' cursors, the WAL's roll-forward) and a catch-up
+        launch at sizes no traffic reaches.  Nothing to load on the
+        engines that compile nothing."""
+
+    def programs(self, *kernels: str) -> str:
+        """The device programs behind ``kernels`` as a device trace
+        names them, joined by ``+`` (a span's ``programs``); empty on
+        the engines that launch none."""
+        return ""
+
+    def release(self) -> None:
+        """Give back what the engine holds outside the host's heap,
+        now: a crash-stopped node of a process that lives on
+        (``PaxosEmulation.kill``) must not keep its slab beside its
+        successor's.  The backend is unusable afterwards.  Nothing to
+        give back on the engines that live on the heap."""
+
     def row_ownership(self) -> Optional[dict]:
         """Active-row counts per mesh device; None when not
         applicable."""
@@ -793,6 +812,41 @@ class ColumnarBackend(AcceptorBackend):
                         st, self._dev(np.zeros((5, b), np.int32)))
             self.state = st
 
+    def warm_recovery(self) -> None:
+        """Load ``create_groups``, ``set_cursor``, ``accept_p`` and
+        ``commit_p`` at every bucket of the ladder: a recovery installs
+        every row in ``_BUCKET_CAP`` chunks, sets as many cursors as it
+        has checkpoints and replays as many lanes as its WAL holds, and
+        a catch-up installs whatever a peer's answer brings, so the last
+        chunk of each is any step of the ladder, and none of them is a
+        size the served path launches (a worker batch is one
+        ``node_wave_p``).  All-invalid lanes, a state no-op, under the
+        ledger's warming bracket like :meth:`warm_elections`.  A node
+        calls it at the head of its recovery; in a process that has
+        loaded them already (another node of an in-process emulation,
+        or a driver before its window) every call is a cache hit."""
+        k = self._k
+        with EngineLedger.warming():
+            st = self.state
+            for b in _ladder():
+                z, no = np.zeros(b, np.int32), np.zeros(b, bool)
+                with self._disp():
+                    st, _ = k.create_groups(
+                        st, self._dev(z), self._dev(z), self._dev(z),
+                        self._dev(z), self._dev(no), self._dev(no))
+                    st, _ = k.set_cursor(st, self._dev(z), self._dev(z),
+                                         self._dev(z), self._dev(no))
+                    st, _ = k.accept_p(
+                        st, self._dev(np.zeros((6, b), np.int32)))
+                    st, _ = k.commit_p(
+                        st, self._dev(np.zeros((5, b), np.int32)))
+            self.state = st
+
+    def programs(self, *kernels: str) -> str:
+        return "+".join(
+            "jit_" + getattr(getattr(self._k, name), "__name__", name)
+            for name in kernels)
+
     @property
     def window(self) -> int:
         return self._window
@@ -1346,6 +1400,12 @@ class ColumnarBackend(AcceptorBackend):
             self.state, _ = scatter_rows(
                 self.state, self._dev(np.asarray([row], np.int32)),
                 row_state, self._dev(np.asarray([True])))
+
+    def release(self) -> None:
+        import jax
+        st, self.state = self.state, None
+        for leaf in jax.tree_util.tree_leaves(st):
+            leaf.delete()
 
     # -- flight deck: slab accounting + kernel costs -----------------------
 

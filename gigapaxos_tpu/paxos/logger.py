@@ -96,6 +96,10 @@ _WAL_MAGIC = b"GPWAL2\r\n"
 _CRC = struct.Struct("<I")
 # checkpoint state-blob envelope (same CRC discipline as WAL records)
 _CKPT_MAGIC = b"gpck2\x00"
+# the same envelope with the group's dedupe ids before the state (u32
+# length, ids): written only where a checkpoint has ids to carry, so a
+# checkpoint without any is byte for byte what it was
+_CKPT_MAGIC_IDS = b"gpck3\x00"
 
 
 class WalImpairedError(RuntimeError):
@@ -131,6 +135,10 @@ class CheckpointRec:
     members: Tuple[int, ...]
     slot: int
     state: bytes
+    # the group's newest executed request ids with their answers
+    # (``packets.pack_dedupe``): at-most-once has to survive a restart
+    # and a checkpoint transfer, so the key rides the checkpoint
+    dedupe: bytes = b""
 
 
 def corrupt_wal_record(path: str, index: int,
@@ -817,52 +825,67 @@ class PaxosLogger:
 
     # -- checkpoints -------------------------------------------------------
 
-    def _wrap_state(self, state: bytes) -> bytes:
+    def _wrap_state(self, state: bytes, dedupe: bytes = b"") -> bytes:
         """Envelope an app-state blob with a CRC32 (WAL_CRC gates it —
         the checkpoint write path has the same silent-corruption
-        exposure as WAL records)."""
+        exposure as WAL records).  Dedupe ids, where the checkpoint
+        has any, go before the state under the envelope's second magic
+        (CRC'd whatever WAL_CRC says: the length has to be trusted)."""
+        if dedupe:
+            body = _CRC.pack(len(dedupe)) + dedupe + state
+            return _CKPT_MAGIC_IDS + _CRC.pack(zlib.crc32(body)) + body
         if not self.wal_crc:
             return state
         return _CKPT_MAGIC + _CRC.pack(zlib.crc32(state)) + state
 
-    def _unwrap_state(self, state: bytes) -> Optional[bytes]:
-        """Undo :meth:`_wrap_state`.  Un-enveloped blobs (pre-CRC rows)
-        pass through.  Returns None when the checksum fails — callers
-        treat the checkpoint as ABSENT, so recovery falls back to
-        WAL-only replay (and peer checkpoint transfer) instead of
-        loading garbage state."""
-        if state is None or state[:len(_CKPT_MAGIC)] != _CKPT_MAGIC:
-            return state
-        body = state[len(_CKPT_MAGIC) + _CRC.size:]
-        want = _CRC.unpack_from(state, len(_CKPT_MAGIC))[0]
+    def _unwrap_state(self, state: bytes
+                      ) -> Optional[Tuple[bytes, bytes]]:
+        """Undo :meth:`_wrap_state`: (state, dedupe ids).  Un-enveloped
+        blobs (pre-CRC rows) pass through.  Returns None when the
+        checksum fails — callers treat the checkpoint as ABSENT, so
+        recovery falls back to WAL-only replay (and peer checkpoint
+        transfer) instead of loading garbage state."""
+        if state is None:
+            return None
+        magic = bytes(state[:len(_CKPT_MAGIC)])
+        if magic not in (_CKPT_MAGIC, _CKPT_MAGIC_IDS):
+            return state, b""
+        body = state[len(magic) + _CRC.size:]
+        want = _CRC.unpack_from(state, len(magic))[0]
         if zlib.crc32(body) != want:
             with self._health_lock:
                 self._ckpt_bad += 1
             return None
-        return body
+        if magic == _CKPT_MAGIC:
+            return body, b""
+        n = _CRC.unpack_from(body, 0)[0]
+        return body[_CRC.size + n:], body[_CRC.size:_CRC.size + n]
 
     def checkpoint(self, rec: CheckpointRec) -> None:
         self.checkpoint_many([rec])
 
     def checkpoint_many(self, recs: List[CheckpointRec]) -> None:
+        blobs = [self._wrap_state(r.state, r.dedupe) for r in recs]
         with self._db_lock:
             self._db.executemany(
                 "INSERT OR REPLACE INTO checkpoints VALUES (?,?,?,?,?,?)",
                 [(_signed(r.gkey), r.name, r.version,
-                  json.dumps(list(r.members)), r.slot,
-                  self._wrap_state(r.state))
-                 for r in recs])
+                  json.dumps(list(r.members)), r.slot, blob)
+                 for r, blob in zip(recs, blobs)])
             self._db.commit()
+        # a call a transaction, the bytes of state and dedupe ids
+        # written (beside `wal.bytes`)
+        DelayProfiler.add_total("ckpt.bytes", 0.0, sum(map(len, blobs)))
 
     def _ckpt_from_row(self, row) -> Optional[CheckpointRec]:
-        state = self._unwrap_state(row[5])
-        if state is None:
+        got = self._unwrap_state(row[5])
+        if got is None:
             log.error("checkpoint for gkey %d failed its CRC — "
                       "dropped (WAL replay / peer transfer recovers "
                       "the group)", _unsigned(row[0]))
             return None
         return CheckpointRec(_unsigned(row[0]), row[1], row[2],
-                             tuple(json.loads(row[3])), row[4], state)
+                             tuple(json.loads(row[3])), row[4], *got)
 
     def get_checkpoint(self, gkey: int) -> Optional[CheckpointRec]:
         with self._db_lock:

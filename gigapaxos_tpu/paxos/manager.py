@@ -483,6 +483,16 @@ class PaxosNode:
         # two-generation lifetime as _executed_recent.
         self._resp_cache: Dict[int, Tuple[int, bytes]] = {}
         self._resp_cache_old: Dict[int, Tuple[int, bytes]] = {}
+        # row -> request ids executed on it, oldest first: what the two
+        # tables above know of ONE group, so that it can ride the
+        # group's checkpoint (_dedupe_of) and outlive a restart or a
+        # checkpoint transfer.  Bounded per group: a checkpoint cut
+        # keeps the last `window` ids, so a list holds the ids executed
+        # since the group's previous checkpoint plus a window; and the
+        # same two generations, so a group's ids age out with the
+        # tables they mirror.
+        self._row_ids: Dict[int, List[int]] = {}
+        self._row_ids_old: Dict[int, List[int]] = {}
         self._elections: Dict[int, _Election] = {}
         self._mass_el: Optional[_MassElections] = None  # lazy (SoA)
 
@@ -628,6 +638,25 @@ class PaxosNode:
         self._last_bounce_gc = 0.0  # _bounced sweep pacing
         self._last_exec_gc = 0.0    # dedupe-generation swap pacing
         self._last_sync: Dict[int, float] = {}  # per-row sync pacing
+        # ---- the frontier exchange (batched catch-up; _frontier_*) ----
+        # set by _recover: once the worker runs, every recovered row's
+        # cursor goes to the peers in FrontierRequest frames
+        self._fx_boot = False
+        # frame id -> [dst, rows, last heard, tries, hop]: frames sent
+        # and not yet answered to the end (hop 1: asked of a second node)
+        self._fx_pending: Dict[int, list] = {}
+        # row -> (the cursor a peer answered with, that peer), for rows a
+        # reply left behind; asked again by the tick
+        self._fx_rows: Dict[int, Tuple[int, int]] = {}
+        self._fx_round = 0       # times the tick asked again
+        self._fx_asked = 0.0     # when the last frame left
+        # the exchange in progress: its gp.rec.catchup span (None with
+        # spans off), when its first frame left, and what it did
+        self._fx_span: Optional[dict] = None
+        self._fx_t0 = 0.0
+        self._fx_stats = dict.fromkeys(
+            ("rows_behind", "rows_level", "frames", "by_decisions",
+             "by_checkpoint", "bytes"), 0)
         self._boot_ts = time.time()  # re-stamped by start()
         # tick pacing + the self-stall guard state
         self._last_tick = 0.0
@@ -1000,6 +1029,9 @@ class PaxosNode:
         self._row_gkey[row] = 0
         self._dec.pop(row, None)
         self._catchup_barrier.pop(row, None)
+        self._row_ids.pop(row, None)
+        self._row_ids_old.pop(row, None)
+        self._fx_rows.pop(row, None)
         if self._rows_freed is not None:
             self._rows_freed.add(row)
 
@@ -1537,6 +1569,8 @@ class PaxosNode:
                 fn()
             except Exception:
                 log.exception("tick hook %r failed", fn)
+        if self._fx_boot or self._fx_t0:
+            self._frontier_tick(now)
         # self-stall guard: if WE went dark longer than the failure
         # timeout (mass create holding the engine lock, GC, a
         # compile storm), the missing pings are OUR silence, not
@@ -1723,6 +1757,8 @@ class PaxosNode:
             self._executed_recent = {}
             self._resp_cache_old = self._resp_cache
             self._resp_cache = {}
+            self._row_ids_old = self._row_ids
+            self._row_ids = {}
             self._client_wait = {
                 r: w for r, w in self._client_wait.items()
                 if w[1] > now - 120}
@@ -1925,6 +1961,10 @@ class PaxosNode:
                     self.app.checkpoint(meta.name)))
         for o in by_type.pop(pkt.CheckpointReply, []):
             self._handle_checkpoint_reply(o)
+        for o in by_type.pop(pkt.FrontierRequest, []):
+            self._handle_frontier_request(o)
+        for o in by_type.pop(pkt.FrontierReply, []):
+            self._handle_frontier_reply(o)
 
         # failover cold path
         prepares = by_type.pop(pkt.Prepare, [])
@@ -3636,6 +3676,7 @@ class PaxosNode:
         P, PO = self._payloads, self._payloads_old
         ER, RC = self._executed_recent, self._resp_cache
         CW, PR = self._client_wait, self._proposed
+        ids: Optional[List[int]] = None  # the row's list, on first use
         n_exec = n_bytes = 0
         while cur in dec:
             req_id = dec[cur]
@@ -3711,6 +3752,9 @@ class PaxosNode:
                 # lost write.
                 ER[req_id] = 1
                 RC[req_id] = (status, resp)
+                if ids is None:
+                    ids = self._row_ids.setdefault(row, [])
+                ids.append(req_id)
             waiter = CW.pop(req_id, None)
             if waiter is not None:
                 self._route(waiter[0], pkt.Response(
@@ -3745,10 +3789,51 @@ class PaxosNode:
         state = self.app.checkpoint(meta.name)
         self.logger.checkpoint(CheckpointRec(
             meta.gkey, meta.name, meta.version, meta.members, upto_slot,
-            state))
+            state, self._dedupe_of(row)))
         self._ckpt[row] = upto_slot
+        # the ids this checkpoint carried stay on disk with it; the
+        # list starts again from the last window's worth
+        ids = self._row_ids_old.pop(row, []) + self._row_ids.get(row, [])
+        self._row_ids[row] = ids[-self.backend.window:]
         self.backend.gc(np.asarray([row], np.int32),
                         np.asarray([upto_slot], np.int32))
+
+    def _dedupe_of(self, row: int) -> bytes:
+        """What the dedupe tables know of ``row``'s group, as it rides
+        the group's checkpoint (local, or sent to a peer): the newest
+        ``checkpoint_interval + window`` request ids executed on it that
+        the tables still hold, each with its answer.  Bounded per group:
+        the ids executed since the previous checkpoint are those whose
+        decisions a reader of this checkpoint may never see, and a window
+        more covers what was in flight when the previous one was cut; a
+        node that jumps further than that is as well off as a table that
+        forgot (the generations age out in one to two minutes)."""
+        ids = self._row_ids_old.get(row, []) + self._row_ids.get(row, [])
+        if not ids:
+            return b""
+        ids = ids[-(self.checkpoint_interval + self.backend.window):]
+        return pkt.pack_dedupe([(rid, *self._cached_resp(rid))
+                                for rid in ids if self._was_executed(rid)])
+
+    def _load_dedupe(self, row: int, blob: bytes) -> int:
+        """A checkpoint's dedupe ids into the tables (``_req_pre`` then
+        answers a parked or retransmitted copy and proposes nothing) and
+        onto the row's own list, so that its next checkpoint passes them
+        on; returns how many."""
+        items = pkt.unpack_dedupe(blob)
+        if not items:
+            return 0
+        ER, RC = self._executed_recent, self._resp_cache
+        ids = self._row_ids.setdefault(row, [])
+        known = set(ids)
+        for rid, st, resp in items:
+            if rid not in ER:
+                ER[rid] = 1
+                RC[rid] = (st, resp)
+            if rid not in known:
+                ids.append(rid)
+        DelayProfiler.add_total("rec.dedupe_ids_loaded", 0.0, len(items))
+        return len(items)
 
     # -- sync (gap fill; ref: SyncDecisionsPacket) ----------------------
 
@@ -3803,85 +3888,391 @@ class PaxosNode:
                 self._inq.put(b"".join(parts[2]))
         # stale partial transfers (lost chunks) age out in _tick
 
+    def _sync_answer(self, meta, from_slot: int, to_slot: int):
+        """What this node holds beyond ``from_slot`` of a group, for a
+        peer that is behind (one row of a ``SyncRequest`` or of a
+        ``FrontierRequest``): ``("dec", [(slot, req, flags, payload)])``
+        for the decisions in ``[from_slot, to_slot)`` whose payload we
+        actually hold (never a fabricated empty payload for one we
+        don't: replica divergence), else ``("ckpt", slot, state,
+        dedupe)`` where they are executed and gone (ref: StatePacket
+        path), else None."""
+        row = meta.row
+        have = []
+        dec = self._dec.get(row)
+        if dec:
+            for s in range(from_slot, to_slot):
+                req = dec.get(s)
+                got = None if req is None else self._payload_get(req)
+                if got is not None:
+                    have.append((s, req, got[0], got[1]))
+        if have:
+            return ("dec", have)
+        if int(self._cur[row]) > from_slot:
+            return ("ckpt", int(self._cur[row]) - 1,
+                    self.app.checkpoint(meta.name), self._dedupe_of(row))
+        return None
+
     def _handle_sync_request(self, o) -> None:
         meta = self._lookup(o.gkey)
         if meta is None:
             return
-        row = meta.row
-        # serve only decisions whose payload we actually hold — never
-        # fabricate an empty payload for one we don't (replica divergence)
-        have = []
-        for s in range(o.from_slot, o.to_slot):
-            req = self._dec.get(row, {}).get(s)
-            if req is not None and self._payload_get(req) is not None:
-                have.append((s, req))
-        if not have:
-            # decisions already executed & GC'd: catch the laggard up with
-            # a whole-state checkpoint instead (ref: StatePacket path)
-            if int(self._cur[row]) > o.from_slot:
-                state = self.app.checkpoint(meta.name)
-                self._route(o.sender, pkt.CheckpointReply(
-                    self.id, meta.gkey, int(self._cur[row]) - 1,
-                    state))
+        ans = self._sync_answer(meta, o.from_slot, o.to_slot)
+        if ans is None:
             return
-        pls = []
-        for s, req in have:
-            fl, pl = self._payload_get(req)
-            pls.append(bytes([fl]) + pl)
+        if ans[0] == "ckpt":
+            self._route(o.sender, pkt.CheckpointReply(
+                self.id, meta.gkey, *ans[1:]))
+            return
+        have = ans[1]
         self._route(o.sender, pkt.SyncReply(
             self.id, meta.gkey,
-            np.asarray([s for s, _ in have], np.int32),
-            *_split_reqs([req for _, req in have]), payloads=pls))
+            np.asarray([h[0] for h in have], np.int32),
+            *_split_reqs([h[1] for h in have]),
+            payloads=[bytes([h[2]]) + h[3] for h in have]))
 
     def _handle_sync_reply(self, o) -> None:
-        meta = self.table.by_key(o.gkey)
-        if meta is None:
-            return
-        pls = o.payloads or [b""] * len(o.slots)
-        ded = {}
-        for j in range(len(o.slots)):
-            req = _join_req(int(o.req_lo[j]), int(o.req_hi[j]))
-            blob = pls[j]
-            if not blob or (blob[0] & FLAG_MISSING):
-                continue  # sender had no payload: don't install the slot
-            self._store_payload(req, blob[0], bytes(blob[1:]))
-            ded[(meta.row, int(o.slots[j]))] = req
-        if not ded:
-            return
-        keys = list(ded.keys())
-        n = len(keys)
-        self._commit_install(
-            np.asarray([k[0] for k in keys], np.int32),
-            np.asarray([k[1] for k in keys], np.int32),
-            np.zeros(n, np.int32),
-            np.asarray([ded[k] for k in keys], np.uint64),
-            np.full(n, o.gkey, np.uint64))
-        self._execute_row(meta.row)
+        n = len(o.slots)
+        self._install_decisions(
+            np.full(n, o.gkey, np.uint64), o.slots, o.req_lo, o.req_hi,
+            o.payloads or [b""] * n)
+
+    def _install_decisions(self, gkeys, slots, req_lo, req_hi,
+                           blobs) -> Dict[int, int]:
+        """Decisions a peer sent with their payloads (a ``SyncReply``'s,
+        or a ``FrontierReply``'s decision section, a lane each): the
+        payloads kept, the lanes through ``_commit_install`` in one call.
+        A lane whose sender had no payload is not installed.  Returns,
+        for each row named, the slot after its highest lane."""
+        rows = self.table.rows_for_keys(np.ascontiguousarray(gkeys))
+        reqs = _merge_req(req_lo, req_hi)
+        upto: Dict[int, int] = {}
+        keep = []
+        for j, row in enumerate(rows.tolist()):
+            blob = blobs[j]
+            if row < 0 or not blob or (blob[0] & FLAG_MISSING):
+                continue
+            self._store_payload(int(reqs[j]), blob[0], bytes(blob[1:]))
+            upto[row] = max(upto.get(row, 0), int(slots[j]) + 1)
+            keep.append(j)
+        if keep:
+            k = np.asarray(keep, np.int64)
+            self._commit_install(
+                rows[k].astype(np.int32), np.asarray(slots, np.int32)[k],
+                np.zeros(len(k), np.int32), reqs[k],
+                np.asarray(gkeys, np.uint64)[k])
+            # (a payload that came with this frame may have unblocked a
+            # decision the install itself did not touch)
+            self._execute_rows(np.asarray(sorted(upto), np.int64))
+        return upto
 
     def _handle_checkpoint_reply(self, o) -> None:
         """Whole-state catch-up: a peer's checkpoint replaces our (lagging)
         app state and advances the frontier (ref: StatePacket install)."""
-        meta = self.table.by_key(o.gkey)
-        if meta is None:
+        self._install_checkpoints(
+            np.asarray([o.gkey], np.uint64), [o.slot], [o.state],
+            [o.dedupe])
+
+    def _install_checkpoints(self, gkeys, slots, states, dedupes) -> int:
+        """Peers' checkpoints for a batch of groups (one
+        ``CheckpointReply``, or a ``FrontierReply``'s checkpoint section):
+        each that is ahead of us replaces the group's app state, brings
+        its dedupe ids, and moves the row's frontier; ONE engine call
+        and ONE checkpoint transaction for the batch.  Returns how many
+        were installed (a stale one, at or behind our cursor, is not)."""
+        rows_all = self.table.rows_for_keys(np.asarray(gkeys, np.uint64))
+        rows, curs, recs = [], [], []
+        for i, row in enumerate(rows_all.tolist()):
+            slot = int(slots[i])
+            if row < 0 or slot < int(self._cur[row]):
+                continue  # unknown here, or we are already past it
+            meta = self.table.by_row(row)
+            self.app.restore(meta.name, states[i])
+            self._load_dedupe(row, dedupes[i])
+            newcur = slot + 1
+            self._cur[row] = newcur
+            d = self._dec.get(row, {})
+            for s in [s for s in d if s < newcur]:
+                self._payload_pop(d.pop(s))
+            self._ckpt[row] = slot
+            rows.append(row)
+            curs.append(newcur)
+            recs.append(CheckpointRec(
+                meta.gkey, meta.name, meta.version, meta.members, slot,
+                states[i], dedupes[i]))
+        if not rows:
+            return 0
+        cs = np.asarray(curs, np.int32)
+        self.backend.set_cursor(np.asarray(rows, np.int32), cs, cs)
+        self.logger.checkpoint_many(recs)
+        self._execute_rows(np.asarray(rows, np.int64))
+        return len(rows)
+
+    # -- the frontier exchange (batched catch-up) ------------------------
+    #
+    # The gap path above asks for ONE row when something later shows
+    # that the row is behind.  A node that comes back from a crash is
+    # behind on every group that decided anything while it was away, and
+    # on an idle group nothing later ever comes: so once its
+    # roll-forward has ended it sends ALL its rows' cursors and promises
+    # to the peers, FRONTIER_ROWS a frame, and each peer answers, in
+    # batches, only for the rows on which it is ahead.  Once per boot,
+    # and again only for rows a reply left behind; no knob.
+
+    def _ring_next(self, rows: np.ndarray, after) -> np.ndarray:
+        """For each row the next member in ring order after ``after``
+        (a node id, or one a row) that is not this node, one that is not
+        suspected if there is one; -1 for a row with nobody to ask."""
+        mm = self._member_mat[rows]
+        can = (mm >= 0) & (mm != self.id)
+        ring = np.where(mm > np.reshape(after, (-1, 1)), mm, mm + (1 << 20))
+        if self._suspects:
+            ring = ring + (np.isin(mm, np.asarray(
+                sorted(self._suspects), np.int32)).astype(np.int64) << 24)
+        ring = np.where(can, ring, 1 << 30)
+        nxt = mm[np.arange(len(rows)), ring.argmin(axis=1)]
+        return np.where(can.any(axis=1), nxt, -1)
+
+    def _frontier_dst(self, rows: np.ndarray) -> np.ndarray:
+        """Whom to ask about each row: its believed coordinator, or
+        (where that is us, unknown or suspected) any live member — the
+        next after us in ring order, which is who runs for a dead
+        coordinator's groups — as ``_sync_if_gap`` picks."""
+        bal = self._bal[rows]
+        coord = np.where(bal >= 0, bal & NODE_MASK, -1)
+        ok = (coord >= 0) & (coord != self.id)
+        if self._suspects:
+            ok &= ~np.isin(coord, np.asarray(sorted(self._suspects),
+                                             np.int32))
+        if ok.all():
+            return coord
+        return np.where(ok, coord, self._ring_next(rows, self.id))
+
+    def _frontier_ask(self, rows: np.ndarray, dst: np.ndarray,
+                      hop: int = 0, tries: int = 0) -> int:
+        """Send ``rows``' cursors and promises to ``dst`` (a node a row)
+        in ``FrontierRequest`` frames, a frame per destination and
+        ``FRONTIER_ROWS`` rows; returns the frames sent."""
+        now = self._now()
+        sent = 0
+        for d in np.unique(dst[dst >= 0]).tolist():
+            part = rows[dst == d]
+            for k, fr in enumerate(pkt.FrontierRequest.frames(
+                    self.id, self._xfer_seq, self._row_gkey[part],
+                    self._cur[part], self._bal[part])):
+                at = k * pkt.FRONTIER_ROWS
+                self._fx_pending[fr.xid] = [
+                    d, part[at:at + pkt.FRONTIER_ROWS], now, tries, hop]
+                self._fx_stats["bytes"] += 16 * len(fr.gkey)
+                self._route(d, fr)
+                sent += 1
+        if sent:
+            if not self._fx_t0:
+                self._fx_t0 = time.monotonic()
+                self._fx_span = RequestInstrumenter.span_begin(
+                    "rec.catchup", node=self.id)
+            self._fx_asked = now
+            self._fx_stats["frames"] += sent
+            DelayProfiler.add_total("rec.catchup_frames", 0.0, sent)
+        return sent
+
+    @property
+    def catching_up(self) -> bool:
+        """True from a recovery's end until its frontier exchange has
+        ended: every frame answered, and no row a reply named left
+        behind (or the tick has given those up)."""
+        return self._fx_boot or bool(self._fx_t0)
+
+    def _frontier_tick(self, now: float) -> None:
+        """The exchange's timers: the boot round once the worker runs; a
+        frame unanswered for 10 s goes to the member after the silent one
+        (five tries; an answer waits its turn behind whatever the peers
+        had queued for this node, so the wait is a long one); rows a reply left behind are asked again a second
+        after the last frame left, of the member after the one that
+        answered (five rounds); and when nothing is open the exchange
+        ends."""
+        if self._fx_boot:
+            rows = np.flatnonzero(self._bal >= 0)
+            if len(rows):
+                self._frontier_ask(rows, self._frontier_dst(rows))
+            # after the frames have left: `catching_up` never reads
+            # false between the recovery's end and the exchange's
+            self._fx_boot = False
+        if not self._fx_t0:
             return
-        row = meta.row
-        cur = int(self._cur[row])
-        if o.slot < cur:
-            return  # stale: we are already past it
-        self.app.restore(meta.name, o.state)
-        newcur = o.slot + 1
-        self._cur[row] = newcur
-        d = self._dec.get(row, {})
-        for s in [s for s in d if s < newcur]:
-            self._payload_pop(d.pop(s))
-        self.backend.set_cursor(np.asarray([row], np.int32),
-                                np.asarray([newcur], np.int32),
-                                np.asarray([newcur], np.int32))
-        self._ckpt[row] = o.slot
-        self.logger.checkpoint(CheckpointRec(
-            meta.gkey, meta.name, meta.version, meta.members, o.slot,
-            o.state))
-        self._execute_row(row)
+        for xid, (d, rows, heard, tries, hop) in list(
+                self._fx_pending.items()):
+            if now - heard < 10.0:
+                continue
+            del self._fx_pending[xid]
+            rows = rows[self._bal[rows] >= 0]  # not deleted since
+            if tries < 5 and len(rows):
+                self._frontier_ask(rows, self._ring_next(rows, d), hop,
+                                   tries + 1)
+        if self._fx_pending:
+            return
+        if self._fx_rows and self._fx_round < 5:
+            if now - self._fx_asked >= 1.0:
+                self._fx_round += 1
+                rows = np.asarray(sorted(self._fx_rows), np.int64)
+                last = np.asarray([self._fx_rows[r][1]
+                                   for r in rows.tolist()], np.int32)
+                if not self._frontier_ask(
+                        rows, self._ring_next(rows, last), 1):
+                    self._fx_rows.clear()
+            return
+        self._frontier_end()
+
+    def _frontier_end(self) -> None:
+        """Close the exchange: its seconds into the ``rec.catchup``
+        total, its span (if one is open) into the ring with what it
+        did."""
+        st = self._fx_stats
+        DelayProfiler.add_total(
+            "rec.catchup", time.monotonic() - self._fx_t0,
+            st["rows_level"])
+        RequestInstrumenter.span_end(self._fx_span, n=st["rows_level"],
+                                     left_behind=len(self._fx_rows), **st)
+        log.info("node %d catch-up ended: %s, %d left behind", self.id,
+                 st, len(self._fx_rows))
+        self._fx_span, self._fx_t0, self._fx_round = None, 0.0, 0
+        self._fx_rows.clear()
+        self._fx_stats = dict.fromkeys(st, 0)
+
+    def _handle_frontier_request(self, o) -> None:
+        """A peer's frontier: answer, in batches, only for the rows on
+        which we are ahead — a higher promise, the decisions we still
+        hold with their payloads, else the group's checkpoint with its
+        dedupe ids.  An answer over 4 MB goes in parts; the last part
+        (always sent, even empty) tells the asker the frame is done."""
+        n = len(o.gkey)
+        with span("rec.serve", node=self.id, n=n, rows=n) as sp:
+            rows = self.table.rows_for_keys(np.ascontiguousarray(o.gkey))
+            live = rows >= 0
+            r = np.where(live, rows, 0)
+            hi_bal = live & (self._bal[r] > o.bal)
+            ahead = np.flatnonzero(live & (self._cur[r] > o.cursor))
+            b_gkey, b_bal = o.gkey[hi_bal], self._bal[r[hi_bal]]
+            W = self.backend.window
+            dec: List[tuple] = []   # (gkey, slot, req, blob)
+            ck: List[tuple] = []    # (gkey, slot, state, dedupe)
+            size = 0
+
+            def part(last: int) -> None:
+                nonlocal b_gkey, b_bal, size
+                self._route(o.sender, pkt.FrontierReply(
+                    self.id, o.xid, last, b_gkey, b_bal,
+                    np.asarray([d[0] for d in dec], np.uint64),
+                    np.asarray([d[1] for d in dec], np.int32),
+                    *_split_reqs([d[2] for d in dec]),
+                    [d[3] for d in dec],
+                    np.asarray([c[0] for c in ck], np.uint64),
+                    np.asarray([c[1] for c in ck], np.int32),
+                    [c[2] for c in ck], [c[3] for c in ck]))
+                b_gkey, b_bal = b_gkey[:0], b_bal[:0]
+                dec.clear()
+                ck.clear()
+                size = 0
+
+            for i in ahead.tolist():
+                meta = self.table.by_row(int(rows[i]))
+                cur = int(o.cursor[i])
+                ans = self._sync_answer(meta, cur, cur + W)
+                if ans is None:
+                    continue
+                if ans[0] == "dec":
+                    for s, req, fl, pl in ans[1]:
+                        dec.append((meta.gkey, s, req, bytes([fl]) + pl))
+                        size += 21 + len(pl)
+                else:
+                    ck.append((meta.gkey, *ans[1:]))
+                    size += 20 + len(ans[2]) + len(ans[3])
+                if size > 4 * 1024 * 1024:
+                    part(0)
+            sp.note(ahead=len(ahead), ballots=int(hi_bal.sum()))
+            part(1)
+
+    def _handle_frontier_reply(self, o) -> None:
+        """A part of a peer's answer: adopt the higher promises, install
+        the decisions through ``_commit_install`` and the checkpoints
+        through ``_install_checkpoints`` (one ``set_cursor`` call a
+        frame, not a call a row), and keep count of the rows it named
+        and of those it brought level."""
+        pend = self._fx_pending.get(o.xid)
+        if pend is not None:
+            pend[2] = self._now()
+        st = self._fx_stats
+        st["bytes"] += (12 * len(o.b_gkey) + 20 * len(o.d_gkey)
+                        + sum(map(len, o.d_payloads))
+                        + 12 * len(o.c_gkey) + sum(map(len, o.c_states))
+                        + sum(map(len, o.c_dedupes)))
+        if len(o.b_gkey):
+            self._adopt_promises(o.b_gkey, o.b_bal)
+        # row -> the cursor the peer is at
+        named = self._install_decisions(o.d_gkey, o.d_slot, o.d_req_lo,
+                                        o.d_req_hi, o.d_payloads) \
+            if len(o.d_gkey) else {}
+        st["by_decisions"] += len(named)
+        if len(o.c_gkey):
+            c_rows = self.table.rows_for_keys(
+                np.ascontiguousarray(o.c_gkey))
+            for j, row in enumerate(c_rows.tolist()):
+                if row >= 0 and int(o.c_slot[j]) >= int(self._cur[row]):
+                    named[row] = max(named.get(row, 0),
+                                     int(o.c_slot[j]) + 1)
+            st["by_checkpoint"] += self._install_checkpoints(
+                o.c_gkey, o.c_slot, o.c_states, o.c_dedupes)
+        fresh = level = 0
+        for row, target in named.items():
+            if row not in self._fx_rows:
+                fresh += 1
+            if int(self._cur[row]) >= target:
+                self._fx_rows.pop(row, None)
+                level += 1
+            else:
+                self._fx_rows[row] = (target, o.sender)
+        if fresh:
+            st["rows_behind"] += fresh
+            DelayProfiler.add_total("rec.rows_behind", 0.0, fresh)
+        if level:
+            st["rows_level"] += level
+            DelayProfiler.add_total("rec.rows_level", 0.0, level)
+        if not o.last or pend is None:
+            return
+        del self._fx_pending[o.xid]
+        d, rows, _heard, _tries, hop = pend
+        if hop == 0:
+            # a row whose coordinator turns out to be somebody else
+            # (the answer raised our promise): only what that node sends
+            # from its answer on is sure to reach us, so it is asked too
+            rows = rows[self._bal[rows] >= 0]
+            coord = self._bal[rows] & NODE_MASK
+            other = (coord != d) & (coord != self.id) & ~np.isin(
+                coord, np.asarray(sorted(self._suspects), np.int32))
+            if other.any():
+                self._frontier_ask(rows[other], coord[other], 1)
+        if not self._fx_pending:
+            self._frontier_tick(self._now())
+
+    def _adopt_promises(self, gkeys, bals) -> None:
+        """Promise the higher ballots a peer reported for these groups
+        (a promise is always safe to make): the engine's acceptor state
+        and the host's mirror, so that a node back from a crash knows
+        who coordinates the groups it once led."""
+        rows = self.table.rows_for_keys(np.ascontiguousarray(gkeys))
+        bals = np.asarray(bals, np.int32)
+        up = np.flatnonzero((rows >= 0)
+                            & (bals > self._bal[np.where(rows >= 0,
+                                                         rows, 0)]))
+        if not len(up):
+            return
+        rows_u = rows[up].astype(np.int32)
+        res = self.backend.prepare(rows_u, bals[up])
+        cur_bal = np.asarray(res.cur_bal, np.int32)
+        gain = cur_bal > self._bal[rows_u]
+        if gain.any():
+            self._note_ballot_change(rows_u[gain])
+            self._bal[rows_u[gain]] = cur_bal[gain]
 
     # ------------------------------------------------------------------
     # failover (ref: §3.5 coordinator failover)
@@ -4553,48 +4944,109 @@ class PaxosNode:
     # ------------------------------------------------------------------
 
     def _recover(self) -> None:
+        """Boot from the durable log: the table and the device rows of
+        every group, the newest checkpoint of each (state, cursor,
+        dedupe ids), then the WAL rolled forward (accepts re-promise,
+        decisions re-execute); what was decided while this node was away
+        comes from the peers once the worker runs (the frontier
+        exchange).  ``gp.rec.boot`` is the whole of it, a span a part
+        inside; a first boot reads an empty table and records a boot of
+        no groups."""
         # paused groups stay cold: their rows hydrate on first touch
         # (ref: lazy recovery at million-group scale, SURVEY §7.3.6)
         self._paused = set(self.logger.paused_keys())
-        groups = self.logger.all_groups()
+        with span("rec.boot", node=self.id, n=0) as boot:
+            with span("rec.groups", node=self.id, n=0) as sp:
+                groups = self.logger.all_groups()
+                sp.n = len(groups)
+                sp.note(rows=len(groups))
+            if groups:
+                boot.n = len(groups)
+                boot.note(groups=len(groups))
+                # what recovery launches is loaded first, under the
+                # ledger's warming bracket: sizes no served wave reaches
+                self.backend.warm_recovery()
+                # a sum nothing waits for: whoever watches the process
+                # (a tracer that wants the recovery's own device work)
+                # sees that one has its programs and begins
+                DelayProfiler.add_total("rec.boots_begun", 0.0,
+                                        len(groups))
+                self._recover_groups(groups)
         if not groups:
             return
+        DelayProfiler.add_total("rec.groups_recovered", 0.0,
+                                len(self.table))
+        # with peers, the rows' cursors go to them once the worker runs
+        self._fx_boot = len(self.addr_map) > 1 and len(self.table) > 0
+        log.info("node %d recovered %d groups in %.3fs", self.id,
+                 len(groups), boot.t1 - boot.t0)
+
+    def _recover_groups(self, groups) -> None:
         t0 = time.time()
         # BATCHED rebuild (one backend call, one checkpoint query): the
         # per-group form — one 1-lane device create + one sqlite SELECT
         # each — measured ~52us/group, i.e. ~50s of boot at 1M groups
         metas = []
-        for gkey, name, version, members in groups:
-            if gkey in self._paused or self.table.by_key(gkey):
-                continue
-            metas.append(self.table.create(name, members, version))
+        with span("rec.table", node=self.id, n=len(groups)):
+            for gkey, name, version, members in groups:
+                if gkey in self._paused or self.table.by_key(gkey):
+                    continue
+                metas.append(self.table.create(name, members, version))
         if metas:
-            self._install_rows(metas, self_coord=False, now=t0)
+            mem = self.backend.memory_info() or {}
+            with span("rec.install", node=self.id, n=len(metas),
+                      rows=len(metas),
+                      programs=self.backend.programs("create_groups"),
+                      bytes=int(len(metas)
+                                * mem.get("bytes_per_group", 0))):
+                self._install_rows(metas, self_coord=False, now=t0)
             # checkpoints fetched ONLY for the rows just rebuilt: a
             # whole-table read would materialize every state blob —
             # including paused groups', defeating lazy recovery — and a
             # pre-existing live group must never be rolled back to a
             # stale checkpoint from a prior incarnation
-            ck_rows, ck_slots = [], []
-            by_key = {m.gkey: m for m in metas}
-            for rec in self.logger.checkpoints_for(list(by_key)):
-                meta = by_key.get(rec.gkey)
-                if meta is None:
-                    continue
-                self.app.restore(meta.name, rec.state)
-                if rec.slot >= 0:
-                    self._cur[meta.row] = rec.slot + 1
-                    self._ckpt[meta.row] = rec.slot
-                    ck_rows.append(meta.row)
-                    ck_slots.append(rec.slot + 1)
-            if ck_rows:
-                cs = np.asarray(ck_slots, np.int32)
-                self.backend.set_cursor(
-                    np.asarray(ck_rows, np.int32), cs, cs)
+            with span("rec.checkpoints", node=self.id, n=len(metas),
+                      programs=self.backend.programs("set_cursor")) as sp:
+                ck_rows, ck_slots = [], []
+                by_key = {m.gkey: m for m in metas}
+                n_rec = n_bytes = n_ids = 0
+                for rec in self.logger.checkpoints_for(list(by_key)):
+                    meta = by_key.get(rec.gkey)
+                    if meta is None:
+                        continue
+                    n_rec += 1
+                    n_bytes += len(rec.state) + len(rec.dedupe)
+                    self.app.restore(meta.name, rec.state)
+                    if rec.dedupe:
+                        n_ids += self._load_dedupe(meta.row, rec.dedupe)
+                    if rec.slot >= 0:
+                        self._cur[meta.row] = rec.slot + 1
+                        self._ckpt[meta.row] = rec.slot
+                        ck_rows.append(meta.row)
+                        ck_slots.append(rec.slot + 1)
+                if ck_rows:
+                    cs = np.asarray(ck_slots, np.int32)
+                    self.backend.set_cursor(
+                        np.asarray(ck_rows, np.int32), cs, cs)
+                sp.note(rows=n_rec, bytes=n_bytes, restored=len(ck_rows),
+                        dedupe_ids=n_ids)
         # roll forward the WAL (accepts re-promise; decisions re-execute)
+        with span("rec.wal", node=self.id, n=0,
+                  programs=self.backend.programs("accept_p",
+                                                 "commit_p")) as sp:
+            n_acc, n_dec, n_bytes = self._roll_forward()
+            sp.n = n_acc + n_dec
+            sp.note(records=n_acc + n_dec, accepts=n_acc,
+                    decisions=n_dec, bytes=n_bytes)
+
+    def _roll_forward(self) -> Tuple[int, int, int]:
+        """The WAL into the engine and the app: (accepts, decisions,
+        bytes) read."""
         acc_rows, acc_slots, acc_bals, acc_reqs = [], [], [], []
         dec_by_row: Dict[int, Dict[int, int]] = {}
+        n_dec = n_bytes = 0
         for e in self.logger.read_wal():
+            n_bytes += 29 + len(e.payload)
             meta = self.table.by_key(e.gkey)
             if meta is None:
                 continue
@@ -4609,6 +5061,7 @@ class PaxosNode:
                 if e.bal > self._bal[meta.row]:
                     self._bal[meta.row] = e.bal
             else:
+                n_dec += 1
                 dec_by_row.setdefault(meta.row, {})[e.slot] = e.req_id
         if acc_rows:
             # coalesce to the max-ballot lane per (row, slot) before the
@@ -4640,8 +5093,7 @@ class PaxosNode:
                         self._dec.setdefault(r, {})[s] = dec_by_row[r][s]
             for r in dec_by_row:
                 self._execute_row(r)
-        log.info("node %d recovered %d groups in %.3fs", self.id,
-                 len(groups), time.time() - t0)
+        return len(acc_rows), n_dec, n_bytes
 
 
 def _np_jsonable(o):

@@ -75,6 +75,8 @@ class PacketType(IntEnum):
     PREPARE_REPLY_BATCH = 20
     FRAG = 21             # per-peer super-frame (wire aggregation)
     WIRE_HELLO = 22       # wire-format version announcement
+    FRONTIER_REQUEST = 23  # a recovered node's cursors, n groups a frame
+    FRONTIER_REPLY = 24    # what the peer holds beyond them, batched
 
 
 _HDR = struct.Struct("<BII")  # type, sender (u32, matches the transport's
@@ -641,18 +643,165 @@ class CheckpointReply:
     gkey: int
     slot: int          # checkpoint is the app state AFTER executing `slot`
     state: bytes
+    # the group's newest executed request ids with their answers
+    # (:func:`pack_dedupe`): whoever jumps to this state jumps over their
+    # decisions, and must still know a late copy of one when it sees it
+    dedupe: bytes = b""
 
     TYPE = PacketType.CHECKPOINT_REPLY
-    _S = struct.Struct("<Qi")
+    _S = struct.Struct("<QiI")
 
     def encode(self) -> bytes:
         return (_HDR.pack(self.TYPE, self.sender, 1) +
-                self._S.pack(self.gkey, self.slot) + self.state)
+                self._S.pack(self.gkey, self.slot, len(self.dedupe)) +
+                self.dedupe + self.state)
 
     @classmethod
     def decode(cls, sender, n, body) -> "CheckpointReply":
-        gkey, slot = cls._S.unpack_from(body, 0)
-        return cls(sender, gkey, slot, bytes(body[cls._S.size:]))
+        gkey, slot, nd = cls._S.unpack_from(body, 0)
+        o = cls._S.size
+        return cls(sender, gkey, slot, bytes(body[o + nd:]),
+                   bytes(body[o:o + nd]))
+
+
+_DEDUPE = struct.Struct("<QBI")  # request id, status, bytes of the answer
+
+
+def pack_dedupe(items: Sequence[Tuple[int, int, bytes]]) -> bytes:
+    """A group's executed requests as they ride its checkpoint: n, then
+    n x (request id, status, answer length), then the answers."""
+    if not items:
+        return b""
+    return (struct.pack("<I", len(items)) +
+            b"".join(_DEDUPE.pack(rid, st, len(resp))
+                     for rid, st, resp in items) +
+            b"".join(resp for _rid, _st, resp in items))
+
+
+def unpack_dedupe(blob: bytes) -> List[Tuple[int, int, bytes]]:
+    """Undo :func:`pack_dedupe`; a blob cut short yields what is whole."""
+    if len(blob) < 4:
+        return []
+    (n,) = struct.unpack_from("<I", blob, 0)
+    o = 4 + n * _DEDUPE.size
+    if o > len(blob):
+        return []
+    out = []
+    for i in range(n):
+        rid, st, ln = _DEDUPE.unpack_from(blob, 4 + i * _DEDUPE.size)
+        if o + ln > len(blob):
+            break
+        out.append((rid, st, bytes(blob[o:o + ln])))
+        o += ln
+    return out
+
+
+# rows a frontier frame carries at most: the chunk create_groups uses
+FRONTIER_ROWS = 16384
+
+
+@dataclass
+class FrontierRequest:
+    """A node's execute cursors and promised ballots for n of its groups
+    in ONE frame: what a recovered node sends once its roll-forward has
+    ended (and a new coordinator for the rows it has to catch up on), so
+    that a peer can say, for all of them at once, where it is ahead.  The
+    batched form of ``SyncRequest`` (ref: ``SyncDecisionsPacket``, one a
+    group)."""
+
+    sender: int
+    xid: int             # the asker's frame id; replies echo it
+    gkey: np.ndarray     # u64[n]
+    cursor: np.ndarray   # i32[n] first slot not yet executed
+    bal: np.ndarray      # i32[n] packed promised ballot
+
+    TYPE = PacketType.FRONTIER_REQUEST
+    _S = struct.Struct("<Q")
+
+    def encode(self) -> bytes:
+        return (_HDR.pack(self.TYPE, self.sender, len(self.gkey)) +
+                self._S.pack(self.xid) +
+                np.ascontiguousarray(self.gkey, np.uint64).tobytes() +
+                np.ascontiguousarray(self.cursor, np.int32).tobytes() +
+                np.ascontiguousarray(self.bal, np.int32).tobytes())
+
+    @classmethod
+    def decode(cls, sender, n, body) -> "FrontierRequest":
+        (xid,) = cls._S.unpack_from(body, 0)
+        o = cls._S.size
+        gkey = np.frombuffer(body[o:o + 8 * n], np.uint64); o += 8 * n
+        cursor = np.frombuffer(body[o:o + 4 * n], np.int32); o += 4 * n
+        bal = np.frombuffer(body[o:o + 4 * n], np.int32)
+        return cls(sender, xid, gkey, cursor, bal)
+
+    @classmethod
+    def frames(cls, sender: int, xids, gkey, cursor, bal
+               ) -> List["FrontierRequest"]:
+        """The rows cut into frames of at most ``FRONTIER_ROWS``, each
+        with the next id of ``xids``."""
+        return [cls(sender, next(xids), gkey[a:a + FRONTIER_ROWS],
+                    cursor[a:a + FRONTIER_ROWS], bal[a:a + FRONTIER_ROWS])
+                for a in range(0, len(gkey), FRONTIER_ROWS)]
+
+
+@dataclass
+class FrontierReply:
+    """A peer's answer to a ``FrontierRequest``, only for the rows on
+    which it is ahead: the higher ballot it has promised (``b_*``), the
+    decisions it still holds with their payloads (``d_*``), else the
+    group's checkpoint with its dedupe ids (``c_*``).  ``last`` is 0 on
+    all but the final part of an answer cut for size."""
+
+    sender: int
+    xid: int
+    last: int
+    b_gkey: np.ndarray    # u64[nb]
+    b_bal: np.ndarray     # i32[nb]
+    d_gkey: np.ndarray    # u64[nd]
+    d_slot: np.ndarray    # i32[nd]
+    d_req_lo: np.ndarray  # i32[nd]
+    d_req_hi: np.ndarray  # i32[nd]
+    d_payloads: List[bytes]   # nd blobs: flags byte + payload
+    c_gkey: np.ndarray    # u64[nc]
+    c_slot: np.ndarray    # i32[nc] state is AFTER executing this slot
+    c_states: List[bytes]     # nc blobs
+    c_dedupes: List[bytes]    # nc blobs (pack_dedupe)
+
+    TYPE = PacketType.FRONTIER_REPLY
+    _S = struct.Struct("<QBII")
+
+    def encode(self) -> bytes:
+        nb, nd, nc = len(self.b_gkey), len(self.d_gkey), len(self.c_gkey)
+        return (_HDR.pack(self.TYPE, self.sender, nb) +
+                self._S.pack(self.xid, self.last, nd, nc) +
+                np.ascontiguousarray(self.b_gkey, np.uint64).tobytes() +
+                np.ascontiguousarray(self.b_bal, np.int32).tobytes() +
+                np.ascontiguousarray(self.d_gkey, np.uint64).tobytes() +
+                np.ascontiguousarray(self.d_slot, np.int32).tobytes() +
+                np.ascontiguousarray(self.d_req_lo, np.int32).tobytes() +
+                np.ascontiguousarray(self.d_req_hi, np.int32).tobytes() +
+                _pack_blobs(self.d_payloads) +
+                np.ascontiguousarray(self.c_gkey, np.uint64).tobytes() +
+                np.ascontiguousarray(self.c_slot, np.int32).tobytes() +
+                _pack_blobs(self.c_states) + _pack_blobs(self.c_dedupes))
+
+    @classmethod
+    def decode(cls, sender, n, body) -> "FrontierReply":
+        xid, last, nd, nc = cls._S.unpack_from(body, 0)
+        o = cls._S.size
+        b_gkey = np.frombuffer(body[o:o + 8 * n], np.uint64); o += 8 * n
+        b_bal = np.frombuffer(body[o:o + 4 * n], np.int32); o += 4 * n
+        d_gkey = np.frombuffer(body[o:o + 8 * nd], np.uint64); o += 8 * nd
+        d_slot = np.frombuffer(body[o:o + 4 * nd], np.int32); o += 4 * nd
+        d_lo = np.frombuffer(body[o:o + 4 * nd], np.int32); o += 4 * nd
+        d_hi = np.frombuffer(body[o:o + 4 * nd], np.int32); o += 4 * nd
+        d_pl, used = _unpack_blobs(body[o:], nd); o += used
+        c_gkey = np.frombuffer(body[o:o + 8 * nc], np.uint64); o += 8 * nc
+        c_slot = np.frombuffer(body[o:o + 4 * nc], np.int32); o += 4 * nc
+        c_st, used = _unpack_blobs(body[o:], nc); o += used
+        c_dd, _ = _unpack_blobs(body[o:], nc)
+        return cls(sender, xid, last, b_gkey, b_bal, d_gkey, d_slot, d_lo,
+                   d_hi, d_pl, c_gkey, c_slot, c_st, c_dd)
 
 
 @dataclass
@@ -756,6 +905,8 @@ _DECODERS = {
     PacketType.CHUNK: Chunk,
     PacketType.PREPARE_BATCH: PrepareBatch,
     PacketType.PREPARE_REPLY_BATCH: PrepareReplyBatch,
+    PacketType.FRONTIER_REQUEST: FrontierRequest,
+    PacketType.FRONTIER_REPLY: FrontierReply,
 }
 
 

@@ -135,7 +135,12 @@ class PaxosEmulation:
     def kill(self, node: int) -> None:
         """Crash-stop: pending packets and unfsynced WAL writes are
         dropped, no goodbye (ref: TESTPaxosConfig crash emulation)."""
-        self.nodes[node].stop(abort=True)
+        nd = self.nodes[node]
+        nd.stop(abort=True)
+        # a crashed process's device memory goes with it; here the
+        # process lives on, and the dead node's slab would stand beside
+        # its successor's until a collection happened to free it
+        nd.backend.release()
         self.nodes[node] = None
 
     def restart(self, node: int) -> PaxosNode:

@@ -175,10 +175,23 @@ def test_election_spans_reach_the_ring(tmp_path):
         victim = 3
         n_led = len(drv.led_by(LIVE, victim, R))
         emu.kill(victim)
-        alive = wait_taken_over(emu, victim, n_led)
-        deadline = time.monotonic() + tscale(10)
-        while time.monotonic() < deadline and not all(
-                victim in nd._suspects for nd in alive):
+        wait_taken_over(emu, victim, n_led)
+
+        def all_in_the_ring():
+            # a span reaches the ring, and its sum the profiler, when it
+            # ENDS: every survivor's suspicion (the scan runs inside it),
+            # the installs for every group the victim led, and the reply
+            # frames around them (an install nests in the reply that
+            # brought its quorum) -- not when a counter they bump moves
+            tot = DelayProfiler.totals()
+            return (sum(s["kind"] == "fo.suspect"
+                        for s in RI.spans_snapshot()) >= R - 1
+                    and tot.get("fo.install", (0, 0, 0))[2] >= n_led
+                    and tot.get("fo.reply", (0, 0, 0))[2] >= 3 * n_led
+                    and tot.get("fo.prepare", (0, 0, 0))[2]
+                    >= (R - 1) * n_led)
+        deadline = time.monotonic() + tscale(20)
+        while time.monotonic() < deadline and not all_in_the_ring():
             time.sleep(0.02)
         spans = RI.spans_snapshot()
         by = {}

@@ -194,14 +194,19 @@ def test_torn_append_recovers_whole_batch(tmp_path):
 # -- checksummed checkpoints ------------------------------------------
 
 
-def test_checkpoint_crc_fallback(tmp_path):
+@pytest.mark.parametrize("wal_crc,dedupe", [
+    (True, b""), (True, b"\x01\x00\x00\x00ids"), (False, b"ids")])
+def test_checkpoint_crc_fallback(tmp_path, wal_crc, dedupe):
     """A checkpoint blob that fails its CRC reads as ABSENT (recovery
     falls back to WAL-only replay / peer transfer), and the drop is
-    tallied for the metrics plane."""
-    lg = _mk(tmp_path, wal_crc=True)
-    rec = CheckpointRec(42, "g42", 0, (0, 1, 2), 9, b"state-blob")
+    tallied for the metrics plane.  One that carries dedupe ids is
+    checksummed whatever WAL_CRC says (the ids' length has to be
+    trusted)."""
+    lg = _mk(tmp_path, wal_crc=wal_crc)
+    rec = CheckpointRec(42, "g42", 0, (0, 1, 2), 9, b"state-blob", dedupe)
     lg.checkpoint(rec)
-    assert lg.get_checkpoint(42).state == b"state-blob"
+    got = lg.get_checkpoint(42)
+    assert (got.state, got.dedupe) == (b"state-blob", dedupe)
     # post-crash media corruption: flip one byte of the stored blob
     with lg._db_lock:
         blob = bytearray(lg._db.execute(
