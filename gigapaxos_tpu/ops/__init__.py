@@ -4,8 +4,9 @@ Reference analog: the per-instance hot loops of
 ``gigapaxos/PaxosAcceptor.java`` (handlePrepare, acceptAndUpdateBallot) and
 ``gigapaxos/PaxosCoordinator.java`` / ``PaxosCoordinatorState.java``
 (propose, handleAcceptReply majority counting) — redesigned columnar: state
-for ALL groups lives in ``[G]`` / ``[G, W]`` device arrays and each message
-type is one batched XLA kernel over a struct-of-arrays packet batch.
+for ALL groups lives in linear ``[G * 16]`` / ``[G * W]`` device planes and
+each message type is one batched XLA kernel over a struct-of-arrays packet
+batch.
 """
 
 from gigapaxos_tpu.ops.types import (

@@ -55,8 +55,10 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from gigapaxos_tpu.ops.types import (ColumnarState, EMITTED_BIT, NO_BALLOT,
-                                     NO_SLOT, PLANES, RowState, VOTE_MASK)
+from gigapaxos_tpu.ops.types import (COL, COL_DTYPE, ColumnarState,
+                                     EMITTED_BIT, GROUP_WORDS, NO_BALLOT,
+                                     NO_SLOT, PLANES, RowState, VOTE_MASK,
+                                     from_word, to_word)
 
 i32 = jnp.int32
 u32 = jnp.uint32
@@ -119,11 +121,10 @@ def lane_runs(g, valid, *lanes, distinct_slots=False):
     later).  A composition hands ``runs`` to every body it runs; a body's
     own ``valid`` may then be any subset of the one given here.  With an
     order a body reads each per-group combine (rank in run, count, max)
-    off a dense scan over ``[B]`` and writes a ``[G]`` field once per run,
+    off a dense scan over ``[B]`` and writes a group's field once per run,
     from one lane; every scatter it issues has unique indices (a dropped
     lane gets an out-of-bounds row of its own, ``G + lane``); and it
-    gathers by the ORDER's valid lanes, so stages that read one field of
-    one state share the gather."""
+    gathers by the ORDER's valid lanes."""
     B = g.shape[0]
     last = jnp.iinfo(i32).max
     key, order, *lanes = jax.lax.sort(
@@ -150,7 +151,7 @@ def _run_count(runs: LaneRuns, mask, reverse=False):
 
 def _run_last(runs: LaneRuns, mask):
     """The last lane of ``mask`` in each run: the ONE lane that writes the
-    run's combine into a ``[G]`` field."""
+    run's combine into the group table."""
     return mask & (_run_count(runs, mask, reverse=True) == 1)
 
 
@@ -201,29 +202,70 @@ def _window_words(rows, W):
 _LANES = 128  # words in a row of the v5e's (8, 128) tile
 
 
+def _own_rows(plane, gi, width):
+    """Each lane's group's ``width`` consecutive words of a linear plane,
+    read as part of ONE row gather a lane.  The row is a whole tile row
+    (128 words, ``per`` groups) wherever groups pack into such rows: seen
+    as ``[G * width / 128, 128]`` the plane keeps its linear order (a
+    bitcast on the v5e, where ``[G, width]`` with width < 128 is another
+    physical order and costs a copy of the plane a stage).  Otherwise the
+    row is the group's own ``width`` words.  Returns ``(rows, c, mine)``:
+    the rows ``[B, per * width]``, each word's column within its group
+    ``[1, per * width]``, and the mask of the lane's own group's words
+    (the row's other groups' are to be masked out)."""
+    G = plane.shape[0] // width
+    per = _LANES // width if (_LANES % width == 0
+                              and G % (_LANES // width) == 0) else 1
+    rows = plane.reshape(-1, per * width)[gi // per]
+    c = jnp.arange(per * width, dtype=i32)[None, :]
+    return rows, c % width, c // width == (gi % per)[:, None]
+
+
 def _frontier_advance(dec_slot, gi, cursor, W):
     """How far each lane's group frontier moves: the columns of its
-    window are read in place and counted with a compare and a row min.
-    Column c is d = (c - cursor) mod W ahead of the cursor and in order
-    iff it holds slot cursor + d; the advance is the least d over the
-    columns that are not, W if all are.
-
-    The W words of a group are consecutive in the linear plane, so they
-    are read as part of ONE row gather a lane.  The row is a whole tile
-    row (128 words, ``per`` groups) wherever groups pack into such rows:
-    seen as ``[G * W / 128, 128]`` the plane keeps its linear order (a
-    bitcast on the v5e, where ``[G, W]`` with W < 128 is another
-    physical order and costs a copy of the plane a stage), and the
-    columns of the row's other groups are masked out.  Otherwise the row
-    is the group's own W words."""
-    G = dec_slot.shape[0] // W
-    per = _LANES // W if _LANES % W == 0 and G % (_LANES // W) == 0 else 1
-    rows = dec_slot.reshape(-1, per * W)[gi // per]
-    c = jnp.arange(per * W, dtype=i32)[None, :]
-    mine = c // W == (gi % per)[:, None]
-    d = (c % W - cursor[:, None]) % W
+    window are read in place (:func:`_own_rows`) and counted with a
+    compare and a row min.  Column c is d = (c - cursor) mod W ahead of
+    the cursor and in order iff it holds slot cursor + d; the advance is
+    the least d over the columns that are not, W if all are."""
+    rows, c, mine = _own_rows(dec_slot, gi, W)
+    d = (c - cursor[:, None]) % W
     ok = rows == cursor[:, None] + d
     return jnp.min(jnp.where(mine & ~ok, d, W), axis=1)
+
+
+def _group_words(grp, gi):
+    """Every scalar of each lane's group from ONE row read a stage call
+    (:func:`_own_rows` on the group table ``grp``) where each ``[G]``
+    field cost a gather of its own.  Returns ``col``: ``col(f)`` is field
+    ``f`` at the lanes' groups, ``[B]`` in the field's own dtype, taken
+    out of the lane's 128-word row with a mask and a row sum, not with a
+    second gather.  A column is traced when it is asked for: a stage
+    takes two to six of the sixteen, and tracing all of them for every
+    stage call of every program cost a process 2.4 s of its boot."""
+    rows, c, mine = _own_rows(grp, gi, GROUP_WORDS)
+
+    def col(f):
+        word = jnp.sum(jnp.where(mine & (c == COL[f]), rows, 0), axis=1)
+        return from_word(word, COL_DTYPE[f])
+    return col
+
+
+def _word(rows, f):
+    """The flat table index of field ``f`` of ``rows`` (out of bounds for
+    a row that is)."""
+    return rows * GROUP_WORDS + COL[f]
+
+
+def _set_cols(grp, rows, unique=False, **cols):
+    """The group table ``grp`` with fields ``cols`` (``[B]`` or scalar
+    values by name) of ``rows`` set: the one-word set of
+    :func:`_set_words`, the index vector built once for all the columns a
+    stage writes, ONE scatter."""
+    idx = jnp.stack([_word(rows, f) for f in cols], axis=1)
+    vals = jnp.stack([jnp.broadcast_to(to_word(v), rows.shape)
+                      for v in cols.values()], axis=1)
+    return grp.at[idx.reshape(-1)].set(
+        vals.reshape(-1), mode="drop", unique_indices=unique)
 
 
 # --------------------------------------------------------------------------
@@ -242,20 +284,24 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
                  runs: Optional[LaneRuns] = None):
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
-    act = state.active[gi]
+    col = _group_words(state.grp, gi)
+    act = col("active")
     live = valid & act  # inactive rows must not be mutated at all
 
     item_bal = jnp.where(live, bal, NO_BALLOT)
     if runs is None:
-        new_bal = state.bal.at[_si(g, live, G)].max(item_bal, mode="drop")
-        cur_bal = new_bal[gi]
+        grp = state.grp.at[_word(_si(g, live, G), "bal")].max(
+            item_bal, mode="drop")
+        # what a batch without a lane order answers with: the table
+        # read again AFTER the stage's own write
+        cur_bal = _group_words(grp, gi)("bal")
     else:
-        cur_bal = jnp.maximum(state.bal[gi], _run_max(runs, item_bal))
-        new_bal = state.bal.at[_sd(g, _run_last(runs, live), G)].set(
-            cur_bal, mode="drop", unique_indices=True)
+        cur_bal = jnp.maximum(col("bal"), _run_max(runs, item_bal))
+        grp = _set_cols(state.grp, _sd(g, _run_last(runs, live), G),
+                        unique=True, bal=cur_bal)
 
     promised_ok = live & (bal >= cur_bal)
-    cursor = state.exec_cursor[gi]
+    cursor = col("exec_cursor")
     stale = valid & act & (slot < cursor)
     in_win = (slot >= cursor) & (slot < cursor + W)
     store = promised_ok & in_win
@@ -274,7 +320,7 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
         out_window=promised_ok & ~in_win & ~stale,
         cur_bal=cur_bal,
     )
-    state = state._replace(bal=new_bal, **acc)
+    state = state._replace(grp=grp, **acc)
     return state, out
 
 
@@ -314,8 +360,10 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     w = jnp.where(valid, slot % W, 0)
     idx = gi * W + w
 
-    coord_here = state.is_coord[gi] & state.coord_active[gi]
-    is_rel = valid & coord_here & (bal == state.cbal[gi])
+    col = _group_words(state.grp, gi)
+    is_coord, cbal = col("is_coord"), col("cbal")
+    coord_here = is_coord & col("coord_active")
+    is_rel = valid & coord_here & (bal == cbal)
     # slot >= 0 guards against matching uninitialized vote columns
     # (prop_slot inits to NO_SLOT = -1)
     match = is_rel & acked & (slot >= 0) & (state.prop_slot[idx] == slot)
@@ -329,7 +377,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     def crossed(votes):
         cnt = jax.lax.population_count(
             jnp.bitwise_and(votes, VOTE_MASK)).astype(i32)
-        return match & (cnt >= _majority(state.members[gi]))
+        return match & (cnt >= _majority(col("members")))
 
     if runs is not None and runs.distinct_slots:
         # alone in its column: a lane's vote is the whole batch's
@@ -365,29 +413,31 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
 
     # Preemption: a nack carrying a ballot above ours ends our reign
     # (ref: PaxosCoordinator preemption on higher-ballot accept replies).
-    # The resign scatters are guarded by a real branch: preemption is a
+    # The resign scatter is guarded by a real branch: preemption is a
     # failover-window event, and XLA:CPU pays every scatter op as a
-    # serial per-lane loop — two [G] scatters per reply wave for an
+    # serial per-lane loop — a scatter per reply wave for an
     # almost-always-empty mask was ~8% of the storm step.
-    pre = valid & state.is_coord[gi] & ~acked & (bal > state.cbal[gi])
-    sp = _si(g, pre, G)
-    is_coord, coord_active = jax.lax.cond(
+    pre = valid & is_coord & ~acked & (bal > cbal)
+    grp = jax.lax.cond(
         pre.any(),
-        lambda ic, ca: (ic.at[sp].set(False, mode="drop"),
-                        ca.at[sp].set(False, mode="drop")),
-        lambda ic, ca: (ic, ca),
-        state.is_coord, state.coord_active)
+        lambda t: _set_cols(t, _si(g, pre, G), is_coord=False,
+                            coord_active=False),
+        lambda t: t, state.grp)
+    # the branch's result stays the table's one value: without the barrier
+    # the v5e's compiler moves the next stage's view of the table into
+    # the branches and then copies the 64 MB plane twice a reply
+    # (tests/test_chip_compile.py holds it to none)
+    grp = jax.lax.optimization_barrier(grp)
 
     out = AcceptReplyOut(
         newly_decided=newly,
         preempted=pre,
         dec_slot=slot,
-        dec_bal=state.cbal[gi],
+        dec_bal=cbal,
         req_lo=state.prop_rlo[idx],
         req_hi=state.prop_rhi[idx],
     )
-    state = state._replace(prop_votes=votes, is_coord=is_coord,
-                           coord_active=coord_active)
+    state = state._replace(prop_votes=votes, grp=grp)
     return state, out
 
 
@@ -417,8 +467,10 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
     B = g.shape[0]
     gi = _gi(g, valid if runs is None else runs.valid)
 
-    can = valid & state.active[gi] & state.is_coord[gi] & \
-        state.coord_active[gi]
+    col = _group_words(state.grp, gi)
+    act = col("active")
+    coord_here = col("is_coord") & col("coord_active")
+    can = valid & act & coord_here
 
     if runs is None:
         iota = jnp.arange(B, dtype=i32)
@@ -426,19 +478,17 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
     else:
         rank = jnp.where(can, _run_count(runs, can) - 1, 0)
 
-    slot = state.next_slot[gi] + rank
-    in_win = slot < state.exec_cursor[gi] + W
+    slot = col("next_slot") + rank
+    in_win = slot < col("exec_cursor") + W
     granted = can & in_win
 
     # advance next_slot by per-group granted count
     if runs is None:
-        sg = _si(g, granted, G)
-        next_slot = state.next_slot.at[sg].add(jnp.where(granted, 1, 0),
-                                               mode="drop")
+        grp = state.grp.at[_word(_si(g, granted, G), "next_slot")].add(
+            jnp.where(granted, 1, 0), mode="drop")
     else:  # the granted lanes lead their run: the last holds the count
-        next_slot = state.next_slot.at[
-            _sd(g, _run_last(runs, granted), G)].set(
-                slot + 1, mode="drop", unique_indices=True)
+        grp = _set_cols(state.grp, _sd(g, _run_last(runs, granted), G),
+                        unique=True, next_slot=slot + 1)
 
     # initialize the proposal column for the assigned slot: slot, req id,
     # zero votes/emitted, a word a component plane; the slots of a group
@@ -451,13 +501,12 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
 
     out = ProposeOut(
         granted=granted,
-        rejected=valid & state.active[gi] & ~(state.is_coord[gi] &
-                                             state.coord_active[gi]),
+        rejected=valid & act & ~coord_here,
         throttled=can & ~in_win,
         slot=slot,
-        cbal=state.cbal[gi],
+        cbal=col("cbal"),
     )
-    state = state._replace(next_slot=next_slot, **prop)
+    state = state._replace(grp=grp, **prop)
     return state, out
 
 
@@ -477,8 +526,8 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
                  runs: Optional[LaneRuns] = None):
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
-    act = state.active[gi]
-    cursor = state.exec_cursor[gi]
+    col = _group_words(state.grp, gi)
+    act, cursor = col("active"), col("exec_cursor")
 
     stale = valid & act & (slot < cursor)
     in_win = (slot >= cursor) & (slot < cursor + W)
@@ -498,20 +547,19 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
     new_cur = cursor + _frontier_advance(dec["dec_slot"], gi, cursor, W)
 
     if runs is None:
-        sg = _si(g, store, G)
-        exec_cursor = state.exec_cursor.at[sg].max(new_cur, mode="drop")
+        grp = state.grp.at[_word(_si(g, store, G), "exec_cursor")].max(
+            new_cur, mode="drop")
     else:  # a group's lanes all read the one row: one frontier, >= cursor
-        exec_cursor = state.exec_cursor.at[
-            _sd(g, _run_last(runs, store), G)].set(
-                new_cur, mode="drop", unique_indices=True)
+        grp = _set_cols(state.grp, _sd(g, _run_last(runs, store), G),
+                        unique=True, exec_cursor=new_cur)
 
     out = CommitOut(
         applied=store,
         stale=stale,
         out_window=valid & act & (slot >= cursor + W),
-        new_cursor=exec_cursor[gi],
+        new_cursor=_group_words(grp, gi)("exec_cursor"),
     )
-    state = state._replace(exec_cursor=exec_cursor, **dec)
+    state = state._replace(grp=grp, **dec)
     return state, out
 
 
@@ -537,24 +585,26 @@ def prepare_batch(state: ColumnarState, g, bal, valid):
     """
     G, W = state.G, state.W
     gi = _gi(g, valid)
-    live = valid & state.active[gi]  # don't mutate inactive rows
+    col = _group_words(state.grp, gi)
+    live = valid & col("active")  # don't mutate inactive rows
 
     item_bal = jnp.where(live, bal, NO_BALLOT)
-    new_bal = state.bal.at[_si(g, live, G)].max(item_bal, mode="drop")
-    cur_bal = new_bal[gi]
+    grp = state.grp.at[_word(_si(g, live, G), "bal")].max(
+        item_bal, mode="drop")
+    cur_bal = _group_words(grp, gi)("bal")
     acked = live & (bal >= cur_bal)
 
     widx = _window_words(gi, W)  # a cold path: a word a gather index
     out = PrepareOut(
         acked=acked,
         cur_bal=cur_bal,
-        exec_cursor=state.exec_cursor[gi],
+        exec_cursor=col("exec_cursor"),
         win_slot=state.acc_slot[widx],
         win_bal=state.acc_bal[widx],
         win_req_lo=state.acc_rlo[widx],
         win_req_hi=state.acc_rhi[widx],
     )
-    return state._replace(bal=new_bal), out
+    return state._replace(grp=grp), out
 
 
 # --------------------------------------------------------------------------
@@ -573,13 +623,8 @@ def install_coordinator_batch(state: ColumnarState, g, cbal, next_slot,
     are initialized here.
     """
     G, W = state.G, state.W
-    si = _si(g, valid, G)
-    gi = _gi(g, valid)
-
-    is_coord = state.is_coord.at[si].set(True, mode="drop")
-    coord_active = state.coord_active.at[si].set(True, mode="drop")
-    cbal_arr = state.cbal.at[si].set(cbal, mode="drop")
-    ns = state.next_slot.at[si].set(next_slot, mode="drop")
+    grp = _set_cols(state.grp, _si(g, valid, G), is_coord=True,
+                    coord_active=True, cbal=cbal, next_slot=next_slot)
 
     has = valid[:, None] & (carry_slot >= 0)
     w = jnp.where(has, carry_slot % W, 0)
@@ -589,11 +634,7 @@ def install_coordinator_batch(state: ColumnarState, g, cbal, next_slot,
         prop_rhi=carry_rhi.reshape(-1),
         prop_votes=jnp.zeros((carry_slot.size,), i32)), unique=False)
 
-    state = state._replace(
-        is_coord=is_coord, coord_active=coord_active, cbal=cbal_arr,
-        next_slot=ns, **prop,
-    )
-    return state, None
+    return state._replace(grp=grp, **prop), None
 
 
 # --------------------------------------------------------------------------
@@ -613,7 +654,6 @@ def create_groups_batch(state: ColumnarState, rows, members, version,
     """
     G, W = state.G, state.W
     si = _si(rows, valid, G)
-    vT = valid
     # every word of a created row's window, fresh: one dense pass a plane
     # under the created rows' mask (a creation wave is any number of rows,
     # the whole fleet at once in the storm's set-up; a pass is the same
@@ -623,55 +663,35 @@ def create_groups_batch(state: ColumnarState, rows, members, version,
     fresh = {f: jnp.where(made, i32(v), getattr(state, f))
              for cols in PLANES.values() for f, v in cols}
 
-    state = state._replace(
-        active=state.active.at[si].set(True, mode="drop"),
-        members=state.members.at[si].set(members, mode="drop"),
-        version=state.version.at[si].set(version, mode="drop"),
-        bal=state.bal.at[si].set(init_bal, mode="drop"),
-        exec_cursor=state.exec_cursor.at[si].set(0, mode="drop"),
-        gc_slot=state.gc_slot.at[si].set(NO_SLOT, mode="drop"),
-        is_coord=state.is_coord.at[si].set(vT & self_coord, mode="drop"),
-        coord_active=state.coord_active.at[si].set(vT & self_coord,
-                                                   mode="drop"),
-        cbal=state.cbal.at[si].set(jnp.where(self_coord, init_bal,
-                                             NO_BALLOT), mode="drop"),
-        next_slot=state.next_slot.at[si].set(0, mode="drop"),
-        prep_votes=state.prep_votes.at[si].set(u32(0), mode="drop"),
-        **fresh,
-    )
-    return state, None
+    grp = _set_cols(
+        state.grp, si, active=True, members=members, version=version,
+        bal=init_bal, exec_cursor=0, gc_slot=NO_SLOT, is_coord=self_coord,
+        coord_active=self_coord,
+        cbal=jnp.where(self_coord, init_bal, NO_BALLOT), next_slot=0,
+        prep_votes=u32(0))
+    return state._replace(grp=grp, **fresh), None
 
 
 def delete_groups_batch(state: ColumnarState, rows, valid):
-    G = state.G
-    si = _si(rows, valid, G)
-    state = state._replace(
-        active=state.active.at[si].set(False, mode="drop"),
-        is_coord=state.is_coord.at[si].set(False, mode="drop"),
-        coord_active=state.coord_active.at[si].set(False, mode="drop"),
-    )
-    return state, None
+    grp = _set_cols(state.grp, _si(rows, valid, state.G), active=False,
+                    is_coord=False, coord_active=False)
+    return state._replace(grp=grp), None
 
 
 def set_cursor_batch(state: ColumnarState, rows, cursor, next_slot, valid):
     """Restore execution frontier on recovery/unpause (host is authoritative
     for executed state; ref: hot-restore via HotRestoreInfo)."""
-    G = state.G
-    si = _si(rows, valid, G)
-    state = state._replace(
-        exec_cursor=state.exec_cursor.at[si].set(cursor, mode="drop"),
-        next_slot=state.next_slot.at[si].max(next_slot, mode="drop"),
-    )
-    return state, None
+    si = _si(rows, valid, state.G)
+    grp = _set_cols(state.grp, si, exec_cursor=cursor)
+    grp = grp.at[_word(si, "next_slot")].max(next_slot, mode="drop")
+    return state._replace(grp=grp), None
 
 
 def gc_batch(state: ColumnarState, rows, upto, valid):
     """Record checkpoint slot (log below it is GC-eligible host-side)."""
-    G = state.G
-    si = _si(rows, valid, G)
-    state = state._replace(
-        gc_slot=state.gc_slot.at[si].max(upto, mode="drop"))
-    return state, None
+    grp = state.grp.at[_word(_si(rows, valid, state.G), "gc_slot")].max(
+        upto, mode="drop")
+    return state._replace(grp=grp), None
 
 
 # --------------------------------------------------------------------------
@@ -684,9 +704,10 @@ def gather_rows(state: ColumnarState, rows) -> RowState:
     fields, and the window planes packed as ``[n, W, k]``."""
     rows = jnp.asarray(rows)
     widx = _window_words(rows, state.W)
+    col = _group_words(state.grp, rows)
     return RowState(**{
         f: jnp.stack([getattr(state, c)[widx] for c, _ in PLANES[f]],
-                     axis=-1) if f in PLANES else getattr(state, f)[rows]
+                     axis=-1) if f in PLANES else col(f)
         for f in RowState._fields})
 
 
@@ -695,14 +716,12 @@ def scatter_rows(state: ColumnarState, rows, row_state: RowState, valid):
     G, W = state.G, state.W
     si = _si(rows, valid, G)
     widx = _window_words(si, W).reshape(-1)
-    new = {}
-    for f, r in row_state._asdict().items():
-        if f in PLANES:
-            for k, (c, _) in enumerate(PLANES[f]):
-                new[c] = getattr(state, c).at[widx].set(
-                    r[..., k].reshape(-1), mode="drop")
-        else:
-            new[f] = getattr(state, f).at[si].set(r, mode="drop")
+    row = row_state._asdict()
+    new = {c: getattr(state, c).at[widx].set(
+        row[f][..., k].reshape(-1), mode="drop")
+        for f, cols in PLANES.items() for k, (c, _) in enumerate(cols)}
+    new["grp"] = _set_cols(state.grp, si,
+                           **{f: row[f] for f in COL})
     return state._replace(**new), None
 
 

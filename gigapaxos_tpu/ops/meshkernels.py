@@ -1,8 +1,8 @@
 """shard_map variants of the columnar kernels (device-mesh engine).
 
 Every leaf of :class:`~gigapaxos_tpu.ops.types.ColumnarState` (the
-``[G]`` ballot/cursor mirrors and vote bitmaps, and the eleven linear
-``[G * W]`` window-plane components, which a cut on axis 0 divides into
+linear ``[G * 16]`` group table and the eleven linear ``[G * W]``
+window-plane components, each of which a cut on axis 0 divides into
 whole groups) is sharded on its one axis over a 1-D ``Mesh`` named
 :data:`GROUP_AXIS`; batch lanes stay replicated.  The
 per-wave kernels run as explicit ``shard_map`` programs: each shard owns

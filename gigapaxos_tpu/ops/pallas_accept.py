@@ -50,7 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT, ColumnarState
+from gigapaxos_tpu.ops.types import (NO_BALLOT, NO_SLOT, ColumnarState,
+                                     with_columns)
 
 i32 = jnp.int32
 SUB = 8  # octile height; Mosaic's sublane granule for i32
@@ -231,7 +232,7 @@ class PallasAccept:
         out_win = np.zeros(B, bool)
         cur_bal = np.full(B, NO_BALLOT, np.int32)
         todo = np.asarray(valid, bool).copy()
-        G = int(state.bal.shape[0])
+        G = state.G
         if G % SUB != 0:
             raise ValueError(f"capacity {G} not a multiple of {SUB}")
         n_blocks = G // SUB
@@ -282,9 +283,12 @@ class PallasAccept:
                 state.acc_bal, state.acc_slot, state.acc_rlo,
                 state.acc_rhi, self.interpret)
             bal_n, abal_n, aslot_n, alo_n, ahi_n, lane_out = new
-            state = state._replace(bal=bal_n, acc_bal=abal_n,
-                                   acc_slot=aslot_n, acc_rlo=alo_n,
-                                   acc_rhi=ahi_n)
+            # the [G] fields come out of the group table through its
+            # read views, a copy a call, and the promises go back a
+            # word a group (this path is off by default; ROADMAP D2)
+            state = with_columns(state, bal=bal_n)._replace(
+                acc_bal=abal_n, acc_slot=aslot_n, acc_rlo=alo_n,
+                acc_rhi=ahi_n)
             lo = np.asarray(lane_out)[:R].reshape(R, 4, self.L)
             live = ~padded.reshape(R, self.L)
             flat = lane_index.reshape(-1)[live.reshape(-1)]

@@ -51,6 +51,19 @@ Design notes (TPU-first):
   (:class:`RowState`: what ``gather_rows`` returns, what snapshots,
   pause blobs and ``scatter_rows`` carry).
 
+- **One word table for a group's scalars.** The eleven per-group scalars
+  (``GROUP_COLS``: ``active`` .. ``prep_votes``) are the columns of ONE
+  linear plane ``grp: i32[G * GROUP_WORDS]``, word ``g * 16 + k``.  A
+  boolean is a 0/1 word, ``prep_votes`` is bit-cast; five words a group
+  are spare and stay zero.  Sixteen words a group and not eleven, so that
+  eight groups fill a 128-word tile row exactly as a ``W = 16`` window
+  does: a stage reads every scalar of its groups with ONE row gather
+  (``kernels._group_words``) where it read each ``[G]`` field with a
+  gather of its own (63.9 ms of the storm step's 148, PERF.md §6, PR 37),
+  and writes a field with the one-word set of the window planes.
+  ``state.bal``, ``state.active``, ... are read-only views (a strided
+  copy on demand; cold path).
+
 - **Request ids.** The device stores only 64-bit request ids (two int32
   lanes); payload bytes stay host-side keyed by id, mirroring the
   reference's split between ``RequestPacket`` identity and body.
@@ -60,6 +73,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -96,6 +110,46 @@ PLANES = {
 }
 
 
+# the group table: column k of ``grp`` (word ``g * GROUP_WORDS + k``), the
+# dtype its read view and its RowState field have, a fresh row's value
+GROUP_WORDS = 16
+GROUP_COLS = (
+    ("active", np.bool_, 0),        # row allocated
+    ("members", np.int32, 0),       # replica count N (quorum = N//2+1)
+    ("version", np.int32, 0),       # reconfiguration epoch of the group
+    # acceptor (ref: PaxosAcceptor.java)
+    ("bal", np.int32, NO_BALLOT),   # promised ballot (packed)
+    ("exec_cursor", np.int32, 0),   # first not-known-decided contiguous slot
+    ("gc_slot", np.int32, NO_SLOT),  # checkpointed slot (log GC'd below)
+    # coordinator (ref: PaxosCoordinator/PaxosCoordinatorState.java)
+    ("is_coord", np.bool_, 0),      # this node believes it coordinates g
+    ("coord_active", np.bool_, 0),  # phase-1 complete, may assign slots
+    ("cbal", np.int32, NO_BALLOT),  # coordinator ballot (packed)
+    ("next_slot", np.int32, 0),     # next slot to assign
+    ("prep_votes", np.uint32, 0),   # phase-1 prepare-reply bitmap
+)
+COL = {f: k for k, (f, _, _) in enumerate(GROUP_COLS)}
+COL_DTYPE = {f: dtype for f, dtype, _ in GROUP_COLS}
+
+
+def to_word(x):
+    """A field's value as its i32 table word: a boolean 0/1, ``u32``
+    bit-cast (never converted: bit 31 stays bit 31)."""
+    x = jnp.asarray(x)
+    if x.dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def from_word(word, dtype):
+    """A table word as its field's dtype (:func:`to_word`'s inverse)."""
+    if dtype == np.bool_:
+        return word != 0
+    if dtype == np.uint32:
+        return jax.lax.bitcast_convert_type(word, jnp.uint32)
+    return word
+
+
 def pack_ballot(num: int, coord: int):
     """Pack (ballotNumber, coordinatorID) into one comparable int32."""
     return (num << NODE_BITS) | (coord & NODE_MASK)
@@ -111,16 +165,14 @@ def unpack_ballot(packed: int) -> Tuple[int, int]:
 
 
 class ColumnarState(NamedTuple):
-    """All-groups paxos state as device arrays.  Shapes: ``[G]``, and
-    ``[G * W]`` for a window-plane component (word ``g * W + w``)."""
+    """All-groups paxos state as device arrays: the group table
+    ``[G * GROUP_WORDS]`` (word ``g * 16 + k``, ``k`` a column of
+    ``GROUP_COLS``) and ``[G * W]`` for a window-plane component (word
+    ``g * W + w``).  Twelve leaves."""
 
-    # -- group table --
-    active: jnp.ndarray        # bool[G]  row allocated
-    members: jnp.ndarray       # i32[G]   replica count N (quorum = N//2+1)
-    version: jnp.ndarray       # i32[G]   reconfiguration epoch of the group
+    grp: jnp.ndarray           # i32[G*16] the groups' scalars (GROUP_COLS)
 
     # -- acceptor (ref: PaxosAcceptor.java) --
-    bal: jnp.ndarray           # i32[G]   promised ballot (packed)
     acc_slot: jnp.ndarray      # i32[G*W] accepted pvalue: slot
     acc_bal: jnp.ndarray       # i32[G*W]   ballot (packed)
     acc_rlo: jnp.ndarray       # i32[G*W]   request id, low word
@@ -128,15 +180,8 @@ class ColumnarState(NamedTuple):
     dec_slot: jnp.ndarray      # i32[G*W] decided pvalue: slot
     dec_rlo: jnp.ndarray       # i32[G*W]
     dec_rhi: jnp.ndarray       # i32[G*W]
-    exec_cursor: jnp.ndarray   # i32[G]   first not-known-decided contiguous slot
-    gc_slot: jnp.ndarray       # i32[G]   checkpointed slot (log GC'd below)
 
     # -- coordinator (ref: PaxosCoordinator/PaxosCoordinatorState.java) --
-    is_coord: jnp.ndarray      # bool[G]  this node believes it coordinates g
-    coord_active: jnp.ndarray  # bool[G]  phase-1 complete, may assign slots
-    cbal: jnp.ndarray          # i32[G]   coordinator ballot (packed)
-    next_slot: jnp.ndarray     # i32[G]   next slot to assign
-    prep_votes: jnp.ndarray    # u32[G]   phase-1 prepare-reply bitmap
     prop_slot: jnp.ndarray     # i32[G*W] proposal: slot
     prop_rlo: jnp.ndarray      # i32[G*W]
     prop_rhi: jnp.ndarray      # i32[G*W]
@@ -144,7 +189,7 @@ class ColumnarState(NamedTuple):
 
     @property
     def G(self) -> int:
-        return self.bal.shape[0]
+        return self.grp.shape[0] // GROUP_WORDS
 
     @property
     def W(self) -> int:
@@ -167,6 +212,18 @@ class ColumnarState(NamedTuple):
     @property
     def prop(self):
         return self._view("prop")
+
+
+def _column_view(f):
+    return property(lambda self: from_word(self.grp[COL[f]::GROUP_WORDS],
+                                           COL_DTYPE[f]))
+
+
+# ``state.active`` .. ``state.prep_votes``: each column of the table as
+# the ``[G]`` array of its dtype, a strided copy on demand like the views
+# above (tests, ``inspect``, the storm driver's read-back)
+for _f in COL:
+    setattr(ColumnarState, _f, _column_view(_f))
 
 
 class RowState(NamedTuple):
@@ -194,29 +251,24 @@ class RowState(NamedTuple):
 def make_state(G: int, W: int) -> ColumnarState:
     """Fresh all-inactive state.  G groups capacity, window width W."""
     i32 = jnp.int32
-
     # NOTE: every field gets its OWN buffer — sharing one array across
     # fields breaks donate_argnums ("attempt to donate the same buffer
     # twice").
-    def zG():
-        return jnp.zeros((G,), i32)
+    fresh = np.zeros((GROUP_WORDS,), np.int32)
+    fresh[:len(GROUP_COLS)] = [v for _, _, v in GROUP_COLS]
+    planes = {f: jnp.full((G * W,), v, i32)
+              for cols in PLANES.values() for f, v in cols}
+    return ColumnarState(grp=jnp.tile(jnp.asarray(fresh), G), **planes)
 
-    planes = {f: jnp.full((G * W,), fresh, i32)
-              for cols in PLANES.values() for f, fresh in cols}
-    return ColumnarState(
-        active=jnp.zeros((G,), jnp.bool_),
-        members=zG(),
-        version=zG(),
-        bal=jnp.full((G,), NO_BALLOT, i32),
-        exec_cursor=zG(),
-        gc_slot=jnp.full((G,), NO_SLOT, i32),
-        is_coord=jnp.zeros((G,), jnp.bool_),
-        coord_active=jnp.zeros((G,), jnp.bool_),
-        cbal=jnp.full((G,), NO_BALLOT, i32),
-        next_slot=zG(),
-        prep_votes=jnp.zeros((G,), jnp.uint32),
-        **planes,
-    )
+
+def with_columns(state: ColumnarState, **cols) -> ColumnarState:
+    """``state`` with whole columns of its group table replaced: ``[G]``
+    arrays by field name.  For building a state by hand (tests, the
+    Pallas path's write-back); a dense strided write, no kernel uses it."""
+    grp = state.grp
+    for f, v in cols.items():
+        grp = grp.at[COL[f]::GROUP_WORDS].set(to_word(v))
+    return state._replace(grp=grp)
 
 
 def split_req_id(req_id: int) -> Tuple[int, int]:
@@ -237,6 +289,6 @@ def join_req_id(lo: int, hi: int) -> int:
 
 def state_nbytes(G: int, W: int) -> int:
     """Approximate device bytes for a state of this capacity."""
-    per_g = 4 * 8 + 3    # 8 i32/u32 [G] fields + 3 bool [G] fields
+    per_g = 4 * GROUP_WORDS  # the group table: sixteen words a group
     per_gw = 4 * (4 + 3 + 4)  # the acc, dec and prop component planes, i32
     return G * per_g + G * W * per_gw

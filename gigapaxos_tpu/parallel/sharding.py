@@ -1,7 +1,8 @@
 """Group-axis sharding of the columnar state over a jax Mesh.
 
 Design (SURVEY.md §2.7, "TPU-native equivalent" column): every per-group
-array (``[G]`` or ``[G, W]``) is sharded on its leading (group) axis; batch
+array (the linear ``[G * 16]`` group table and the linear ``[G * W]``
+window planes) is sharded on its one axis, cut into whole groups; batch
 lanes stay replicated.  The per-wave kernels run as explicit ``shard_map``
 programs (:mod:`gigapaxos_tpu.ops.meshkernels`): each shard masks the
 batch down to the rows it owns and runs the unmodified kernel body on its

@@ -31,7 +31,8 @@ import numpy as np
 from gigapaxos_tpu.ops.kernels import (WAVE_IN, WAVE_IN_CUTS,
                                        WAVE_OUT_CUTS, WAVE_SECTIONS)
 from gigapaxos_tpu.ops.oracle import OracleGroup, PValue, make_oracle_group
-from gigapaxos_tpu.ops.types import NO_BALLOT, NO_SLOT, PLANES
+from gigapaxos_tpu.ops.types import (COL, COL_DTYPE, GROUP_WORDS, NO_BALLOT,
+                                     NO_SLOT, PLANES)
 from gigapaxos_tpu.utils.engineledger import EngineLedger
 from gigapaxos_tpu.utils.instrument import (RequestInstrumenter, span,
                                             traced)
@@ -1354,23 +1355,20 @@ class ColumnarBackend(AcceptorBackend):
                     self._pad1(upto[a:b], NO_SLOT), self._valid(b - a))
 
     def cursor_of(self, row: int) -> int:
-        return int(self.state.exec_cursor[row])
+        return int(self.inspect_rows([row])["exec_cursor"][0])
 
     def inspect_rows(self, rows) -> Dict[str, np.ndarray]:
         """ONE stacked gather + ONE device->host transfer for the four
         scalar consensus planes — the cheap vectorized extraction the
         ``/groups`` introspection endpoint leans on (snapshot_rows
         hauls the full [W, 4] window planes; this hauls 4 ints/row)."""
-        rows = np.asarray(rows, np.int32)
-        st = self.state
+        fields = ("bal", "cbal", "next_slot", "exec_cursor")
+        words = np.asarray(rows, np.int32)[None, :] * GROUP_WORDS + \
+            np.asarray([[COL[f]] for f in fields], np.int32)
         with self._disp():
             import jax
-            stacked = jax.device_get(jax.numpy.stack(
-                (st.bal[rows], st.cbal[rows], st.next_slot[rows],
-                 st.exec_cursor[rows])))
-        stacked = np.asarray(stacked, np.int64)
-        return {"bal": stacked[0], "cbal": stacked[1],
-                "next_slot": stacked[2], "exec_cursor": stacked[3]}
+            stacked = jax.device_get(self.state.grp[words])
+        return dict(zip(fields, np.asarray(stacked, np.int64)))
 
     def snapshot_row(self, row: int) -> dict:
         return self.snapshot_rows([row])[0]
@@ -1391,10 +1389,8 @@ class ColumnarBackend(AcceptorBackend):
         # coerce dtypes: snapshots may round-trip through JSON (pause
         # blobs), which turns u32 vote words / bool flags into int lists
         row_state = RowState(
-            **{f: self._dev(
-                np.asarray(snap[f]).astype(
-                    np.int32 if f in PLANES
-                    else getattr(self.state, f).dtype)[None])
+            **{f: self._dev(np.asarray(snap[f]).astype(
+                COL_DTYPE.get(f, np.int32))[None])
                for f in RowState._fields})
         with self._disp():
             self.state, _ = scatter_rows(
@@ -1421,7 +1417,7 @@ class ColumnarBackend(AcceptorBackend):
         total = 0
         for f in st._fields:
             nb = int(getattr(st, f).nbytes)
-            plane = _PLANE_OF.get(f, "control")
+            plane = _PLANE_OF[f]
             planes[plane] = planes.get(plane, 0) + nb
             total += nb
         per_group = total / float(self.capacity)
@@ -1436,7 +1432,7 @@ class ColumnarBackend(AcceptorBackend):
             "platform": self.engine_platform,
         }
         # None on backends that keep no allocator statistics (XLA:CPU)
-        ms = next(iter(st.bal.devices())).memory_stats()
+        ms = next(iter(st.grp.devices())).memory_stats()
         if ms:
             limit = int(ms.get("bytes_limit", 0) or 0)
             out["device_bytes_in_use"] = int(
@@ -1505,13 +1501,8 @@ class ColumnarBackend(AcceptorBackend):
 
 # plane grouping of the ColumnarState fields for the accounting view:
 # the window planes' components roll up into the three slabs they make
-# (acc_slot .. acc_rhi -> "acc"); the [G] scalar mirrors roll up by role
+# (acc_slot .. acc_rhi -> "acc"); the groups' scalars are one table
 _PLANE_OF = {
     **{c: view for view, cols in PLANES.items() for c, _ in cols},
-    "bal": "ballots", "cbal": "ballots",
-    "exec_cursor": "cursors", "next_slot": "cursors",
-    "gc_slot": "cursors",
-    "prep_votes": "votes",
-    "active": "control", "members": "control", "version": "control",
-    "is_coord": "control", "coord_active": "control",
+    "grp": "groups",
 }

@@ -29,8 +29,7 @@ ENGINE_KEYS = {"node", "platform", "engine_mesh",
 LEDGER_KEYS = {"kernels", "compiles", "retraces", "compile_s",
                "cache_hits", "cache_misses", "monitoring", "warmed"}
 # plane grouping of the columnar slab accounting view
-PLANE_KEYS = {"control", "ballots", "acc", "dec", "cursors", "votes",
-              "prop"}
+PLANE_KEYS = {"groups", "acc", "dec", "prop"}
 
 
 def _columnar_node(tmp_path):

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from gigapaxos_tpu.ops import kernels, make_state, pack_ballot
+from gigapaxos_tpu.ops.types import with_columns
 from gigapaxos_tpu.ops.types import NO_SLOT, join_req_id, split_req_id
 from gigapaxos_tpu.ops.oracle import make_oracle_group, PValue
 
@@ -445,10 +446,9 @@ def test_commit_advance_equals_oracle_frontier(kind, Wn):
         og.decided = {int(v): 1 for c, v in cells.items()
                       if not (c == s % Wn and cur <= s < cur + Wn)}
         want.append(og.commit(s, 1))
-    st = make_state(Bn, Wn)
-    st = st._replace(
-        active=jnp.ones((Bn,), jnp.bool_), exec_cursor=jnp.asarray(cursor),
-        dec_slot=jnp.asarray(dslot).reshape(-1))
+    st = with_columns(
+        make_state(Bn, Wn)._replace(dec_slot=jnp.asarray(dslot).reshape(-1)),
+        active=jnp.ones((Bn,), jnp.bool_), exec_cursor=jnp.asarray(cursor))
     valid = jnp.arange(Bn) < len(rows)
     one = jnp.ones((Bn,), i32)
     st, o = kernels.commit(st, jnp.arange(Bn, dtype=i32), jnp.asarray(slot),

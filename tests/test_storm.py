@@ -135,11 +135,14 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     lane-shaped array (what un-permuting a rank is), and 35 scatters that
     always run, each of ONE word a lane: propose 1 + 4 (``next_slot`` and
     the four proposal components), accept (1 + 4) x R, accept-reply
-    1 x R (the vote add), commit (3 + 1) x R; the 2 x R resign scatters
-    sit under a cond.  Every one that always runs states that its indices
-    are unique, and writes a ``[G]`` field or a linear ``[G * W]``
-    component plane.  No gather reads a lane-shaped operand and no scan
-    runs along a window row."""
+    1 x R (the vote add), commit (3 + 1) x R; the R resign scatters (two
+    columns of the group table each) sit under a cond.  Every one that
+    always runs states that its indices are unique, and writes the group
+    table ``[G * 16]`` or a linear ``[G * W]`` component plane: there is
+    no ``[G]`` array.  The table is read ONCE a stage call, 1 + 3 R row
+    gathers (and once more for the cursor a commit answers with, which
+    the step drops, and the compiler the read with it); no gather reads a
+    lane-shaped operand and no scan runs along a window row."""
     import jax
     import jax.numpy as jnp
     from gigapaxos_tpu.ops.storm import decide_storm_step
@@ -156,9 +159,12 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     scatters = [(c, e) for n, c, e in eqns if n.startswith("scatter")]
     always = [e for c, e in scatters if not c]
     assert len(always) == 5 + 10 * R, len(always)
-    assert len(scatters) - len(always) == 2 * R
+    assert len(scatters) - len(always) == R
+    table = 16 * G
+    assert sum(e.invars[0].aval.shape == (table,) for e in always) == \
+        1 + 2 * R  # next_slot; bal and exec_cursor of each replica
     for e in always:
-        assert e.invars[0].aval.shape in ((G,), (G * W,)), e  # never [B]
+        assert e.invars[0].aval.shape in ((table,), (G * W,)), e
         assert e.invars[2].aval.shape == (B,), e  # a word a lane
         assert e.params["unique_indices"], e
     # every gather reads a state array and every scan runs along the
@@ -167,7 +173,9 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     gathers = [e for n, _, e in eqns if n == "gather"]
     assert gathers
     for e in gathers:  # the plane may be seen as rows of 128 words
-        assert e.invars[0].aval.size in (G, G * W), e
+        assert e.invars[0].aval.size in (table, G * W), e
+    assert sum(e.invars[0].aval.size == table
+               for e in gathers) == 1 + 3 * R + R
     for n, _, e in eqns:
         if n.startswith("cum"):
             assert e.invars[0].aval.shape == (B,), e
