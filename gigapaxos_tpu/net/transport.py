@@ -39,6 +39,7 @@ import numpy as np
 from gigapaxos_tpu import native
 from gigapaxos_tpu.chaos.faults import ChaosPlane
 from gigapaxos_tpu.paxos import packets as pk
+from gigapaxos_tpu.utils.instrument import traced
 from gigapaxos_tpu.utils.logutil import get_logger
 from gigapaxos_tpu.utils.profiler import DelayProfiler
 
@@ -359,7 +360,14 @@ class Transport:
         return True
 
     def send_threadsafe(self, dst: int, frame: bytes) -> None:
-        self._loop.call_soon_threadsafe(self.send, dst, frame)
+        self._loop.call_soon_threadsafe(self._send_traced, dst, frame)
+
+    def _send_traced(self, dst: int, frame: bytes) -> None:
+        """:meth:`send` as the loop runs it for another thread, under
+        the span ``gp.net.write``."""
+        with traced("net.write", node=self.id, frames=1,
+                    bytes=len(frame)):
+            self.send(dst, frame)
 
     def send_many(self, items: list) -> None:
         """Enqueue ``[(dst, payload, preframed, nframes), ...]`` — ONE
@@ -370,28 +378,33 @@ class Transport:
         super-frame via :meth:`send_frags` — per-destination send order
         is preserved (a non-coalescible item flushes the pending
         group first), and only peers that announced a compatible wire
-        version participate."""
-        if not self.wire_coalesce:
+        version participate.  The loop runs it under the span
+        ``gp.net.write``, with the frames and bytes handed over."""
+        with traced("net.write", node=self.id) as sp:
+            if sp.on:
+                sp.note(frames=sum(it[3] for it in items),
+                        bytes=sum(len(it[1]) for it in items))
+            if not self.wire_coalesce:
+                for dst, payload, preframed, nframes in items:
+                    self._enqueue(dst, payload, preframed, nframes)
+                return
+            groups: Dict[int, list] = {}
             for dst, payload, preframed, nframes in items:
+                if not preframed \
+                        and self.peer_wire.get(dst, 0) \
+                        >= pk.WIRE_GATED["FRAG"] \
+                        and dst in self.addr_map:
+                    g = groups.get(dst)
+                    if g is None:
+                        g = groups[dst] = []
+                    g.append(payload)
+                    continue
+                pend = groups.pop(dst, None)
+                if pend is not None:
+                    self._flush_group(dst, pend)
                 self._enqueue(dst, payload, preframed, nframes)
-            return
-        groups: Dict[int, list] = {}
-        for dst, payload, preframed, nframes in items:
-            if not preframed \
-                    and self.peer_wire.get(dst, 0) \
-                    >= pk.WIRE_GATED["FRAG"] \
-                    and dst in self.addr_map:
-                g = groups.get(dst)
-                if g is None:
-                    g = groups[dst] = []
-                g.append(payload)
-                continue
-            pend = groups.pop(dst, None)
-            if pend is not None:
-                self._flush_group(dst, pend)
-            self._enqueue(dst, payload, preframed, nframes)
-        for dst, bufs in groups.items():
-            self._flush_group(dst, bufs)
+            for dst, bufs in groups.items():
+                self._flush_group(dst, bufs)
 
     def _flush_group(self, dst: int, bufs: list) -> None:
         # a lone column-packable batch frame still rides a 1-member
@@ -579,70 +592,76 @@ class Transport:
             chunk = await reader.read(1 << 18)
             if not chunk:
                 return
-            self.rx_reads += 1
-            buf += chunk
-            offs, lens, consumed = native.scan_frames(buf)
-            if len(offs):
-                mv = memoryview(buf)
-                start = 0
-                if first:
-                    # a coalescing peer's first frame is its version
-                    # hello: record and swallow (never delivered)
-                    first = False
-                    o0, l0 = int(offs[0]), int(lens[0])
-                    if l0 >= 10 and buf[o0] == _HELLO_T:
-                        try:
-                            s, v = pk.parse_wire_hello(
-                                bytes(mv[o0:o0 + l0]))
-                        except ValueError:
-                            pass
-                        else:
-                            self.peer_wire[s] = v
-                            self.rcvd_frames += 1
-                            start = 1
-                if self.rx_chunks:
-                    ck = self._make_chunk(mv, offs, lens, start,
-                                          consumed)
-                    if ck is not None:
-                        if self.on_frames is not None:
+            # gp.net.read: the chunk's scan, delivery and intake put,
+            # with its logical frames (as rcvd_frames counts them)
+            with traced("net.read", node=self.id) as sp:
+                f0 = self.rcvd_frames
+                self.rx_reads += 1
+                buf += chunk
+                offs, lens, consumed = native.scan_frames(buf)
+                if len(offs):
+                    mv = memoryview(buf)
+                    start = 0
+                    if first:
+                        # a coalescing peer's first frame is its version
+                        # hello: record and swallow (never delivered)
+                        first = False
+                        o0, l0 = int(offs[0]), int(lens[0])
+                        if l0 >= 10 and buf[o0] == _HELLO_T:
                             try:
-                                self.on_frames([ck])
-                            except Exception:
-                                log.exception("batch handler failed")
-                        else:
-                            for i in range(len(ck)):
-                                self._dispatch(bytes(ck.view(i)))
-                else:
-                    frames = [bytes(mv[int(o):int(o) + int(ln)])
-                              for o, ln in zip(offs[start:],
-                                               lens[start:])]
-                    n_log = len(frames)
-                    if self.wire_coalesce:
-                        # count FRAG containers as their member frames
-                        # (rx_frames stays the logical-frame counter)
-                        for f in frames:
-                            if f and f[0] == _FRAG_T:
-                                k = _HDR_N.unpack_from(f, 5)[0]
-                                self.rx_frags += 1
-                                self.rx_frag_members += k
-                                n_log += k - 1
-                    self.rcvd_frames += n_log
-                    self.rcvd_bytes += consumed
-                    bb = self.blackbox
-                    if bb is not None:
-                        bb.note_ingress(n_log, consumed)
-                    if frames:
-                        if self.on_frames is not None:
-                            try:
-                                self.on_frames(frames)
-                            except Exception:
-                                log.exception("batch handler failed")
-                        else:
+                                s, v = pk.parse_wire_hello(
+                                    bytes(mv[o0:o0 + l0]))
+                            except ValueError:
+                                pass
+                            else:
+                                self.peer_wire[s] = v
+                                self.rcvd_frames += 1
+                                start = 1
+                    if self.rx_chunks:
+                        ck = self._make_chunk(mv, offs, lens, start,
+                                              consumed)
+                        if ck is not None:
+                            if self.on_frames is not None:
+                                try:
+                                    self.on_frames([ck])
+                                except Exception:
+                                    log.exception("batch handler failed")
+                            else:
+                                for i in range(len(ck)):
+                                    self._dispatch(bytes(ck.view(i)))
+                    else:
+                        frames = [bytes(mv[int(o):int(o) + int(ln)])
+                                  for o, ln in zip(offs[start:],
+                                                   lens[start:])]
+                        n_log = len(frames)
+                        if self.wire_coalesce:
+                            # count FRAG containers as their member frames
+                            # (rx_frames stays the logical-frame counter)
                             for f in frames:
-                                self._dispatch(f)
-                del mv
-            if consumed:
-                del buf[:consumed]
+                                if f and f[0] == _FRAG_T:
+                                    k = _HDR_N.unpack_from(f, 5)[0]
+                                    self.rx_frags += 1
+                                    self.rx_frag_members += k
+                                    n_log += k - 1
+                        self.rcvd_frames += n_log
+                        self.rcvd_bytes += consumed
+                        bb = self.blackbox
+                        if bb is not None:
+                            bb.note_ingress(n_log, consumed)
+                        if frames:
+                            if self.on_frames is not None:
+                                try:
+                                    self.on_frames(frames)
+                                except Exception:
+                                    log.exception("batch handler failed")
+                            else:
+                                for f in frames:
+                                    self._dispatch(f)
+                    del mv
+                if consumed:
+                    del buf[:consumed]
+                if sp.on:
+                    sp.note(frames=self.rcvd_frames - f0, bytes=consumed)
 
     def _make_chunk(self, mv: memoryview, offs: np.ndarray,
                     lens: np.ndarray, start: int,

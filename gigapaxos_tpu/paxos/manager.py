@@ -117,12 +117,6 @@ class _StampedQueue(queue_mod.Queue):
 _STOP = object()  # _next_batch: the stop sentinel came out of the queue
 
 
-def _no_cpu_clock():
-    """Stand-in for time.thread_time when PC.PROFILE_CPU is off —
-    update_total skips the CPU column for a None t0."""
-    return None
-
-
 @dataclass
 class _InFlight:
     """Coordinator-side in-flight proposal (dedupe + accept re-drive).
@@ -375,9 +369,10 @@ class PaxosNode:
         self.batch_busy = int(Config.get(PC.BATCH_BUSY_ITEMS))
         self.checkpoint_interval = int(Config.get(PC.CHECKPOINT_INTERVAL))
         # stage CPU accounting: thread_time() is a ~6us syscall, so the
-        # hot path only samples it when PC.PROFILE_CPU asks for it
+        # sums only sample it when PC.PROFILE_CPU asks for it (the ring's
+        # c0/c1 are read while spans are on, whatever this says)
         self._ct = time.thread_time \
-            if bool(Config.get(PC.PROFILE_CPU)) else _no_cpu_clock
+            if bool(Config.get(PC.PROFILE_CPU)) else None
 
         # host-side per-row mirrors (the cold scalar state the reference
         # keeps in PaxosInstanceStateMachine fields).  Row-indexed numpy
@@ -1972,20 +1967,17 @@ class PaxosNode:
             self._handle_prepares(prepares)
         pbs = by_type.pop(pkt.PrepareBatch, [])
         if pbs:
-            t0 = time.monotonic()
-            self._handle_prepare_batches(pbs)
-            DelayProfiler.update_total(
-                "w.prepare_batch", t0, sum(len(p.gkey) for p in pbs))
+            with span("w.prepare_batch", node=self.id,
+                      n=sum(len(p.gkey) for p in pbs), cpu=self._ct):
+                self._handle_prepare_batches(pbs)
         for o in by_type.pop(pkt.PrepareReply, []):
             self._handle_prepare_reply(o)
         prbs = by_type.pop(pkt.PrepareReplyBatch, [])
         if prbs:
-            t0 = time.monotonic()
-            for o in prbs:
-                self._handle_prepare_reply_batch(o)
-            DelayProfiler.update_total(
-                "w.prepare_reply_batch", t0,
-                sum(len(p.gkey) for p in prbs))
+            with span("w.prepare_reply_batch", node=self.id,
+                      n=sum(len(p.gkey) for p in prbs), cpu=self._ct):
+                for o in prbs:
+                    self._handle_prepare_reply_batch(o)
 
         # hot path, pipeline order
         reqs = by_type.pop(pkt.Request, [])
@@ -2002,12 +1994,9 @@ class PaxosNode:
             # whole-wave fusion: every hot frame of the batch, whatever
             # roles it holds, in ONE engine wave (_handle_node_wave has
             # the ordering argument)
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_node_wave(reqs, props, soas, replies, accepts,
-                                   commits)
-            DelayProfiler.update_total("w.node_wave", t0, n_hot,
-                                       cpu_t0=c0)
+            with span("w.node_wave", node=self.id, n=n_hot, cpu=self._ct):
+                self._handle_node_wave(reqs, props, soas, replies,
+                                       accepts, commits)
         elif n_hot:
             self._handle_hot_split(reqs, props, soas, replies, accepts,
                                    commits)
@@ -2017,15 +2006,14 @@ class PaxosNode:
                 log.warning("unhandled packet type %s x%d", t.__name__,
                             len(objs))
                 continue
-            t0 = time.monotonic()
-            for o in objs:
-                for h in handlers:
-                    try:
-                        h(o)
-                    except Exception:
-                        log.exception("handler %r failed", h)
-            DelayProfiler.update_total(f"w.upper.{t.__name__}", t0,
-                                       len(objs))
+            with span(f"w.upper.{t.__name__}", node=self.id,
+                      n=len(objs), cpu=self._ct):
+                for o in objs:
+                    for h in handlers:
+                        try:
+                            h(o)
+                        except Exception:
+                            log.exception("handler %r failed", h)
 
     def _handle_hot_split(self, reqs: List, props: List, soas: List,
                           replies: List, accepts: List,
@@ -2034,13 +2022,10 @@ class PaxosNode:
         role in pipeline order (every engine but the columnar one with
         whole-wave fusion on, which takes :meth:`_handle_node_wave`)."""
         if reqs or props or soas:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_requests(reqs, props, soas)
-            DelayProfiler.update_total(
-                "w.requests", t0,
-                len(reqs) + len(props) + sum(len(s.gkey) for s in soas),
-                cpu_t0=c0)
+            with span("w.requests", node=self.id,
+                      n=len(reqs) + len(props)
+                      + sum(len(s.gkey) for s in soas), cpu=self._ct):
+                self._handle_requests(reqs, props, soas)
         # async overlapped acceptor wave (columnar, fusion off — the
         # host-XLA operating point): submit the accept wave AND the
         # commit wave back-to-back, then run the host halves in split-
@@ -2055,30 +2040,21 @@ class PaxosNode:
         overlap_wave = bool(accepts) and bool(commits) \
             and self._col_self is not None
         if overlap_wave:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_accepts_commits_overlapped(accepts, commits)
-            DelayProfiler.update_total(
-                "w.acc_com", t0, len(accepts) + len(commits),
-                cpu_t0=c0)
+            with span("w.acc_com", node=self.id,
+                      n=len(accepts) + len(commits), cpu=self._ct):
+                self._handle_accepts_commits_overlapped(accepts, commits)
         elif accepts:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_accepts(accepts)
-            DelayProfiler.update_total("w.accepts", t0, len(accepts),
-                                       cpu_t0=c0)
+            with span("w.accepts", node=self.id, n=len(accepts),
+                      cpu=self._ct):
+                self._handle_accepts(accepts)
         if replies:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_accept_replies(replies)
-            DelayProfiler.update_total("w.replies", t0, len(replies),
-                                       cpu_t0=c0)
+            with span("w.replies", node=self.id, n=len(replies),
+                      cpu=self._ct):
+                self._handle_accept_replies(replies)
         if commits and not overlap_wave:
-            t0 = time.monotonic()
-            c0 = self._ct()
-            self._handle_commits(commits)
-            DelayProfiler.update_total("w.commits", t0, len(commits),
-                                       cpu_t0=c0)
+            with span("w.commits", node=self.id, n=len(commits),
+                      cpu=self._ct):
+                self._handle_commits(commits)
 
     def register_handler(self, ptype: type, fn) -> None:
         """Register an upper-layer handler for a packet class (called on
@@ -3374,60 +3350,70 @@ class PaxosNode:
         re-offered accept is a wave of its own after the batch
         (:meth:`_process`), as before."""
         qpre = ppre = None
-        if reqs or props or soas:
-            qpre = self._req_pre(reqs, props, soas)
-        if replies:
-            r_gkeys = _cat(replies,
-                           lambda o: np.asarray(o.gkey, np.uint64))
-            ppre = self._rep_pre(
-                self._rows_for_keys(r_gkeys),
-                _cat(replies, lambda o: np.asarray(o.slot, np.int32)),
-                _cat(replies, lambda o: np.asarray(o.bal, np.int32)),
-                _cat(replies, lambda o: np.full(len(o.gkey), o.sender,
-                                                np.int32)),
-                _cat(replies, lambda o: np.asarray(o.acked, np.uint8)))
-        a_gkeys, apre, c_gkeys, cpre = self._acc_com_pre(accepts, commits)
-        if qpre is ppre is apre is cpre is None:
-            return
-        req = rep = acc = com = None
-        if qpre is not None:
-            rows, req_ids, flag_parts, pay_parts, q_now = qpre
-            req = (rows, req_ids, self._self_midx(rows))
-        if ppre is not None:
-            r_sel, rep = ppre[0], ppre[1:]
-        if apre is not None:
-            idxs, a_rows, a_slots, a_bals, a_reqs, senders, a_now = apre
-            acc = (a_rows, a_slots, a_bals, a_reqs)
-        if cpre is not None:
-            c_sel, com = cpre[0], cpre[1:]
+        with traced("w.pre", node=self.id) as sp:
+            if sp.on:
+                sp.note(roles="+".join(
+                    r for r, objs in (("req", reqs or props or soas),
+                                      ("rep", replies), ("acc", accepts),
+                                      ("com", commits)) if objs))
+            if reqs or props or soas:
+                qpre = self._req_pre(reqs, props, soas)
+            if replies:
+                r_gkeys = _cat(replies,
+                               lambda o: np.asarray(o.gkey, np.uint64))
+                ppre = self._rep_pre(
+                    self._rows_for_keys(r_gkeys),
+                    _cat(replies, lambda o: np.asarray(o.slot, np.int32)),
+                    _cat(replies, lambda o: np.asarray(o.bal, np.int32)),
+                    _cat(replies, lambda o: np.full(len(o.gkey), o.sender,
+                                                    np.int32)),
+                    _cat(replies,
+                         lambda o: np.asarray(o.acked, np.uint8)))
+            a_gkeys, apre, c_gkeys, cpre = self._acc_com_pre(accepts,
+                                                             commits)
+            if qpre is ppre is apre is cpre is None:
+                return
+            req = rep = acc = com = None
+            if qpre is not None:
+                rows, req_ids, flag_parts, pay_parts, q_now = qpre
+                req = (rows, req_ids, self._self_midx(rows))
+            if ppre is not None:
+                r_sel, rep = ppre[0], ppre[1:]
+            if apre is not None:
+                idxs, a_rows, a_slots, a_bals, a_reqs, senders, a_now = \
+                    apre
+                acc = (a_rows, a_slots, a_bals, a_reqs)
+            if cpre is not None:
+                c_sel, com = cpre[0], cpre[1:]
         qres, pres, ares, cres = self.backend.wave_submit(
             req, rep, acc, com).collect()
-        freed = self._rows_freed = set()
-        try:
-            if qres is not None:
-                self._req_post(rows, req_ids, flag_parts, pay_parts,
-                               q_now, *qres)
-            if pres is not None:
-                self._rep_post(r_gkeys, r_sel, *rep[:3], *pres)
-        finally:
-            self._rows_freed = None
+        with traced("w.post", node=self.id):
+            freed = self._rows_freed = set()
+            try:
+                if qres is not None:
+                    self._req_post(rows, req_ids, flag_parts, pay_parts,
+                                   q_now, *qres)
+                if pres is not None:
+                    self._rep_post(r_gkeys, r_sel, *rep[:3], *pres)
+            finally:
+                self._rows_freed = None
 
-        def still(rows_, cols, res):
-            """The lanes (columns and results) whose row the
-            coordinator's posts left alone."""
-            if not freed:
-                return cols, res
-            keep = ~np.isin(rows_, list(freed))
-            return ([c[keep] for c in cols],
-                    type(res)(*(np.asarray(x)[keep] for x in res)))
+            def still(rows_, cols, res):
+                """The lanes (columns and results) whose row the
+                coordinator's posts left alone."""
+                if not freed:
+                    return cols, res
+                keep = ~np.isin(rows_, list(freed))
+                return ([c[keep] for c in cols],
+                        type(res)(*(np.asarray(x)[keep] for x in res)))
 
-        if ares is not None:
-            cols, ares = still(a_rows, (idxs, a_rows, a_slots, a_bals,
-                                        a_reqs, senders), ares)
-            self._acc_post(accepts, a_gkeys, *cols, a_now, ares)
-        if cres is not None:
-            cols, cres = still(com[0], (c_sel, *com), cres)
-            self._commit_post(c_gkeys, *cols, cres)
+            if ares is not None:
+                cols, ares = still(a_rows, (idxs, a_rows, a_slots,
+                                            a_bals, a_reqs, senders), ares)
+                self._acc_post(accepts, a_gkeys, *cols, a_now, ares)
+            if cres is not None:
+                cols, cres = still(com[0], (c_sel, *com), cres)
+                self._commit_post(c_gkeys, *cols, cres)
 
     # -- accept replies (coordinator side) ------------------------------
 

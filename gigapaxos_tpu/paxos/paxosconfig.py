@@ -90,8 +90,8 @@ class PC(ConfigKey):
     # traffic (proposals/accepts/replies/commits) always flows.  0
     # disables.
     INTAKE_BACKLOG_LIMIT = 2048
-    # per-stage CPU-seconds accounting (DelayProfiler update_total
-    # cpu column).  Off by default: thread_time() is a real syscall
+    # per-stage CPU-seconds accounting (the cpu column of a stage
+    # span's DelayProfiler total).  Off by default: thread_time() is a real syscall
     # (~6 us — no vDSO for CLOCK_THREAD_CPUTIME_ID) and the worker
     # makes ~12 of these per pass, a measurable tax on trickle batches
     PROFILE_CPU = False

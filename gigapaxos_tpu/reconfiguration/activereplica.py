@@ -25,6 +25,7 @@ from gigapaxos_tpu.paxos.manager import FLAG_STOP, PaxosNode
 from gigapaxos_tpu.reconfiguration import rcpackets as rc
 from gigapaxos_tpu.reconfiguration.coordinator import PaxosReplicaCoordinator
 from gigapaxos_tpu.reconfiguration.rcdb import b64d, b64e
+from gigapaxos_tpu.utils.instrument import span
 from gigapaxos_tpu.utils.logutil import get_logger
 
 log = get_logger("gp.active")
@@ -80,16 +81,9 @@ class ActiveReplica:
     # -- control handling (worker thread) ----------------------------------
 
     def _on_control(self, o: pkt.Control) -> None:
-        import time as _time
-
-        from gigapaxos_tpu.utils.profiler import DelayProfiler
-        _t0 = _time.monotonic()
-        _c0 = _time.thread_time()
-        try:
+        with span(f"w.ar.{o.body.get('rc')}", node=self.id,
+                  cpu=self.node._ct):
             self._on_control_inner(o)
-        finally:
-            DelayProfiler.update_total(
-                f"w.ar.{o.body.get('rc')}", _t0, cpu_t0=_c0)
 
     def _on_control_inner(self, o: pkt.Control) -> None:
         b = o.body

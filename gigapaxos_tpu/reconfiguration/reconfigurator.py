@@ -35,6 +35,7 @@ from gigapaxos_tpu.reconfiguration.demand import AbstractDemandProfile
 from gigapaxos_tpu.reconfiguration.rcdb import (READY, WAIT_ACK_START,
                                                 WAIT_ACK_STOP, RCRecord,
                                                 ReconfiguratorDB)
+from gigapaxos_tpu.utils.instrument import span
 from gigapaxos_tpu.utils.logutil import get_logger
 
 log = get_logger("gp.rc")
@@ -155,16 +156,9 @@ class Reconfigurator:
     # -- client/active control traffic (worker thread) ---------------------
 
     def _on_control(self, o: pkt.Control) -> None:
-        import time as _time
-
-        from gigapaxos_tpu.utils.profiler import DelayProfiler
-        _t0 = _time.monotonic()
-        _c0 = _time.thread_time()
-        try:
+        with span(f"w.rc.{o.body.get('rc')}", node=self.id,
+                  cpu=self.node._ct):
             self._on_control_inner(o)
-        finally:
-            DelayProfiler.update_total(
-                f"w.rc.{o.body.get('rc')}", _t0, cpu_t0=_c0)
 
     def _on_control_inner(self, o: pkt.Control) -> None:
         b = o.body

@@ -224,18 +224,15 @@ class DelayProfiler:
     enabled: bool = True
 
     @classmethod
-    def update_total(cls, tag: str, t0: float, n: int = 1,
-                     cpu_t0: Optional[float] = None) -> None:
+    def update_total(cls, tag: str, t0: float, n: int = 1) -> None:
         """Accumulate wall seconds + item count under ``tag`` — the
         where-does-the-core-go view (EWMAs show per-batch shape, totals
-        show the budget split).  Pass ``cpu_t0`` (from
-        ``time.thread_time()``) to also accumulate true CPU seconds —
-        on a saturated 1-core host, wall inside a stage is mostly GIL
-        wait and lies about the budget."""
+        show the budget split).  A stage of the node's own takes
+        ``utils.instrument.span`` instead, whose ``cpu=`` adds the CPU
+        column and whose record the span ring keeps."""
         if not cls.enabled:
             return
         dt = time.monotonic() - t0
-        dcpu = (time.thread_time() - cpu_t0) if cpu_t0 is not None else 0.0
         with cls._lock:
             t = cls._totals.get(tag)
             if t is None:  # not setdefault: no list built per call
@@ -243,7 +240,6 @@ class DelayProfiler:
             t[0] += dt
             t[1] += 1
             t[2] += n
-            t[3] += dcpu
 
     @classmethod
     def add_total(cls, tag: str, seconds: float, n: int = 1,

@@ -19,6 +19,14 @@ from gigapaxos_tpu.utils.instrument import span, traced
 from gigapaxos_tpu.utils.profiler import DelayProfiler
 
 TOP = ("w.wait", "w.coalesce", "w.decode", "w.process", "w.tick")
+HANDLERS = ("w.node_wave", "w.requests", "w.acc_com", "w.accepts",
+            "w.replies", "w.commits", "w.prepare_batch",
+            "w.prepare_reply_batch")
+
+
+def _handler(kind):
+    return kind in HANDLERS or kind.startswith(("w.upper.", "w.ar.",
+                                                "w.rc."))
 
 
 @pytest.fixture(autouse=True)
@@ -107,6 +115,111 @@ def test_the_ring_counts_what_it_drops(monkeypatch):
 
 
 @pytest.mark.smoke
+def test_off_reads_no_thread_clock(monkeypatch):
+    calls = []
+    clock = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: calls.append(1) or clock())
+    assert not RI.tracing()
+    for _ in range(3):
+        with span("t.stage", node=1, n=2):
+            with traced("t.inner", bytes=8):
+                pass
+    assert calls == [] and DelayProfiler.totals()["t.stage"][1] == 3
+    # on: two reads a span, at its open and its close
+    RI.enabled = True
+    with span("t.stage"):
+        with traced("t.inner"):
+            pass
+    assert len(calls) == 4
+
+
+@pytest.mark.smoke
+def test_on_each_record_has_the_threads_cpu_clock():
+    RI.enabled = True
+    best_sleep, best_busy = 0.0, 0.0
+    for _ in range(5):  # a loaded host may preempt a busy loop: best of 5
+        with span("t.sleep") as a:
+            time.sleep(0.05)
+        with traced("t.busy") as b:
+            end = time.monotonic() + 0.05
+            while time.monotonic() < end:
+                pass
+        sa, sb = RI.spans_snapshot()[-2:]
+        assert (sa["kind"], sb["kind"]) == ("t.sleep", "t.busy")
+        best_sleep = max(best_sleep, (sa["t1"] - sa["t0"])
+                         - (sa["c1"] - sa["c0"]))
+        best_busy = max(best_busy, (sb["c1"] - sb["c0"])
+                        / (sb["t1"] - sb["t0"]))
+        if best_sleep >= 0.04 and best_busy >= 0.8:
+            break
+    assert a.on and b.on
+    assert best_sleep >= 0.04, best_sleep   # off CPU
+    assert best_busy >= 0.8, best_busy      # on CPU
+    assert all(s["c0"] <= s["c1"] for s in RI.spans_snapshot())
+
+
+async def _transport(node_id, addr_map, inbox):
+    from gigapaxos_tpu.net.transport import Transport
+    from gigapaxos_tpu.paxos import packets as pk
+    t = Transport(node_id, ("127.0.0.1", 0), addr_map,
+                  on_frame=lambda f: inbox.append(pk.decode(f)))
+    await t.start()
+    return t
+
+
+def test_the_event_loop_spans_its_reads_and_writes():
+    """A loopback pair on an event loop of its own thread: every read and
+    every write another thread hands the loop is a ``gp.net.*`` span on
+    the loop's thread, and the reads' frames and bytes are the receiver's
+    counters."""
+    import asyncio
+
+    from gigapaxos_tpu.paxos import packets as pk
+    RI.enabled = True
+    loop = asyncio.new_event_loop()
+    th = threading.Thread(target=loop.run_forever, daemon=True)
+    th.start()
+
+    def run(coro):
+        return asyncio.run_coroutine_threadsafe(coro, loop).result(5)
+    in0, in1 = [], []
+    try:
+        t0 = run(_transport(0, {}, in0))
+        t1 = run(_transport(1, {0: ("127.0.0.1", t0.port)}, in1))
+        t0.addr_map[1] = ("127.0.0.1", t1.port)
+        frames = [pk.Prepare(1, k, k).encode() for k in range(40)]
+        t1.send_many_threadsafe([(0, f, False, 1) for f in frames[:30]])
+        for f in frames[30:]:
+            t1.send_threadsafe(0, f)
+        t0.send_threadsafe(1, pk.FailureDetect(0, 0, 42).encode())
+        deadline = time.monotonic() + 5
+        while (len(in0), len(in1)) != (40, 1):
+            assert time.monotonic() < deadline, (len(in0), len(in1))
+            time.sleep(0.005)
+        run(t0.stop())
+        run(t1.stop())
+    finally:
+        loop.call_soon_threadsafe(loop.stop)
+        th.join(5)
+    assert not th.is_alive()
+    net = [s for s in RI.spans_snapshot() if s["kind"].startswith("net.")]
+    assert {s["tid"] for s in net} == {th.ident}
+    assert all(s["c0"] <= s["c1"] for s in net)
+
+    def total(kind, node, attr):
+        return sum(s[attr] for s in net
+                   if s["kind"] == kind and s["node"] == node)
+    assert total("net.read", 0, "frames") == t0.rcvd_frames == 40
+    assert total("net.read", 0, "bytes") == t0.rcvd_bytes > 0
+    assert total("net.read", 1, "frames") == t1.rcvd_frames == 1
+    assert total("net.read", 1, "bytes") == t1.rcvd_bytes
+    assert total("net.write", 1, "frames") == 40
+    assert total("net.write", 1, "bytes") == sum(len(f) for f in frames)
+    assert total("net.write", 0, "frames") == 1
+
+
+@pytest.mark.smoke
 def test_queue_wait_pairs_puts_with_gets_under_two_putting_threads():
     q = _StampedQueue()
     n = 2000
@@ -174,6 +287,7 @@ def test_a_profiler_session_turns_the_spans_on(tmp_path):
         # followers execute behind the acknowledgement: let them end, so
         # that no span opens under a w.process begun before the session
         time.sleep(0.3)
+        before = DelayProfiler.totals()
         trace_dir = str(tmp_path / "trace")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
@@ -185,6 +299,7 @@ def test_a_profiler_session_turns_the_spans_on(tmp_path):
             time.sleep(0.1)  # only waits and ticks are in flight now
         finally:
             jax.profiler.stop_trace()
+        t_stop, after = time.monotonic(), DelayProfiler.totals()
         assert res["ok"] == 300
     finally:
         emu.stop()
@@ -215,12 +330,14 @@ def test_a_profiler_session_turns_the_spans_on(tmp_path):
 
     # the same spans as events of the profile, by kind; a wait or a tick
     # in flight when the session stopped ends after it, so the profile
-    # lacks at most one per worker thread
+    # lacks at most one per worker thread (and a loop's read of a ping
+    # at most one per loop)
     events = _events(trace_dir)
     ring = collections.Counter("gp." + s["kind"] for s in spans)
     assert set(events) == set(ring)
     for name, n in ring.items():
-        slack = 3 if name in ("gp.w.wait", "gp.w.tick") else 0
+        slack = 3 if name in ("gp.w.wait", "gp.w.tick", "gp.net.read",
+                              "gp.net.write") else 0
         assert 0 <= n - len(events[name]) <= slack, (name, n)
     sub = events["gp.eng.submit"][0]
     assert {"node", "wave", "n", "kernel", "lanes", "bucket", "chunks",
@@ -238,14 +355,19 @@ def test_a_profiler_session_turns_the_spans_on(tmp_path):
         k = s["kind"]
         if k in TOP:
             assert s["parent"] == 0, s
-        elif k in ("eng.submit", "eng.collect", "wal", "w.emit"):
+        elif k in ("eng.submit", "eng.collect", "wal", "w.emit") or \
+                _handler(k):
             assert s["parent"], s
             p = by_id[s["parent"]]
-            assert p["kind"] == "w.process", (s, p)
+            while p["kind"] != "w.process":  # through the handler spans
+                assert _handler(p["kind"]) or p["kind"] == "w.post", (s, p)
+                p = by_id[p["parent"]]
             assert (p["wave"], p["tid"]) == (s["wave"], s["tid"])
             assert p["t0"] <= s["t0"] and s["t1"] <= p["t1"]
         elif k == "eng.pack":
             assert by_id[s["parent"]]["kind"] == "eng.submit"
+        elif k in ("w.pre", "w.post"):
+            assert by_id[s["parent"]]["kind"] == "w.node_wave", s
         elif k == "wal.fsync":
             # under the inline append; the logger's own writer thread
             # (checkpoints, creates) syncs under no span
@@ -256,6 +378,29 @@ def test_a_profiler_session_turns_the_spans_on(tmp_path):
             assert s["lanes"] <= s["launched"]
         if k == "w.wait":
             assert s["wave"] == 0
+
+    # every record has its thread's CPU clock; the fused handler holds its
+    # pre and post halves between which the wave's submit and collect sit
+    assert all(s["c0"] <= s["c1"] for s in spans)
+    # (a batch whose every frame the pre halves drop launches nothing)
+    assert kinds["w.node_wave"] == kinds["w.pre"] >= kinds["w.post"] > 0
+    for s in spans:
+        if s["kind"] in ("eng.submit", "eng.collect") and \
+                by_id[s["parent"]]["kind"] == "w.node_wave":
+            pre = [c for c in spans if c["parent"] == s["parent"]
+                   and c["kind"] == "w.pre"][0]
+            post = [c for c in spans if c["parent"] == s["parent"]
+                    and c["kind"] == "w.post"][0]
+            assert pre["t1"] <= s["t0"] and s["t1"] <= post["t0"]
+    # the handlers' sums count what their spans count: a call a record,
+    # the items their n
+    done = [s for s in spans if _handler(s["kind"]) and s["t1"] <= t_stop]
+    assert {s["kind"] for s in done} >= {"w.node_wave"}
+    for kind in {s["kind"] for s in done}:
+        mine = [s for s in done if s["kind"] == kind]
+        b = before.get(kind, (0.0, 0, 0))
+        assert after[kind][1] - b[1] == len(mine), kind
+        assert after[kind][2] - b[2] == sum(s["n"] for s in mine), kind
 
     # the five top-level spans tile each worker thread's time
     for tid in workers:
