@@ -45,6 +45,15 @@ along; the bodies then derive ranks, counts and maxima per group from
 dense scans over the run structure instead of sorting and scattering for
 them.  Called without it — the packed wrappers, the mesh table, recovery —
 a body is exactly what it was.
+
+Group view (the optional argument ``view`` after ``runs``): a composition
+with a lane order may read each replica's group table ONCE
+(:func:`group_view`) and hand the bodies the ``[B]`` columns they read
+instead of a row read a stage call.  A body given a view reads those
+columns from it, sets the table as it would without one, and returns the
+view as a third value with each column it set as the table now holds it,
+on every lane of the group's run.  Called without one, a body reads the
+table and returns two values, as before.
 """
 
 from __future__ import annotations
@@ -149,6 +158,12 @@ def _run_count(runs: LaneRuns, mask, reverse=False):
     return cs - jax.lax.cummax(jnp.where(first, cs - n, 0), reverse=reverse)
 
 
+def _run_total(runs: LaneRuns, mask):
+    """Lanes of ``mask`` in a lane's whole run, on every lane of it."""
+    return (_run_count(runs, mask) + _run_count(runs, mask, reverse=True)
+            - mask.astype(i32))
+
+
 def _run_last(runs: LaneRuns, mask):
     """The last lane of ``mask`` in each run: the ONE lane that writes the
     run's combine into the group table."""
@@ -250,6 +265,25 @@ def _group_words(grp, gi):
     return col
 
 
+def group_view(state: ColumnarState, gi, fields):
+    """A replica's view for a composition that hands every stage its lanes
+    in one order (see the module's note): ``{f: [B]}`` for each of
+    ``fields``, from one row read of the table at the order's ``gi``.  It
+    is exact on the order's valid lanes; an invalid lane's words are
+    padding, as its outputs are."""
+    col = _group_words(state.grp, gi)
+    return {f: col(f) for f in fields}
+
+
+def _stage_cols(grp, gi, runs, view):
+    """A stage's ``col``: the handed-on view where the caller gives one,
+    else ONE row read of the table (:func:`_group_words`)."""
+    if view is None:
+        return _group_words(grp, gi)
+    assert runs is not None, "a group view comes with a lane order"
+    return view.__getitem__
+
+
 def _word(rows, f):
     """The flat table index of field ``f`` of ``rows`` (out of bounds for
     a row that is)."""
@@ -281,10 +315,11 @@ class AcceptOut(NamedTuple):
 
 
 def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
-                 runs: Optional[LaneRuns] = None):
+                 runs: Optional[LaneRuns] = None,
+                 view: Optional[dict] = None):
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
-    col = _group_words(state.grp, gi)
+    col = _stage_cols(state.grp, gi, runs, view)
     act = col("active")
     live = valid & act  # inactive rows must not be mutated at all
 
@@ -321,7 +356,11 @@ def accept_batch(state: ColumnarState, g, slot, bal, rlo, rhi, valid,
         cur_bal=cur_bal,
     )
     state = state._replace(grp=grp, **acc)
-    return state, out
+    if view is None:
+        return state, out
+    # the run's promise, written from its last live lane; a run with none
+    # keeps its promise, which is what the max with NO_BALLOT leaves
+    return state, out, dict(view, bal=cur_bal)
 
 
 # --------------------------------------------------------------------------
@@ -339,7 +378,8 @@ class AcceptReplyOut(NamedTuple):
 
 
 def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
-                       valid, runs: Optional[LaneRuns] = None):
+                       valid, runs: Optional[LaneRuns] = None,
+                       view: Optional[dict] = None):
     """Handle (batched) accept replies.
 
     ``bal`` carries the accepted ballot on ack lanes and the acceptor's
@@ -360,7 +400,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     w = jnp.where(valid, slot % W, 0)
     idx = gi * W + w
 
-    col = _group_words(state.grp, gi)
+    col = _stage_cols(state.grp, gi, runs, view)
     is_coord, cbal = col("is_coord"), col("cbal")
     coord_here = is_coord & col("coord_active")
     is_rel = valid & coord_here & (bal == cbal)
@@ -418,11 +458,22 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
     # serial per-lane loop — a scatter per reply wave for an
     # almost-always-empty mask was ~8% of the storm step.
     pre = valid & is_coord & ~acked & (bal > cbal)
-    grp = jax.lax.cond(
-        pre.any(),
-        lambda t: _set_cols(t, _si(g, pre, G), is_coord=False,
-                            coord_active=False),
-        lambda t: t, state.grp)
+
+    def resign(t):
+        return _set_cols(t, _si(g, pre, G), is_coord=False,
+                         coord_active=False)
+
+    if view is None:
+        grp = jax.lax.cond(pre.any(), resign, lambda t: t, state.grp)
+    else:
+        def resign_viewed(t, is_c, c_act):  # a run with a resigner resigns
+            out = _run_total(runs, pre) > 0
+            return resign(t), is_c & ~out, c_act & ~out
+
+        grp, is_c, c_act = jax.lax.cond(
+            pre.any(), resign_viewed, lambda *a: a, state.grp,
+            view["is_coord"], view["coord_active"])
+        view = dict(view, is_coord=is_c, coord_active=c_act)
     # the branch's result stays the table's one value: without the barrier
     # the v5e's compiler moves the next stage's view of the table into
     # the branches and then copies the 64 MB plane twice a reply
@@ -438,7 +489,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         req_hi=state.prop_rhi[idx],
     )
     state = state._replace(prop_votes=votes, grp=grp)
-    return state, out
+    return (state, out) if view is None else (state, out, view)
 
 
 # --------------------------------------------------------------------------
@@ -455,7 +506,8 @@ class ProposeOut(NamedTuple):
 
 
 def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
-                  runs: Optional[LaneRuns] = None):
+                  runs: Optional[LaneRuns] = None,
+                  view: Optional[dict] = None):
     """Assign contiguous slots to new requests, multiple per group per batch.
 
     Lane i's slot is ``next_slot[g] + rank_i`` where rank is the lane's
@@ -467,7 +519,7 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
     B = g.shape[0]
     gi = _gi(g, valid if runs is None else runs.valid)
 
-    col = _group_words(state.grp, gi)
+    col = _stage_cols(state.grp, gi, runs, view)
     act = col("active")
     coord_here = col("is_coord") & col("coord_active")
     can = valid & act & coord_here
@@ -507,7 +559,12 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
         cbal=col("cbal"),
     )
     state = state._replace(grp=grp, **prop)
-    return state, out
+    if view is None:
+        return state, out
+    # the granted lanes take ranks 0.. of their run: the last one wrote
+    # next_slot plus their count
+    return state, out, dict(
+        view, next_slot=col("next_slot") + _run_total(runs, granted))
 
 
 # --------------------------------------------------------------------------
@@ -523,10 +580,11 @@ class CommitOut(NamedTuple):
 
 
 def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
-                 runs: Optional[LaneRuns] = None):
+                 runs: Optional[LaneRuns] = None,
+                 view: Optional[dict] = None):
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
-    col = _group_words(state.grp, gi)
+    col = _stage_cols(state.grp, gi, runs, view)
     act, cursor = col("active"), col("exec_cursor")
 
     stale = valid & act & (slot < cursor)
@@ -557,10 +615,15 @@ def commit_batch(state: ColumnarState, g, slot, rlo, rhi, valid,
         applied=store,
         stale=stale,
         out_window=valid & act & (slot >= cursor + W),
-        new_cursor=_group_words(grp, gi)("exec_cursor"),
+        # the run's frontier where it stored a lane, as written
+        new_cursor=(_group_words(grp, gi)("exec_cursor") if view is None
+                    else jnp.where(_run_total(runs, store) > 0, new_cur,
+                                   cursor)),
     )
     state = state._replace(grp=grp, **dec)
-    return state, out
+    if view is None:
+        return state, out
+    return state, out, dict(view, exec_cursor=out.new_cursor)
 
 
 # --------------------------------------------------------------------------

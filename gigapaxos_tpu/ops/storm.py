@@ -29,6 +29,12 @@ from gigapaxos_tpu.ops.types import ColumnarState
 
 i32 = jnp.int32
 
+# the columns each replica's stages read: the coordinator's propose, accept,
+# accept-replies and commit; an acceptor's accept and commit
+COORDINATOR_COLS = ("active", "is_coord", "coord_active", "cbal",
+                    "next_slot", "exec_cursor", "bal", "members")
+ACCEPTOR_COLS = ("active", "bal", "exec_cursor")
+
 
 def decide_storm_step(states: Tuple[ColumnarState, ...], g, rlo, rhi,
                       valid):
@@ -46,31 +52,33 @@ def decide_storm_step(states: Tuple[ColumnarState, ...], g, rlo, rhi,
     # one window, which is what `distinct_slots` vouches for.
     runs, g, rlo, rhi = kernels.lane_runs(g, valid, rlo, rhi,
                                           distinct_slots=True)
-    s0 = states[0]
-    s0, pr = kernels.propose_batch(s0, g, rlo, rhi, runs.valid, runs)
+    # ONE row read of each replica's group table a step: the stages read
+    # their groups' columns from the view and hand it on updated
+    views = [kernels.group_view(s, g, COORDINATOR_COLS if r == 0
+                                else ACCEPTOR_COLS)
+             for r, s in enumerate(states)]
+    new_states = list(states)
+    new_states[0], pr, views[0] = kernels.propose_batch(
+        new_states[0], g, rlo, rhi, runs.valid, runs, views[0])
     slot, bal, granted = pr.slot, pr.cbal, pr.granted
 
     acks = []
-    new_states = [s0] + list(states[1:])
     for r in range(R):
-        sr, ar = kernels.accept_batch(new_states[r], g, slot, bal, rlo,
-                                      rhi, granted, runs)
-        new_states[r] = sr
+        new_states[r], ar, views[r] = kernels.accept_batch(
+            new_states[r], g, slot, bal, rlo, rhi, granted, runs, views[r])
         acks.append(ar.acked)
 
     newly = jnp.zeros_like(granted)
     for r in range(R):
         sender = jnp.full_like(g, r)
-        s0 = new_states[0]
-        s0, rr = kernels.accept_reply_batch(s0, g, slot, bal, sender,
-                                            acks[r], granted, runs)
-        new_states[0] = s0
+        new_states[0], rr, views[0] = kernels.accept_reply_batch(
+            new_states[0], g, slot, bal, sender, acks[r], granted, runs,
+            views[0])
         newly = newly | rr.newly_decided
 
     for r in range(R):
-        sr, _cr = kernels.commit_batch(new_states[r], g, slot, rlo, rhi,
-                                       newly, runs)
-        new_states[r] = sr
+        new_states[r], _cr, views[r] = kernels.commit_batch(
+            new_states[r], g, slot, rlo, rhi, newly, runs, views[r])
 
     return tuple(new_states), jnp.sum(newly.astype(i32))
 

@@ -182,8 +182,9 @@ def _no_relayout(name, bucket=BUCKET, stagings=0):
     ``prop_votes``, and since PR 37 the group table, which every stage
     reads and most set: the row reads of a table it holds there cost a
     third); the counts are what it chooses today, the resign branches'
-    included.  At the lane counts the served cells run (8-1,024) it
-    stages none."""
+    included (the storm step's 12 became 7 when its stages read each
+    table once a step, PR 39).  At the lane counts the served cells run
+    (8-1,024) it stages none."""
     def run(topo, compiled):
         c, words = compiled(name, bucket), CAPACITY * WINDOW
         moved = _plane_moves(c, words, _RELAYOUTS)
@@ -211,6 +212,23 @@ def _no_group_array(name):
                    for dims in _SHAPE.findall(line)):
                 found.append(line.strip()[:200])
         assert not found, "\n".join(found)
+    return run
+
+
+def _row_reads(name, most):
+    """The program's row reads of 128 words a lane at the storm's size
+    (``s32[262144,128]`` gathers, inside fusions or out): ONE of each
+    replica's group table a step, whose columns the stages hand on as a
+    view, and the commits' three ``dec`` window reads, 6.  13 before
+    PR 39 (ten of the table, a stage call each, and the three ``dec``;
+    the jaxpr's count, and the compiled step's), at 2.63 ms each from
+    HBM (PERF.md §5.3)."""
+    def run(topo, compiled):
+        reads = [line.strip()[:160]
+                 for line in compiled(name).as_text().splitlines()
+                 if re.search(rf"= s32\[{STORM_LANES},128\]\S* gather\(",
+                              line)]
+        assert 0 < len(reads) <= most, "\n".join(reads)
     return run
 
 
@@ -264,11 +282,12 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
          **{f"no_plane_relayout.serving.{n}.bucket64": _no_relayout(n, 64)
             for n in ("request_reply_p", "accept_commit_p", "node_wave_p")},
          "no_plane_relayout.storm.decide_storm_step": _no_relayout(
-             "storm", stagings=12),
+             "storm", stagings=7),
          # the groups' scalars are one word table (PR 37)
          **{f"no_group_array.serving.{n}": _no_group_array(n)
             for n in SERVING},
          "no_group_array.storm.decide_storm_step": _no_group_array("storm"),
+         "storm_row_reads.storm.decide_storm_step": _row_reads("storm", 6),
          "state.twelve_leaves": _twelve_leaves,
          "mesh.accept_p": _mesh("accept_p"),
          "mesh.accept_commit_p": _mesh("accept_commit_p"),
@@ -278,6 +297,47 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_v5e(case, topo, compiled):
     CASES[case](topo, compiled)
+
+
+# row reads of the group table in each serving program's jaxpr, as they
+# were before PR 39 handed the storm's stages a view: a program that
+# passes no lane order reads the table a stage call, and still does
+SERVING_TABLE_READS = {
+    "propose_p": 1, "accept_p": 2, "accept_reply_p": 1, "commit_p": 2,
+    "propose_accept_self_p": 4, "accept_reply_commit_self_p": 3,
+    "accept_commit_p": 4, "request_reply_p": 7, "node_wave_p": 11,
+}
+
+
+def _gathers_of(jaxpr, words):
+    """Gathers of an operand of ``words`` elements, sub-jaxprs included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "gather" and \
+            eqn.invars[0].aval.size == words
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _gathers_of(sub, words)
+    return n
+
+
+@pytest.mark.parametrize("name", sorted(SERVING))
+def test_serving_reads_the_group_table_as_before(name):
+    """The serving programs pass no lane order, so no view: each stage
+    call of theirs reads the table once, as before PR 39 (the packed
+    bodies' jaxprs are the parent's).  Traced at a small size: counts
+    only, no chip."""
+    from gigapaxos_tpu.ops import kernels
+    from gigapaxos_tpu.ops.types import GROUP_WORDS, make_state
+    G, bucket = 64, 16
+    st = jax.eval_shape(lambda: make_state(G, 8))
+    jaxpr = jax.make_jaxpr(getattr(kernels, name))(
+        st, *[jax.ShapeDtypeStruct((k, bucket), jnp.int32)
+              for k in SERVING[name]])
+    assert _gathers_of(jaxpr.jaxpr, G * GROUP_WORDS) == \
+        SERVING_TABLE_READS[name]
 
 
 def test_boot_loads_node_wave_at_every_bucket():

@@ -42,30 +42,77 @@ def test_storm_duplicate_groups_in_batch():
                                   np.full(G, 2))
 
 
-def _reference_step(states, g, rlo, rhi, valid):
-    """The step as it was before the lanes were put in group order: the
-    same ten stages, each body called without an order, every lane where
-    the caller put it.  The reference the ordered step must equal."""
+def _composition(states, g, rlo, rhi, valid, ordered=False,
+                 nack_promise=False):
+    """The step's ten stages.  Unordered, the reference the storm step
+    must equal: the step as it was before the lanes were put in group
+    order, each body called without an order or a view, every lane where
+    the caller put it.  Ordered, the storm step as it runs them: one lane
+    order, each replica's group view read once and handed from stage to
+    stage; it also answers with the valid lanes of the order and, after
+    every stage, each column of the stage's view beside the same column
+    read from the table the stage left.  ``nack_promise``: an acceptor
+    answers a nack with its promise, as a served node does, so a nack
+    above the coordinator's ballot resigns it (the storm's replies carry
+    the coordinator's own ballot, which cannot)."""
     import jax.numpy as jnp
     from gigapaxos_tpu.ops import kernels
+    from gigapaxos_tpu.ops.storm import ACCEPTOR_COLS, COORDINATOR_COLS
 
     R = len(states)
     new = list(states)
-    new[0], pr = kernels.propose_batch(new[0], g, rlo, rhi, valid)
+    seen = []
+    if ordered:
+        runs, g, rlo, rhi = kernels.lane_runs(g, valid, rlo, rhi,
+                                              distinct_slots=True)
+        valid = runs.valid
+        views = [kernels.group_view(s, g, COORDINATOR_COLS if r == 0
+                                    else ACCEPTOR_COLS)
+                 for r, s in enumerate(states)]
+
+    def stage(r, body, *lanes):
+        if not ordered:
+            new[r], out = body(new[r], *lanes)
+            return out
+        new[r], out, views[r] = body(new[r], *lanes, runs, views[r])
+        table = kernels._group_words(new[r].grp, g)
+        seen.append({f: (v, table(f)) for f, v in views[r].items()})
+        return out
+
+    pr = stage(0, kernels.propose_batch, g, rlo, rhi, valid)
     slot, bal, granted = pr.slot, pr.cbal, pr.granted
-    acks = []
-    for r in range(R):
-        new[r], ar = kernels.accept_batch(new[r], g, slot, bal, rlo, rhi,
-                                          granted)
-        acks.append(ar.acked)
+    acks = [stage(r, kernels.accept_batch, g, slot, bal, rlo, rhi, granted)
+            for r in range(R)]
     newly = jnp.zeros_like(granted)
-    for r in range(R):
-        new[0], rr = kernels.accept_reply_batch(
-            new[0], g, slot, bal, jnp.full_like(g, r), acks[r], granted)
+    for r, ar in enumerate(acks):
+        reply = jnp.where(ar.acked, bal, ar.cur_bal) if nack_promise else bal
+        rr = stage(0, kernels.accept_reply_batch, g, slot, reply,
+                   jnp.full_like(g, r), ar.acked, granted)
         newly = newly | rr.newly_decided
     for r in range(R):
-        new[r], _ = kernels.commit_batch(new[r], g, slot, rlo, rhi, newly)
-    return tuple(new), jnp.sum(newly.astype(jnp.int32))
+        stage(r, kernels.commit_batch, g, slot, rlo, rhi, newly)
+    return tuple(new), jnp.sum(newly.astype(jnp.int32)), (valid, seen)
+
+
+def _assert_views_are_the_tables(valid, seen, where):
+    """Every column of every stage's view is what the table holds for the
+    lane's group, on the order's valid lanes."""
+    v = np.asarray(valid)
+    assert len(seen) == 1 + 3 + 3 + 3  # stages of a three-replica step
+    for k, cols in enumerate(seen):
+        for f, (view, table) in cols.items():
+            np.testing.assert_array_equal(
+                np.asarray(view)[v], np.asarray(table)[v],
+                err_msg=f"{where}: stage {k}, {f}")
+
+
+def _assert_same_fleet(got, want, where):
+    for r in range(len(want)):
+        for f in want[r]._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got[r], f)),
+                np.asarray(getattr(want[r], f)),
+                err_msg=f"{where}, replica {r}, {f}")
 
 
 # name: (G, W, B, groups the lanes fall on, share of valid lanes,
@@ -86,14 +133,20 @@ def test_ordered_step_equals_the_unordered_composition(case):
     order leaves every field of every replica, and the decided count, as
     the unordered bodies leave them: same slot per lane (lane order within
     the window), same promises, same planes — over several steps, so
-    windows fill, throttle and wrap."""
+    windows fill, throttle and wrap.  The views the step hands on are
+    the tables: after every stage, each column of each replica's view is
+    what a row read of its table would give, the step's last views
+    included."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     from gigapaxos_tpu.ops.storm import make_fleet, storm
 
     G, W, B, hit, p_valid, hot = ORDER_CASES[case]
     rng = np.random.default_rng(sorted(ORDER_CASES).index(case))
-    reference = jax.jit(_reference_step)
+    reference = jax.jit(_composition)
+    viewed = jax.jit(functools.partial(_composition, ordered=True))
     want, got = make_fleet(G, W, R=3), make_fleet(G, W, R=3)
     total = 0
     for it in range(5):
@@ -103,17 +156,97 @@ def test_ordered_step_equals_the_unordered_composition(case):
             g, rng.integers(1, 1 << 30, B, dtype=np.int32),
             rng.integers(1, 1 << 30, B, dtype=np.int32),
             rng.random(B) < p_valid)]
-        want, n_want = reference(want, *lanes)
+        # the views of the step the storm takes, checked on a copy
+        mirror, n_mirror, (v, seen) = viewed(got, *lanes)
+        _assert_views_are_the_tables(v, seen, f"{case}: step {it}")
+        want, n_want, _ = reference(want, *lanes)
         got, n_got = storm(got, *lanes)
-        assert int(n_got) == int(n_want), (case, it)
+        assert int(n_got) == int(n_want) == int(n_mirror), (case, it)
         total += int(n_got)
-        for r in range(3):
-            for f in want[r]._fields:
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(got[r], f)),
-                    np.asarray(getattr(want[r], f)),
-                    err_msg=f"{case}: step {it}, replica {r}, {f}")
+        _assert_same_fleet(got, want, f"{case}: step {it}")
+        _assert_same_fleet(mirror, want, f"{case}: step {it}, viewed")
     assert (total > 0) == (p_valid > 0)
+
+
+@pytest.mark.parametrize("nack_promise", [False, True],
+                         ids=["coordinator_ballot", "promise_on_nack"])
+def test_views_under_preemption(nack_promise):
+    """Replicas 1 and 2 promise a higher ballot than the coordinator's on
+    two overlapping sets of groups before the first step, so their
+    accepts nack there and the quorum is lost where both took a group;
+    on a fifth of the groups the coordinator runs at a ballot above the
+    promise of an acceptor that took nothing, whose accepts raise its
+    promise within the step, so its view's ``bal`` moves.  With the acceptor's promise on a nack, reply 1
+    resigns the coordinator of its groups and reply 2, which acks the
+    groups that only replica 1 took, must find ``is_coord`` cleared in
+    the view: a stale view counts that vote and decides.  Every field of
+    every replica and the decided count equal the unordered bodies',
+    over several steps; every stage's view equals its table."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops import kernels
+    from gigapaxos_tpu.ops.storm import make_fleet, storm
+    from gigapaxos_tpu.ops.types import pack_ballot
+
+    G, W, B = 64, 8, 96
+    rng = np.random.default_rng(39)
+    groups = np.arange(G, dtype=np.int32)
+    took = {1: groups % 3 == 0, 2: groups % 2 == 0}
+
+    def promised(states):
+        states = list(states)
+        # the coordinator of a fifth of the groups at ballot (1, 0), which
+        # the acceptors that promised nothing higher take on its accepts
+        states[0], _ = kernels.install_coordinator(
+            states[0], jnp.asarray(groups),
+            jnp.full((G,), pack_ballot(1, 0), jnp.int32),
+            jnp.zeros((G,), jnp.int32), *[jnp.full((G, W), -1, jnp.int32)] * 3,
+            jnp.asarray(groups % 5 == 0))
+        for r, mask in took.items():
+            states[r], po = kernels.prepare(
+                states[r], jnp.asarray(groups),
+                jnp.full((G,), pack_ballot(1, r), jnp.int32),
+                jnp.asarray(mask))
+            assert np.asarray(po.acked).sum() == mask.sum()
+        return tuple(states)
+
+    step = functools.partial(_composition, nack_promise=nack_promise)
+    reference = jax.jit(step)
+    viewed = jax.jit(functools.partial(step, ordered=True))
+    want = promised(make_fleet(G, W, R=3))
+    got = promised(make_fleet(G, W, R=3))
+    total = 0
+    for it in range(4):
+        lanes = [jnp.asarray(a) for a in (
+            rng.integers(0, G, B).astype(np.int32),
+            rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.random(B) < 0.9)]
+        want, n_want, _ = reference(want, *lanes)
+        if nack_promise:
+            got, n_got, (v, seen) = viewed(got, *lanes)
+        else:
+            _m, _n, (v, seen) = viewed(got, *lanes)
+            got, n_got = storm(got, *lanes)
+        _assert_views_are_the_tables(v, seen, f"step {it}")
+        assert int(n_got) == int(n_want), it
+        total += int(n_got)
+        _assert_same_fleet(got, want, f"step {it}")
+    assert 0 < total
+    coord, cursor = (np.asarray(f) for f in (got[0].is_coord,
+                                             got[0].exec_cursor))
+    both, only1 = took[1] & took[2], took[1] & ~took[2]
+    assert (cursor[both] == 0).all()  # two nacks of three: no quorum
+    # with a promise on the nack, reply 1 resigns the groups only replica
+    # 1 took before reply 2's ack can count; without one they decide
+    assert coord[only1].all() != nack_promise
+    assert (cursor[only1] == 0).all() == nack_promise
+    assert coord[~(took[1] | took[2])].all()
+    raised = (groups % 5 == 0) & ~took[1] & (cursor > 0)
+    assert raised.any()
+    assert (np.asarray(got[1].bal)[raised] == pack_ballot(1, 0)).all()
 
 
 def _count_primitives(jaxpr, out, under_cond=False):
@@ -139,10 +272,15 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     columns of the group table each) sit under a cond.  Every one that
     always runs states that its indices are unique, and writes the group
     table ``[G * 16]`` or a linear ``[G * W]`` component plane: there is
-    no ``[G]`` array.  The table is read ONCE a stage call, 1 + 3 R row
-    gathers (and once more for the cursor a commit answers with, which
-    the step drops, and the compiler the read with it); no gather reads a
-    lane-shaped operand and no scan runs along a window row."""
+    no ``[G]`` array.  The table is read ONCE a replica a step, R row
+    gathers where each stage call read it (1 + 3 R, and R more for the
+    cursor a commit answered with): the coordinator's view carries
+    ``active``, ``is_coord``, ``coord_active``, ``cbal``, ``next_slot``,
+    ``exec_cursor``, ``bal`` and ``members`` through its propose, accept,
+    replies and commit; an acceptor's carries ``active``, ``bal`` and
+    ``exec_cursor`` through its accept and commit; each stage sets what
+    it writes in the view too.  No gather reads a lane-shaped operand and
+    no scan runs along a window row."""
     import jax
     import jax.numpy as jnp
     from gigapaxos_tpu.ops.storm import decide_storm_step
@@ -174,8 +312,7 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     assert gathers
     for e in gathers:  # the plane may be seen as rows of 128 words
         assert e.invars[0].aval.size in (table, G * W), e
-    assert sum(e.invars[0].aval.size == table
-               for e in gathers) == 1 + 3 * R + R
+    assert sum(e.invars[0].aval.size == table for e in gathers) == R
     for n, _, e in eqns:
         if n.startswith("cum"):
             assert e.invars[0].aval.shape == (B,), e
