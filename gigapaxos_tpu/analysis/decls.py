@@ -410,6 +410,14 @@ def project_decls() -> Decls:
                 "the exchange's timers read _now())",
             "PaxosNode._frontier_end::monotonic":
                 "length of the rec.catchup profiler span (metrics only)",
+            "PaxosNode._ri_arrive::monotonic":
+                "a read's arrival for the ri.wait total (metrics only; "
+                "its timeout reads _now())",
+            "PaxosNode._ri_answer::monotonic":
+                "the ri.wait / ri.exec_wait totals' end (metrics only)",
+            "PendingReads.on_reply::monotonic":
+                "a read's confirmation for the ri.exec_wait total "
+                "(metrics only)",
             "_Rate.*":
                 "DelayProfiler's internal rate window — the "
                 "measurement plane's own clock",

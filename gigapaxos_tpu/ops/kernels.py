@@ -671,6 +671,55 @@ def prepare_batch(state: ColumnarState, g, bal, valid):
 
 
 # --------------------------------------------------------------------------
+# read index (reads without a round)         ref: Raft ReadIndex (thesis 6.4)
+# --------------------------------------------------------------------------
+
+
+class ReadIndexOut(NamedTuple):
+    ok: jnp.ndarray         # bool[B] the row leads at an undisputed ballot
+    cbal: jnp.ndarray       # i32[B]  the ballot peers are asked to confirm
+    next_slot: jnp.ndarray  # i32[B]  the read's index: every slot below it
+
+
+def read_index_batch(state: ColumnarState, g, valid):
+    """The coordinator's half of a read: from ONE row read of the group
+    table a lane, whether its node may answer the read (the row is live,
+    this node coordinates it with phase 1 done, and its own acceptor
+    promised exactly the coordinator's ballot), that ballot, and the
+    read's index, ``next_slot``.  Reads only: no field changes."""
+    col = _group_words(state.grp, _gi(g, valid))
+    cbal = col("cbal")
+    ok = valid & col("active") & col("is_coord") & col("coord_active") \
+        & (col("bal") == cbal)
+    return ReadIndexOut(ok=ok, cbal=cbal, next_slot=col("next_slot"))
+
+
+def confirm_batch(state: ColumnarState, g, bal, valid):
+    """An acceptor's half: ``bool[B]``, the row is live and its promise
+    is exactly the ballot asked about (none above it, and the asker's
+    own).  Reads only."""
+    col = _group_words(state.grp, _gi(g, valid))
+    return valid & col("active") & (col("bal") == bal)
+
+
+def read_index_packed(state: ColumnarState, packed):
+    """packed[2, B]: g, valid -> out[3, B]: ok, cbal, next_slot.  The
+    state goes back unchanged, donated as every packed program's is: a
+    state the program only reads, the compiler copies whole into its
+    fast memory at every bucket, where one it hands back it reads in
+    place."""
+    o = read_index_batch(state, packed[0], packed[1] != 0)
+    return state, jnp.stack([o.ok.astype(i32), o.cbal, o.next_slot])
+
+
+def confirm_packed(state: ColumnarState, packed):
+    """packed[3, B]: g, bal, valid -> out[1, B]: ok.  The state goes back
+    unchanged, as :func:`read_index_packed`'s."""
+    return state, confirm_batch(state, packed[0], packed[1],
+                                packed[2] != 0).astype(i32)[None]
+
+
+# --------------------------------------------------------------------------
 # coordinator install (phase-1 completion + carryover)
 # --------------------------------------------------------------------------
 
@@ -1005,6 +1054,8 @@ commit_p = _jit("commit_p", commit_packed)
 accept_commit_p = _jit("accept_commit_p", accept_commit_packed)
 request_reply_p = _jit("request_reply_p", request_reply_packed)
 node_wave_p = _jit("node_wave_p", node_wave_packed)
+read_index_p = _jit("read_index_p", read_index_packed)
+confirm_p = _jit("confirm_p", confirm_packed)
 prepare = _jit("prepare", prepare_batch)
 install_coordinator = _jit("install_coordinator",
                            install_coordinator_batch)

@@ -181,6 +181,11 @@ class MeshKernels:
             (sh, rp, rp))
         # one worker batch's four sections in one program
         self.node_wave_p = jit1("node_wave_p", _wave_local, 1, (sh, rp))
+        # the read index's two: the state handed back as it came
+        self.read_index_p = jit1(
+            "read_index_p", _packed1(_K.read_index_packed), 1, (sh, rp))
+        self.confirm_p = jit1(
+            "confirm_p", _packed1(_K.confirm_packed), 1, (sh, rp))
         # unpacked cold/control ops
         self.prepare = jit1("prepare", _prepare_local, 3, (sh, rp))
         self._install = jit1(

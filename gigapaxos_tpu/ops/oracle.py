@@ -129,6 +129,21 @@ class OracleGroup:
             return True, False, self.prop_req[slot]
         return False, False, None
 
+    # -- read index (reads without a round) ---------------------------------
+
+    def read_index(self) -> Tuple[bool, int, int]:
+        """-> (ok, cbal, next_slot): whether a read may be answered here
+        (this node leads the group, phase 1 done, and its own acceptor
+        promised exactly the coordinator's ballot), the ballot its peers
+        are asked to confirm, and the read's index."""
+        ok = self.is_coord and self.coord_active and self.bal == self.cbal
+        return ok, self.cbal, self.next_slot
+
+    def confirm(self, bal: int) -> bool:
+        """An acceptor's answer to a coordinator's confirmation: it has
+        promised ``bal`` and nothing above it."""
+        return self.bal == bal
+
     def install_coordinator(self, cbal: int, next_slot: int,
                             carryover: List[PValue]) -> None:
         self.is_coord = True

@@ -69,6 +69,12 @@ class CommitRes(NamedTuple):
     new_cursor: np.ndarray
 
 
+class ReadIndexRes(NamedTuple):
+    ok: np.ndarray          # this node may answer the read (see kernels)
+    cbal: np.ndarray        # the ballot its peers are asked to confirm
+    next_slot: np.ndarray   # the read's index
+
+
 class PrepareRes(NamedTuple):
     acked: np.ndarray
     cur_bal: np.ndarray
@@ -201,6 +207,21 @@ class AcceptorBackend(abc.ABC):
         """Load whatever a takeover would otherwise compile the first
         time a leader dies.  Nothing to load on the engines that
         compile nothing."""
+
+    def read_index(self, rows) -> ReadIndexRes:
+        """The coordinator's half of a read without a round, a lane a
+        row: ``kernels.read_index_batch`` says what it answers."""
+        raise NotImplementedError
+
+    def confirm(self, rows, bals) -> np.ndarray:
+        """An acceptor's half: ``bool[n]``, each row's promise is exactly
+        ``bals`` (``kernels.confirm_batch``)."""
+        raise NotImplementedError
+
+    def warm_reads(self) -> None:
+        """Load the read index's programs at every bucket a batch of
+        reads can reach.  Nothing to load on the engines that compile
+        nothing."""
 
     def warm_recovery(self) -> None:
         """Load whatever a recovery (the install of every row, the
@@ -363,6 +384,21 @@ class ScalarBackend(AcceptorBackend):
                 wl[i, j], wh[i, j] = lo[0], hi[0]
         return PrepareRes(acked, cur_bal, cursor, ws, wb, wl, wh)
 
+    def read_index(self, rows) -> ReadIndexRes:
+        n = len(rows)
+        ok = np.zeros(n, bool)
+        cbal = np.full(n, NO_BALLOT, np.int32)
+        nxt = np.zeros(n, np.int32)
+        for i in range(n):
+            g = self._g(rows[i])
+            if g is not None:
+                ok[i], cbal[i], nxt[i] = g.read_index()
+        return ReadIndexRes(ok, cbal, nxt)
+
+    def confirm(self, rows, bals) -> np.ndarray:
+        return np.asarray([g is not None and g.confirm(int(b)) for g, b in
+                           zip(map(self._g, rows), bals)], bool)
+
     def install_coordinator(self, rows, cbals, next_slots, carry_slot,
                             carry_req) -> None:
         for i in range(len(rows)):
@@ -484,6 +520,21 @@ class NativeBackend(AcceptorBackend):
         n = len(rows)
         return PrepareRes(acked, cur_bal, cursor, ws, wb,
                           lo.reshape(n, -1), hi.reshape(n, -1))
+
+    def _scal(self, rows) -> np.ndarray:
+        """``[n, 8]``: each row's snapshot scalars, [bal, cbal,
+        exec_cursor, next_slot, gc_slot, version, members, is_coord << 1
+        | coord_active]."""
+        return np.asarray([s["scal"] for s in self.snapshot_rows(rows)],
+                          np.int32).reshape(-1, 8)
+
+    def read_index(self, rows) -> ReadIndexRes:
+        scal = self._scal(rows)
+        return ReadIndexRes((scal[:, 7] == 3) & (scal[:, 0] == scal[:, 1]),
+                            scal[:, 1], scal[:, 3])
+
+    def confirm(self, rows, bals) -> np.ndarray:
+        return self._scal(rows)[:, 0] == np.asarray(bals, np.int32)
 
     def install_coordinator(self, rows, cbals, next_slots, carry_slot,
                             carry_req) -> None:
@@ -1253,11 +1304,12 @@ class ColumnarBackend(AcceptorBackend):
             return tuple(res)
         return EngineWave(finish, n)
 
-    def _election_span(self, kind: str, name: str, n: int, chunks,
+    def _dispatch_span(self, kind: str, name: str, n: int, chunks,
                        back: int):
-        """The span of one election dispatch (``gp.eng.prepare`` /
-        ``gp.eng.install`` and the total of the same name): the kernel
-        it launches and the jitted function's own name (``program``: a
+        """The span of one dispatch outside the node wave
+        (``gp.eng.prepare`` / ``.install`` / ``.read_index`` /
+        ``.confirm`` and the total of the same name): the kernel it
+        launches and the jitted function's own name (``program``: a
         device trace lists its runs as ``jit_<program>``), valid lanes,
         first chunk's bucket, chunks, and the ``bytes`` its results copy
         back to the host (``back`` a padded lane)."""
@@ -1274,7 +1326,7 @@ class ColumnarBackend(AcceptorBackend):
         chunks = _chunks(n)
         # copied back a lane: acked (a byte), the promise and the
         # cursor, and the four [W] window columns
-        with self._election_span("eng.prepare", "prepare", n, chunks,
+        with self._dispatch_span("eng.prepare", "prepare", n, chunks,
                                  9 + 16 * self._window):
             outs = []
             for a, b in chunks:
@@ -1318,7 +1370,7 @@ class ColumnarBackend(AcceptorBackend):
         lo = lo.reshape(len(rows), m)
         hi = hi.reshape(len(rows), m)
         chunks = _chunks(len(rows))
-        with self._election_span("eng.install", "install_coordinator",
+        with self._dispatch_span("eng.install", "install_coordinator",
                                  len(rows), chunks, 0):
             for a, bnd in chunks:
                 n = bnd - a
@@ -1353,6 +1405,52 @@ class ColumnarBackend(AcceptorBackend):
                 self.state, _ = self._k.gc(
                     self.state, self._pad1(rows[a:b], 0),
                     self._pad1(upto[a:b], NO_SLOT), self._valid(b - a))
+
+    def _read_launch(self, kind: str, name: str, cols, back: int
+                     ) -> np.ndarray:
+        """Launch the read index's packed program ``name`` over
+        <=``_BUCKET_CAP``-lane chunks and collect ``[k, n]``, as
+        :meth:`_submit1` does but under a span of its own (the state
+        comes back unchanged)."""
+        kern = getattr(self._k, name)
+        n = len(cols[0][0])
+        chunks = _chunks(n)
+        self.launches += len(chunks)
+        with self._dispatch_span(kind, name, n, chunks, back):
+            cols = [(np.asarray(c), f) for c, f in cols]
+            outs = []
+            for a, b in chunks:
+                with self._disp():
+                    self.state, o = kern(self.state, self._packed(
+                        b - a, *[(c[a:b], f) for c, f in cols]))
+                _d2h_start(o)
+                outs.append((o, b - a))
+            return _collect_cols(outs)
+
+    def read_index(self, rows) -> ReadIndexRes:
+        # copied back a lane: ok, the ballot and the index
+        out = self._read_launch("eng.read_index", "read_index_p",
+                                [(rows, 0)], 12)
+        return ReadIndexRes(out[0] != 0, out[1], out[2])
+
+    def confirm(self, rows, bals) -> np.ndarray:
+        return self._read_launch("eng.confirm", "confirm_p",
+                                 [(rows, 0), (bals, NO_BALLOT)], 4)[0] != 0
+
+    def warm_reads(self) -> None:
+        """Load ``read_index_p`` and ``confirm_p`` at every bucket of the
+        ladder: a batch's reads, and a peer's confirmations, are any
+        number of lanes.  All-invalid lanes, under the ledger's warming
+        bracket like :meth:`warm_elections`; a node with ``PC.READ_INDEX``
+        on calls it at boot."""
+        k = self._k
+        with EngineLedger.warming():
+            for b in _ladder():
+                with self._disp():
+                    self.state, _ = k.read_index_p(
+                        self.state, self._dev(np.zeros((2, b), np.int32)))
+                    self.state, _ = k.confirm_p(
+                        self.state, self._dev(np.zeros((3, b), np.int32)))
 
     def cursor_of(self, row: int) -> int:
         return int(self.inspect_rows([row])["exec_cursor"][0])

@@ -39,6 +39,24 @@ class Replicable(abc.ABC):
     def restore(self, name: str, state: bytes) -> bool:
         """Reset group ``name``'s state to ``state`` (b"" = initial)."""
 
+    def is_read(self, payload: bytes) -> bool:
+        """Whether ``payload`` is a read: a request that changes nothing
+        of its group's state and whose answer is :meth:`read`'s.  With
+        ``PC.READ_INDEX`` on, the runtime answers such a request from the
+        group's coordinator without giving it a place in the order (the
+        read index), or, where that cannot be done, gives it a place and
+        answers it with :meth:`read` there, on every replica alike.  The
+        default calls nothing a read: every request is executed in the
+        order, as it always was."""
+        return False
+
+    def read(self, name: str, payload: bytes) -> bytes:
+        """The answer to the read ``payload`` from group ``name``'s state
+        as it stands: deterministic, and it changes nothing (no count, no
+        field, no place in the order).  Called only for payloads
+        :meth:`is_read` accepts."""
+        raise NotImplementedError(f"{type(self).__name__} serves no reads")
+
 
 class NoopApp(Replicable):
     """The benchmark app (ref: ``gigapaxos/examples/NoopPaxosApp.java``):
@@ -148,7 +166,14 @@ class RecordApp(Replicable):
     takes its place in the order, changes nothing and is answered with
     the count and ``b"?"``.  A record nobody restored or wrote is zeros.
     ``checkpoint`` / ``restore`` carry the count and the record (``b""``
-    for a group as created: nothing executed, nothing restored)."""
+    for a group as created: nothing executed, nothing restored).
+
+    ``b"R"`` is a read (``is_read``): ``read`` answers it with the count
+    of requests the group has executed so far and the record, and changes
+    nothing, the count included, so a read answered that way takes no
+    place: its count says how many requests of the order it saw.  Through
+    ``execute`` (``PC.READ_INDEX`` off) a read takes its place as any
+    request does."""
 
     def __init__(self, fields: int = 10, field_bytes: int = 100):
         self.fields, self.field_bytes = fields, field_bytes
@@ -173,6 +198,14 @@ class RecordApp(Replicable):
                 self.records[name] = rec[:at] + payload[2:] + rec[at + fb:]
                 return head
             return head + b"?"
+
+    def is_read(self, payload) -> bool:
+        return payload == b"R"
+
+    def read(self, name, payload) -> bytes:
+        with self._lock:
+            return self.count.get(name, 0).to_bytes(8, "little") \
+                + self.records.get(name, self._blank)
 
     def checkpoint(self, name) -> bytes:
         with self._lock:

@@ -50,6 +50,7 @@ from gigapaxos_tpu.paxos.logger import (CheckpointRec, LogEntry, PaxosLogger,
                                         REC_ACCEPT, REC_DECIDE,
                                         WalDegradedError, WalImpairedError)
 from gigapaxos_tpu.paxos.paxosconfig import PC, removed_settings
+from gigapaxos_tpu.paxos.readindex import PendingReads, Read
 from gigapaxos_tpu.utils.config import Config
 from gigapaxos_tpu.utils.engineledger import EngineLedger
 from gigapaxos_tpu.utils.instrument import (RequestInstrumenter, span,
@@ -70,6 +71,10 @@ FLAG_MISSING = 4
 # The coordinator also stamps it onto hash-sampled requests at propose
 # time, so acceptors honor the verdict even if configured differently.
 FLAG_SAMPLED = pkt.Request.FLAG_SAMPLED
+# a read (PC.READ_INDEX) that goes as a round: stamped by its coordinator,
+# it rides the log in the accept blob's flags byte, and every executor
+# answers it with Replicable.read at its place, so it changes no state
+FLAG_READ = 16
 
 _UNSET = object()  # cache-miss sentinel (None is a valid cached value)
 
@@ -328,6 +333,14 @@ class PaxosNode:
             # a node with peers can lose a leader: the election programs
             # are loaded now, not inside the first takeover
             self.backend.warm_elections()
+        # reads without a round (PC.READ_INDEX, paxos/readindex.py): the
+        # coordinator's book of reads between arrival and answer, the id
+        # of its next ReadConfirm frame, and the two programs loaded now
+        self._ri: Optional[PendingReads] = None
+        self._ri_xid = itertools.count(1)
+        if bool(Config.get(PC.READ_INDEX)):
+            self._ri = PendingReads()
+            self.backend.warm_reads()
         # fused C stage handlers (native backend only): one C call per
         # worker batch per stage, updating the numpy mirrors in place —
         # the per-batch numpy assembly cost (~1ms/batch chain at small
@@ -1566,6 +1579,12 @@ class PaxosNode:
                 log.exception("tick hook %r failed", fn)
         if self._fx_boot or self._fx_t0:
             self._frontier_tick(now)
+        if self._ri is not None and self._ri.reads:
+            # reads neither answered nor confirmed in time go as rounds
+            self._ri_answer()
+            late = self._ri.expire(now)
+            if late:
+                self._ri_fallback(late)
         # self-stall guard: if WE went dark longer than the failure
         # timeout (mass create holding the engine lock, GC, a
         # compile storm), the missing pings are OUR silence, not
@@ -1813,6 +1832,10 @@ class PaxosNode:
                     break
                 wave, self._self_buf = self._self_buf, []
                 self._process_inner(wave)
+            if self._ri is not None and (self._ri.ready or self._ri.fell):
+                # reads confirmed whose index has executed, the batch's
+                # answers and executions included
+                self._ri_answer()
         finally:
             # every per-batch buffer goes back to None BEFORE anything
             # below can raise: they are plain attributes, and one left
@@ -1978,6 +2001,15 @@ class PaxosNode:
                       n=sum(len(p.gkey) for p in prbs), cpu=self._ct):
                 for o in prbs:
                     self._handle_prepare_reply_batch(o)
+
+        # reads without a round: a peer's confirmations, the answers to
+        # this node's (PC.READ_INDEX)
+        rcs = by_type.pop(pkt.ReadConfirm, [])
+        if rcs:
+            self._handle_read_confirms(rcs)
+        rcrs = by_type.pop(pkt.ReadConfirmReply, [])
+        if rcrs:
+            self._handle_read_confirm_replies(rcrs)
 
         # hot path, pipeline order
         reqs = by_type.pop(pkt.Request, [])
@@ -2563,6 +2595,12 @@ class PaxosNode:
         # later slots wedged the group for good
         seen: set = set()
         n_dups = 0
+        # reads the read index answers (PC.READ_INDEX): taken out of the
+        # propose lanes here, launched together at the end.  A read that
+        # already went as a round (FLAG_READ) goes as one again
+        ri = self._ri
+        is_read = self.app.is_read if ri is not None else None
+        ri_lanes: List[Read] = []
         # ---- vectorized client batches (the hot path: one _ReqSoA per
         # wire read; per-lane Python is 3-4 dict ops) ----
         for sb in soas:
@@ -2633,6 +2671,15 @@ class PaxosNode:
                     n_dups += 1  # a copy already has its lane
                     continue
                 seen.add(rid)
+                if is_read is not None \
+                        and not int(sb.flags[i]) & FLAG_READ \
+                        and is_read(sb.payload(i)):
+                    if rid not in ri.reads:  # else a copy waits already
+                        ri_lanes.append(Read(
+                            rid, int(rows[i]), int(sb.gkey[i]),
+                            int(snd[i]), int(snd[i]), True,
+                            int(sb.flags[i]), bytes(sb.payload(i))))
+                    continue
                 keep.append(i)
             if keep:
                 ka = np.asarray(keep, np.int64)
@@ -2704,6 +2751,13 @@ class PaxosNode:
                 n_dups += 1
                 continue
             seen.add(o.req_id)
+            if is_read is not None and not o.flags & FLAG_READ \
+                    and is_read(o.payload):
+                if o.req_id not in ri.reads:
+                    ri_lanes.append(Read(o.req_id, meta.row, o.gkey,
+                                         o.sender, o.sender, True, o.flags,
+                                         o.payload))
+                continue
             lanes.append((meta.row, o.req_id, o.flags, o.payload, o.sender))
         for o in props:
             meta = self._lookup(o.gkey)
@@ -2782,10 +2836,21 @@ class PaxosNode:
                 n_dups += 1
                 continue
             seen.add(o.req_id)
+            if is_read is not None and not o.flags & FLAG_READ \
+                    and is_read(o.payload):
+                # answered to the replica that forwarded it, which
+                # relays the answer to its client
+                if o.req_id not in ri.reads:
+                    ri_lanes.append(Read(o.req_id, meta.row, o.gkey,
+                                         o.sender, o.entry, False, o.flags,
+                                         o.payload))
+                continue
             lanes.append((meta.row, o.req_id, o.flags, o.payload, o.entry))
         if n_dups:
             with self._stat_lock:
                 self.n_wave_dups += n_dups
+        if ri_lanes:
+            self._ri_arrive(ri_lanes, now)
         if lanes:
             rows_parts.append(np.asarray([l[0] for l in lanes], np.int32))
             req_parts.append(np.asarray([l[1] for l in lanes], np.uint64))
@@ -2797,6 +2862,141 @@ class PaxosNode:
         req_ids = np.concatenate(req_parts)
         self._la[rows] = now
         return rows, req_ids, flag_parts, pay_parts, now
+
+    # -- reads without a round (PC.READ_INDEX; paxos/readindex.py) -------
+
+    def _ri_arrive(self, reads: List[Read], now: float) -> None:
+        """Reads that arrived at a node that believes it coordinates
+        their groups: ONE launch of the coordinator program reads each
+        read's row (``backend.read_index``); a read whose row this node
+        leads at an undisputed ballot is booked with its index, and the
+        batch asks each peer, in ONE ``ReadConfirm`` frame, about the
+        groups of those reads (a lane a group).  Every other read goes as
+        a round."""
+        ri = self._ri
+        rows = np.asarray([rd.row for rd in reads], np.int32)
+        res = self.backend.read_index(rows)
+        ok = np.asarray(res.ok, bool)
+        m0 = time.monotonic()
+        fall: List[Read] = []
+        lane_of: Dict[int, int] = {}  # row -> its lane in the frame
+        lane_rids: List[List[int]] = []
+        lane_bal: List[int] = []  # the row's one coordinator ballot
+        for i, rd in enumerate(reads):
+            if not ok[i]:
+                fall.append(rd)
+                continue
+            rd.index, rd.t0, rd.m0 = int(res.next_slot[i]), now, m0
+            k = lane_of.setdefault(rd.row, len(lane_rids))
+            if k == len(lane_rids):
+                lane_rids.append([])
+                lane_bal.append(int(res.cbal[i]))
+            lane_rids[k].append(rd.rid)
+            mem = self._member_mat[rd.row]
+            rd.need = int((mem >= 0).sum()) // 2  # a majority, less self
+            ri.add(rd)
+        if lane_rids:
+            urows = np.fromiter(lane_of, np.int32, len(lane_of))
+            cbal = np.asarray(lane_bal, np.int32)
+            mm = self._member_mat[urows]
+            xid = next(self._ri_xid)
+            asked: Dict[int, np.ndarray] = {}
+            for peer in np.unique(mm).tolist():
+                if peer < 0 or peer == self.id:
+                    continue
+                sel = np.flatnonzero((mm == peer).any(axis=1))
+                asked[peer] = sel
+                self._route(peer, pkt.ReadConfirm(
+                    self.id, xid, self._row_gkey[urows[sel]], cbal[sel]))
+            ri.open_frame(xid, now, lane_rids, asked)
+            if asked:
+                DelayProfiler.add_total("ri.confirm_frames", 0.0,
+                                        len(asked))
+        if fall:
+            self._ri_fallback(fall)
+
+    def _ri_fallback(self, reads: List[Read]) -> None:
+        """Reads the read index cannot answer go as rounds: each one a
+        Proposal to this node, stamped ``FLAG_READ`` (so that it takes a
+        slot and is answered with ``Replicable.read`` at its place, on
+        every replica).  A read from a client keeps its waiter here; a
+        forwarded one is answered by the replica that forwarded it, which
+        executes the slot too."""
+        self._ri.fell += len(reads)
+        DelayProfiler.add_total("ri.fallbacks", 0.0, len(reads))
+        for rd in reads:
+            self._route(self.id, pkt.Proposal(
+                self.id, rd.gkey, rd.rid, rd.entry, rd.flags | FLAG_READ,
+                rd.payload))
+
+    def _ri_answer(self) -> None:
+        """Answer the confirmed reads whose index this node's app has
+        executed up to (``_cur``): ``Replicable.read`` on the state as it
+        stands, to each read's waiter."""
+        ri = self._ri
+        done = ri.take_ready(self._cur)
+        with traced("ri.serve", node=self.id) as sp:
+            sp.note(lanes=len(done), fallback=ri.fell)
+            ri.fell = 0
+            if not done:
+                return
+            m = time.monotonic()
+            fall, served = [], []
+            for rd in done:
+                meta = self.table.by_row(rd.row)
+                if meta is None or meta.gkey != rd.gkey:
+                    fall.append(rd)  # the group left this row meanwhile
+                    continue
+                if rd.client:
+                    self._client_wait.pop(rd.rid, None)
+                self._route(rd.waiter, pkt.Response(
+                    self.id, rd.gkey, rd.rid, 0,
+                    self.app.read(meta.name, rd.payload)))
+                served.append(rd)
+            if served:
+                n = len(served)
+                DelayProfiler.add_total("ri.reads", 0.0, n)
+                DelayProfiler.add_total(
+                    "ri.wait", sum(m - rd.m0 for rd in served), n)
+                DelayProfiler.add_total(
+                    "ri.exec_wait", sum(m - rd.m1 for rd in served), n)
+            if fall:
+                self._ri_fallback(fall)
+
+    def _handle_read_confirms(self, objs: List) -> None:
+        """A peer's half: ONE launch of the acceptor program over every
+        lane of the batch's ``ReadConfirm`` frames (``backend.confirm``:
+        ok where the row's promise is exactly the ballot asked about),
+        and a reply a frame.  A group this node does not hold is not
+        ok."""
+        gkeys = _cat(objs, lambda o: np.asarray(o.gkey, np.uint64))
+        bals = _cat(objs, lambda o: np.asarray(o.bal, np.int32))
+        with traced("ri.confirm", node=self.id) as sp:
+            rows = self._rows_for_keys(gkeys)
+            ok = np.zeros(len(rows), bool)
+            live = rows >= 0
+            if live.any():
+                ok[live] = self.backend.confirm(rows[live], bals[live])
+            sp.note(lanes=len(rows), ok=int(ok.sum()))
+            at = 0
+            for o in objs:
+                n = len(o.gkey)
+                self._route(o.sender, pkt.ReadConfirmReply(
+                    self.id, o.xid, ok[at:at + n].astype(np.uint8)))
+                at += n
+
+    def _handle_read_confirm_replies(self, objs: List) -> None:
+        """The coordinator's count: each ok counts for the reads of its
+        lane; a refusal sends the lane's reads, not yet confirmed, to the
+        round path (a higher ballot may have been promised: this node
+        answers nothing from its own state then)."""
+        if self._ri is None:
+            return
+        fall: List[Read] = []
+        for o in objs:
+            fall += self._ri.on_reply(o.xid, o.sender, np.asarray(o.ok))
+        if fall:
+            self._ri_fallback(fall)
 
     def _self_midx(self, rows) -> np.ndarray:
         """This node's member index per row (the fused self kernels
@@ -3686,6 +3886,10 @@ class PaxosNode:
                 # final state excludes it); tell the client to re-resolve
                 # the group and retry (ref: stopped-instance handling)
                 resp, status = b"", 3
+            elif flags & FLAG_READ and self.app.is_read(payload):
+                # a read that went as a round (PC.READ_INDEX): answered
+                # at its place, and it takes none in the app's state
+                resp = self.app.read(meta.name, payload)
             else:
                 # Bounded retries before declaring the exception
                 # deterministic: a transient, replica-local failure (I/O,

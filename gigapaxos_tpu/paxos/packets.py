@@ -77,6 +77,8 @@ class PacketType(IntEnum):
     WIRE_HELLO = 22       # wire-format version announcement
     FRONTIER_REQUEST = 23  # a recovered node's cursors, n groups a frame
     FRONTIER_REPLY = 24    # what the peer holds beyond them, batched
+    READ_CONFIRM = 25      # coordinator -> peers: confirm a batch's reads
+    READ_CONFIRM_REPLY = 26  # peer -> coordinator: an ok bit a group
 
 
 _HDR = struct.Struct("<BII")  # type, sender (u32, matches the transport's
@@ -805,6 +807,61 @@ class FrontierReply:
 
 
 @dataclass
+class ReadConfirm:
+    """Coordinator -> a peer: the groups of one worker batch's reads and
+    the ballot this node coordinates each at, asking whether the peer has
+    promised exactly that ballot and none above it (the read index's
+    confirmation round, ref: Raft's ReadIndex heartbeat).  A lane a
+    group, whatever number of reads the batch held for it."""
+
+    sender: int
+    xid: int             # the asker's frame id; the reply echoes it
+    gkey: np.ndarray     # u64[n]
+    bal: np.ndarray      # i32[n] packed coordinator ballot
+
+    TYPE = PacketType.READ_CONFIRM
+    _S = struct.Struct("<Q")
+
+    def encode(self) -> bytes:
+        return (_HDR.pack(self.TYPE, self.sender, len(self.gkey)) +
+                self._S.pack(self.xid) +
+                np.ascontiguousarray(self.gkey, np.uint64).tobytes() +
+                np.ascontiguousarray(self.bal, np.int32).tobytes())
+
+    @classmethod
+    def decode(cls, sender, n, body) -> "ReadConfirm":
+        (xid,) = cls._S.unpack_from(body, 0)
+        o = cls._S.size
+        gkey = np.frombuffer(body[o:o + 8 * n], np.uint64); o += 8 * n
+        bal = np.frombuffer(body[o:o + 4 * n], np.int32)
+        return cls(sender, xid, gkey, bal)
+
+
+@dataclass
+class ReadConfirmReply:
+    """A peer's answer to a ``ReadConfirm``: one ok byte a lane, in the
+    request's lane order."""
+
+    sender: int
+    xid: int
+    ok: np.ndarray       # u8[n]
+
+    TYPE = PacketType.READ_CONFIRM_REPLY
+    _S = struct.Struct("<Q")
+
+    def encode(self) -> bytes:
+        return (_HDR.pack(self.TYPE, self.sender, len(self.ok)) +
+                self._S.pack(self.xid) +
+                np.ascontiguousarray(self.ok, np.uint8).tobytes())
+
+    @classmethod
+    def decode(cls, sender, n, body) -> "ReadConfirmReply":
+        (xid,) = cls._S.unpack_from(body, 0)
+        o = cls._S.size
+        return cls(sender, xid, np.frombuffer(body[o:o + n], np.uint8))
+
+
+@dataclass
 class Control:
     """JSON control-plane envelope (cold path; reconfiguration layer).
 
@@ -907,6 +964,8 @@ _DECODERS = {
     PacketType.PREPARE_REPLY_BATCH: PrepareReplyBatch,
     PacketType.FRONTIER_REQUEST: FrontierRequest,
     PacketType.FRONTIER_REPLY: FrontierReply,
+    PacketType.READ_CONFIRM: ReadConfirm,
+    PacketType.READ_CONFIRM_REPLY: ReadConfirmReply,
 }
 
 

@@ -60,6 +60,14 @@ class PC(ConfigKey):
     USE_PALLAS_ACCEPT = False
     # fsync WAL batches before acking accepts (the durability contract)
     SYNC_WAL = True
+    # linearizable reads without a round (Raft's ReadIndex): a request the
+    # app calls a read (Replicable.is_read), arriving at its group's
+    # active coordinator, takes no slot, no accept and no WAL append; the
+    # coordinator notes its next slot, has a majority confirm that no
+    # higher ballot was promised, and answers with Replicable.read once
+    # its app executed every slot below the noted one.  Off: every read
+    # is a consensus round, as before.  Set it alike on every replica
+    READ_INDEX = False
     # compact (GC entries below each group's checkpointed slot) when the
     # WAL grows past this many bytes; the rewrite runs on the logger's
     # writer thread, off the worker's hot path

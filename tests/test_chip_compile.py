@@ -34,6 +34,9 @@ SERVING = {
     "accept_reply_commit_self_p": (6,), "accept_commit_p": (6, 5),
     "request_reply_p": (5, 6), "node_wave_p": (22,),
 }
+# the read index's two (PC.READ_INDEX): they read the group table and
+# hand the slab back as it came
+READS = {"read_index_p": (2,), "confirm_p": (3,)}
 
 
 @pytest.fixture(scope="module")
@@ -142,7 +145,8 @@ def compiled(topo, no_persistent_cache):
                 made[name, bucket] = _compile(
                     getattr(kernels, name),
                     _state_shapes(CAPACITY, WINDOW, chip),
-                    *[_packed(k, chip, bucket) for k in SERVING[name]])
+                    *[_packed(k, chip, bucket)
+                      for k in {**SERVING, **READS}[name]])
         return made[name, bucket]
     return get
 
@@ -156,6 +160,19 @@ def _serving(name):
         # beside its slab, where a program that copied ONE component
         # plane would ask 67 MB (request_reply_p at 64 lanes asked
         # 270,886,400 B before PR 32)
+        assert ma.temp_size_in_bytes < SERVING_TEMP
+    return run
+
+
+def _reads(name):
+    """A read program takes the slab and hands it back in place (every
+    output leaf but its lanes an alias of its input); its scratch is
+    lanes and their rows."""
+    def run(topo, compiled):
+        c = compiled(name)
+        ma = c.memory_analysis()
+        assert SLAB_BYTES <= ma.argument_size_in_bytes < 1.01 * SLAB_BYTES
+        assert ma.alias_size_in_bytes >= SLAB_BYTES
         assert ma.temp_size_in_bytes < SERVING_TEMP
     return run
 
@@ -288,6 +305,15 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
             for n in SERVING},
          "no_group_array.storm.decide_storm_step": _no_group_array("storm"),
          "storm_row_reads.storm.decide_storm_step": _row_reads("storm", 6),
+         # the read index reads one 128-word row a lane of the group
+         # table and nothing else, and moves and stages no plane
+         **{f"reads.{n}": _reads(n) for n in READS},
+         **{f"no_plane_relayout.reads.{n}": _no_relayout(n)
+            for n in READS},
+         **{f"no_plane_relayout.reads.{n}.bucket64": _no_relayout(n, 64)
+            for n in READS},
+         **{f"no_group_array.reads.{n}": _no_group_array(n)
+            for n in READS},
          "state.twelve_leaves": _twelve_leaves,
          "mesh.accept_p": _mesh("accept_p"),
          "mesh.accept_commit_p": _mesh("accept_commit_p"),

@@ -523,7 +523,7 @@ def test_readme_knob_table_is_the_code(tmp_path):
                            _readme(), re.M))
     assert set(rows) == {k.name for k in PC}, (
         sorted(set(rows) ^ {k.name for k in PC}))
-    assert len(PC) == 53
+    assert len(PC) == 54
     units = {"MiB": 1 << 20}
     for key in PC:
         shown, want = rows[key.name].strip(), key.default
