@@ -46,7 +46,8 @@ dense scans over the run structure instead of sorting and scattering for
 them.  Called without it — the packed wrappers, the mesh table, recovery —
 a body is exactly what it was.
 
-Group view (the optional argument ``view`` after ``runs``): a composition
+Group view (the optional argument ``view`` after ``runs`` of
+``propose_batch``, ``accept_batch`` and ``commit_batch``): a composition
 with a lane order may read each replica's group table ONCE
 (:func:`group_view`) and hand the bodies the ``[B]`` columns they read
 instead of a row read a stage call.  A body given a view reads those
@@ -54,6 +55,11 @@ columns from it, sets the table as it would without one, and returns the
 view as a third value with each column it set as the table now holds it,
 on every lane of the group's run.  Called without one, a body reads the
 table and returns two values, as before.
+
+Handed-on proposal: a composition with a view that proposes its lanes and
+counts their replies in the same program (the storm step) hands
+``propose_batch``'s output to :func:`accept_replies_batch`, which counts
+every sender's reply at once and reads no proposal word back.
 """
 
 from __future__ import annotations
@@ -378,29 +384,25 @@ class AcceptReplyOut(NamedTuple):
 
 
 def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
-                       valid, runs: Optional[LaneRuns] = None,
-                       view: Optional[dict] = None):
+                       valid, runs: Optional[LaneRuns] = None):
     """Handle (batched) accept replies.
 
     ``bal`` carries the accepted ballot on ack lanes and the acceptor's
     (higher) promised ballot on nack lanes, matching the reference's
     ``AcceptReplyPacket`` semantics.
 
-    Where ``runs`` vouches for distinct slots, a lane is alone in its
-    (group, slot) column: its vote is the whole batch's, nothing is
-    gathered again and nothing deduped, and vote and emitted bit ride ONE
-    scatter.
-
     The proposal column is read a word a plane at the flat index the
-    votes are added at; a caller that drops ``req_lo`` / ``req_hi`` (the
-    storm step) drops their two gathers with them.
+    votes are added at; a caller that drops ``req_lo`` / ``req_hi`` drops
+    their two gathers with them.  A composition that made the proposals
+    in the same program counts its replies with
+    :func:`accept_replies_batch` instead, which reads none of it.
     """
     G, W = state.G, state.W
     gi = _gi(g, valid if runs is None else runs.valid)
     w = jnp.where(valid, slot % W, 0)
     idx = gi * W + w
 
-    col = _stage_cols(state.grp, gi, runs, view)
+    col = _group_words(state.grp, gi)
     is_coord, cbal = col("is_coord"), col("cbal")
     coord_here = is_coord & col("coord_active")
     is_rel = valid & coord_here & (bal == cbal)
@@ -419,37 +421,27 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
             jnp.bitwise_and(votes, VOTE_MASK)).astype(i32)
         return match & (cnt >= _majority(col("members")))
 
-    if runs is not None and runs.distinct_slots:
-        # alone in its column: a lane's vote is the whole batch's
-        vote = jnp.where(fresh, bit, 0)
-        newly = crossed(prev + vote) & (jnp.bitwise_and(prev,
-                                                        EMITTED_BIT) == 0)
-        votes = state.prop_votes.at[
-            _sd(g, fresh | newly, G) * W + w].add(
-                vote + jnp.where(newly, EMITTED_BIT, 0), mode="drop",
-                unique_indices=True)
-    else:
-        votes = state.prop_votes.at[_si(g, fresh, G) * W + w].add(
-            jnp.where(fresh, bit, 0), mode="drop")
-        # re-gather POST-scatter so every lane of a (group, slot) column
-        # sees the whole batch's votes (two fresh votes in one batch must
-        # still cross quorum); `fresh` guarantees no bit is added twice,
-        # so the add never carries into EMITTED_BIT
-        quorum = crossed(votes[idx])
-        # Exactly-once emission: besides the cross-batch EMITTED_BIT,
-        # dedupe WITHIN the batch — when two replies for the same (group,
-        # slot) cross quorum in one batch, only the first lane emits the
-        # decision.  Non-quorum lanes get unique sentinel keys so they
-        # never form runs.
-        B = g.shape[0]
-        iota = jnp.arange(B, dtype=i32)
-        dup_before = quorum & (_run_rank(jnp.where(quorum, g, -1),
-                                         jnp.where(quorum, slot, iota)) > 0)
-        emitted_prev = jnp.bitwise_and(prev, EMITTED_BIT) != 0
-        newly = quorum & ~emitted_prev & ~dup_before
-        # `newly` is true at most once per column ever, so the add is an OR
-        votes = votes.at[_si(g, newly, G) * W + w].add(
-            jnp.where(newly, EMITTED_BIT, 0), mode="drop")
+    votes = state.prop_votes.at[_si(g, fresh, G) * W + w].add(
+        jnp.where(fresh, bit, 0), mode="drop")
+    # re-gather POST-scatter so every lane of a (group, slot) column
+    # sees the whole batch's votes (two fresh votes in one batch must
+    # still cross quorum); `fresh` guarantees no bit is added twice,
+    # so the add never carries into EMITTED_BIT
+    quorum = crossed(votes[idx])
+    # Exactly-once emission: besides the cross-batch EMITTED_BIT,
+    # dedupe WITHIN the batch — when two replies for the same (group,
+    # slot) cross quorum in one batch, only the first lane emits the
+    # decision.  Non-quorum lanes get unique sentinel keys so they
+    # never form runs.
+    B = g.shape[0]
+    iota = jnp.arange(B, dtype=i32)
+    dup_before = quorum & (_run_rank(jnp.where(quorum, g, -1),
+                                     jnp.where(quorum, slot, iota)) > 0)
+    emitted_prev = jnp.bitwise_and(prev, EMITTED_BIT) != 0
+    newly = quorum & ~emitted_prev & ~dup_before
+    # `newly` is true at most once per column ever, so the add is an OR
+    votes = votes.at[_si(g, newly, G) * W + w].add(
+        jnp.where(newly, EMITTED_BIT, 0), mode="drop")
 
     # Preemption: a nack carrying a ballot above ours ends our reign
     # (ref: PaxosCoordinator preemption on higher-ballot accept replies).
@@ -463,17 +455,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         return _set_cols(t, _si(g, pre, G), is_coord=False,
                          coord_active=False)
 
-    if view is None:
-        grp = jax.lax.cond(pre.any(), resign, lambda t: t, state.grp)
-    else:
-        def resign_viewed(t, is_c, c_act):  # a run with a resigner resigns
-            out = _run_total(runs, pre) > 0
-            return resign(t), is_c & ~out, c_act & ~out
-
-        grp, is_c, c_act = jax.lax.cond(
-            pre.any(), resign_viewed, lambda *a: a, state.grp,
-            view["is_coord"], view["coord_active"])
-        view = dict(view, is_coord=is_c, coord_active=c_act)
+    grp = jax.lax.cond(pre.any(), resign, lambda t: t, state.grp)
     # the branch's result stays the table's one value: without the barrier
     # the v5e's compiler moves the next stage's view of the table into
     # the branches and then copies the 64 MB plane twice a reply
@@ -488,8 +470,7 @@ def accept_reply_batch(state: ColumnarState, g, slot, bal, sender, acked,
         req_lo=state.prop_rlo[idx],
         req_hi=state.prop_rhi[idx],
     )
-    state = state._replace(prop_votes=votes, grp=grp)
-    return (state, out) if view is None else (state, out, view)
+    return state._replace(prop_votes=votes, grp=grp), out
 
 
 # --------------------------------------------------------------------------
@@ -514,6 +495,10 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
     occurrence index among same-group lanes (stable-sort run rank,
     O(B log B) — see :func:`_run_rank`; its place in the run, with
     ``runs``).
+
+    Given a view, the proposal it returns is handed on: the granted
+    lanes' vote words are NOT written here, and the caller hands the
+    :class:`ProposeOut` to :func:`accept_replies_batch`, which sets them.
     """
     G, W = state.G, state.W
     B = g.shape[0]
@@ -544,12 +529,16 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
 
     # initialize the proposal column for the assigned slot: slot, req id,
     # zero votes/emitted, a word a component plane; the slots of a group
-    # are distinct and inside one window: distinct columns
+    # are distinct and inside one window: distinct columns.  With a view
+    # the vote word is left to the reply stage the proposal is handed to
+    # (:func:`accept_replies_batch`), which sets it once from zero
     w = jnp.where(granted, slot % W, 0)
     sg = _si(g, granted, G) if runs is None else _sd(g, granted, G)
-    prop = _set_words(state, sg * W + w, dict(
-        prop_slot=slot, prop_rlo=rlo, prop_rhi=rhi,
-        prop_votes=jnp.zeros_like(slot)), unique=runs is not None)
+    idx = sg * W + w
+    words = dict(prop_slot=slot, prop_rlo=rlo, prop_rhi=rhi)
+    if view is None:
+        words["prop_votes"] = jnp.zeros_like(slot)
+    prop = _set_words(state, idx, words, unique=runs is not None)
 
     out = ProposeOut(
         granted=granted,
@@ -565,6 +554,77 @@ def propose_batch(state: ColumnarState, g, rlo, rhi, valid,
     # next_slot plus their count
     return state, out, dict(
         view, next_slot=col("next_slot") + _run_total(runs, granted))
+
+
+def accept_replies_batch(state: ColumnarState, g, proposal: ProposeOut,
+                         bals, acks, runs: LaneRuns, view: dict):
+    """Every sender's accept reply to the proposals this program has just
+    made, counted in ONE stage: ``bals[r]`` and ``acks[r]`` are the reply
+    of sender ``r``, what :func:`accept_reply_batch` takes a sender a
+    call, for ``r`` in 0 .. R-1 in sender order.
+
+    ``proposal`` is what :func:`propose_batch` returned given the same
+    lane order and a view: a granted lane's column holds ``prop_slot ==
+    slot``, and its vote word, which that stage left unwritten, starts at
+    0.  So nothing of the proposal is read back.  A lane's votes are
+    counted in sender order: a sender that matches sets its bit (the word
+    starts empty and each sender has a bit of its own, so every match is
+    fresh), and the first whose bit brings the count to a majority
+    decides the lane.  The vote word is then set ONCE on every granted
+    lane: the senders' bits, and ``EMITTED_BIT`` where the lane decided.
+    A nack above ``cbal`` resigns the coordinator of its group for the
+    senders after it, as between two calls.  Returns ``(state, newly,
+    view)``: the planes and the table as R calls of
+    :func:`accept_reply_batch` leave them, the lanes that decided, and
+    the view with ``is_coord`` and ``coord_active`` as that table holds
+    them.
+    """
+    G, W = state.G, state.W
+    slot, granted = proposal.slot, proposal.granted
+    cbal, quorum = view["cbal"], _majority(view["members"])
+
+    # Preemption, in sender order: a prefix over the senders, since a
+    # resigned coordinator counts no later sender's vote.  Under one real
+    # branch (see accept_reply_batch), taken where a sender would resign
+    # by the view as it comes in: the view only loses coordinators, so no
+    # sender resigns where none would by it
+    nack_up = [granted & ~a & (b > cbal) for a, b in zip(acks, bals)]
+
+    def resign_in_order(t, is_c, c_act):
+        heres, down = [], jnp.zeros_like(granted)
+        for up in nack_up:
+            heres.append(is_c & c_act)
+            pre = up & is_c
+            down = down | pre
+            out = _run_total(runs, pre) > 0  # a run with a resigner
+            is_c, c_act = is_c & ~out, c_act & ~out
+        t = _set_cols(t, _si(g, down, G), is_coord=False,
+                      coord_active=False)
+        return t, heres, is_c, c_act
+
+    def keep(t, is_c, c_act):
+        return t, [is_c & c_act] * len(nack_up), is_c, c_act
+
+    is_c = view["is_coord"]
+    grp, heres, is_c, c_act = jax.lax.cond(
+        jnp.any(jnp.stack(nack_up) & is_c), resign_in_order, keep,
+        state.grp, is_c, view["coord_active"])
+    # the table stays the branch's one value (see accept_reply_batch)
+    grp = jax.lax.optimization_barrier(grp)
+
+    votes, newly = jnp.zeros_like(slot), jnp.zeros_like(granted)
+    for r, (a, b, here) in enumerate(zip(acks, bals, heres)):
+        match = granted & here & (b == cbal) & a
+        votes = votes | jnp.where(match, 1 << r, 0)
+        cnt = jax.lax.population_count(votes).astype(i32)
+        newly = newly | (match & (cnt >= quorum))
+    w = jnp.where(granted, slot % W, 0)
+    prop_votes = state.prop_votes.at[_sd(g, granted, G) * W + w].set(
+        votes | jnp.where(newly, EMITTED_BIT, 0), mode="drop",
+        unique_indices=True)
+
+    state = state._replace(prop_votes=prop_votes, grp=grp)
+    return state, newly, dict(view, is_coord=is_c, coord_active=c_act)
 
 
 # --------------------------------------------------------------------------

@@ -3,8 +3,8 @@
 One jitted call runs the ENTIRE §3.1 hot path for a batch of B requests
 against an emulated R-replica fleet living on one chip:
 
-    propose (coordinator) → accept ×R → accept_reply ×R (quorum count)
-    → commit ×R (window frontier advance)
+    propose (coordinator) → accept ×R → the R accept replies counted at
+    once (quorum count) → commit ×R (window frontier advance)
 
 This is the BASELINE.json config-3 workload ("1M groups, batched
 AcceptPacket storms") expressed the TPU way: instead of R processes
@@ -68,13 +68,11 @@ def decide_storm_step(states: Tuple[ColumnarState, ...], g, rlo, rhi,
             new_states[r], g, slot, bal, rlo, rhi, granted, runs, views[r])
         acks.append(ar.acked)
 
-    newly = jnp.zeros_like(granted)
-    for r in range(R):
-        sender = jnp.full_like(g, r)
-        new_states[0], rr, views[0] = kernels.accept_reply_batch(
-            new_states[0], g, slot, bal, sender, acks[r], granted, runs,
-            views[0])
-        newly = newly | rr.newly_decided
+    # the coordinator counts a lane's R replies in registers, from the
+    # proposal its propose stage hands on: no proposal word is read back,
+    # and the vote word is written once
+    new_states[0], newly, views[0] = kernels.accept_replies_batch(
+        new_states[0], g, pr, [bal] * R, acks, runs, views[0])
 
     for r in range(R):
         new_states[r], _cr, views[r] = kernels.commit_batch(

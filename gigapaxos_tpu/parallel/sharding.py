@@ -104,7 +104,7 @@ def make_sharded_storm(mesh: Mesh, n_replicas: int = 3):
     """The full decide-storm step as ONE shard_map program: every shard
     masks the wave down to its own groups (block ownership, same math as
     :mod:`gigapaxos_tpu.ops.meshkernels`), runs the whole propose ->
-    accept x R -> reply x R -> commit x R pipeline on its local state
+    accept x R -> the R replies -> commit x R pipeline on its local state
     block, and the only collective is the psum of the decided count.
     State stays resident and donated; ``n_replicas`` is pinned by the
     caller and unused here (the fleet tuple's length carries it)."""

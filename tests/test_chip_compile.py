@@ -201,7 +201,9 @@ def _no_relayout(name, bucket=BUCKET, stagings=0):
     third); the counts are what it chooses today, the resign branches'
     included (the storm step's 12 became 7 when its stages read each
     table once a step, PR 39).  At the lane counts the served cells run
-    (8-1,024) it stages none."""
+    (8-1,024) it stages none.  The storm step's 7 became 5 when its
+    replies stopped reading the proposal back: ``prop_votes`` is set once
+    a step and read by no stage."""
     def run(topo, compiled):
         c, words = compiled(name, bucket), CAPACITY * WINDOW
         moved = _plane_moves(c, words, _RELAYOUTS)
@@ -246,6 +248,28 @@ def _row_reads(name, most):
                  if re.search(rf"= s32\[{STORM_LANES},128\]\S* gather\(",
                               line)]
         assert 0 < len(reads) <= most, "\n".join(reads)
+    return run
+
+
+_NAMED = re.compile(r"^\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+
+
+def _no_word_gather_of_a_plane(name):
+    """No gather in the program takes one word a lane (an ``s32[262144]``
+    at the storm's size) out of an array of a plane's 2^24 words, inside
+    a fusion or out of one (a fused gather's operand is a parameter of
+    its fusion, whose shape that parameter's own line gives).  The
+    storm's replies count a lane's votes from the proposal its propose
+    stage hands on; where they read the proposal back, ``prop_slot``
+    once and ``prop_votes`` once a sender, these were four gathers of
+    1.9-4.0 ms each, the step's longest operations (PERF.md §5.3)."""
+    def run(topo, compiled):
+        text = compiled(name).as_text()
+        shape = dict(_NAMED.findall(text))
+        found = [f"{op} s32[{shape.get(op)}]" for op in re.findall(
+            rf"= s32\[{STORM_LANES}\]\S* gather\((%[\w.\-]+),", text)
+            if _words(shape.get(op, "")) >= CAPACITY * WINDOW]
+        assert not found, "\n".join(found)
     return run
 
 
@@ -299,12 +323,14 @@ CASES = {**{f"serving.{n}": _serving(n) for n in SERVING},
          **{f"no_plane_relayout.serving.{n}.bucket64": _no_relayout(n, 64)
             for n in ("request_reply_p", "accept_commit_p", "node_wave_p")},
          "no_plane_relayout.storm.decide_storm_step": _no_relayout(
-             "storm", stagings=7),
+             "storm", stagings=5),
          # the groups' scalars are one word table (PR 37)
          **{f"no_group_array.serving.{n}": _no_group_array(n)
             for n in SERVING},
          "no_group_array.storm.decide_storm_step": _no_group_array("storm"),
          "storm_row_reads.storm.decide_storm_step": _row_reads("storm", 6),
+         "no_word_gather_of_a_plane.storm.decide_storm_step":
+             _no_word_gather_of_a_plane("storm"),
          # the read index reads one 128-word row a lane of the group
          # table and nothing else, and moves and stages no plane
          **{f"reads.{n}": _reads(n) for n in READS},

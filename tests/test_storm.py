@@ -44,14 +44,16 @@ def test_storm_duplicate_groups_in_batch():
 
 def _composition(states, g, rlo, rhi, valid, ordered=False,
                  nack_promise=False):
-    """The step's ten stages.  Unordered, the reference the storm step
+    """The step's stages.  Unordered, the reference the storm step
     must equal: the step as it was before the lanes were put in group
-    order, each body called without an order or a view, every lane where
-    the caller put it.  Ordered, the storm step as it runs them: one lane
-    order, each replica's group view read once and handed from stage to
-    stage; it also answers with the valid lanes of the order and, after
-    every stage, each column of the stage's view beside the same column
-    read from the table the stage left.  ``nack_promise``: an acceptor
+    order, each body called without an order or a view, a reply stage a
+    sender, every lane where the caller put it.  Ordered, the storm step
+    as it runs them: one lane order, each replica's group view read once
+    and handed from stage to stage, the proposal handed from propose to
+    ONE reply stage that counts every sender's reply; it also answers
+    with the valid lanes of the order and, after every stage, each column
+    of the stage's view beside the same column read from the table the
+    stage left.  ``nack_promise``: an acceptor
     answers a nack with its promise, as a served node does, so a nack
     above the coordinator's ballot resigns it (the storm's replies carry
     the coordinator's own ballot, which cannot)."""
@@ -83,12 +85,17 @@ def _composition(states, g, rlo, rhi, valid, ordered=False,
     slot, bal, granted = pr.slot, pr.cbal, pr.granted
     acks = [stage(r, kernels.accept_batch, g, slot, bal, rlo, rhi, granted)
             for r in range(R)]
-    newly = jnp.zeros_like(granted)
-    for r, ar in enumerate(acks):
-        reply = jnp.where(ar.acked, bal, ar.cur_bal) if nack_promise else bal
-        rr = stage(0, kernels.accept_reply_batch, g, slot, reply,
-                   jnp.full_like(g, r), ar.acked, granted)
-        newly = newly | rr.newly_decided
+    replies = [jnp.where(ar.acked, bal, ar.cur_bal) if nack_promise else bal
+               for ar in acks]
+    if ordered:  # the coordinator counts every sender's reply at once
+        newly = stage(0, kernels.accept_replies_batch, g, pr, replies,
+                      [ar.acked for ar in acks])
+    else:
+        newly = jnp.zeros_like(granted)
+        for r, (ar, reply) in enumerate(zip(acks, replies)):
+            rr = stage(0, kernels.accept_reply_batch, g, slot, reply,
+                       jnp.full_like(g, r), ar.acked, granted)
+            newly = newly | rr.newly_decided
     for r in range(R):
         stage(r, kernels.commit_batch, g, slot, rlo, rhi, newly)
     return tuple(new), jnp.sum(newly.astype(jnp.int32)), (valid, seen)
@@ -98,7 +105,8 @@ def _assert_views_are_the_tables(valid, seen, where):
     """Every column of every stage's view is what the table holds for the
     lane's group, on the order's valid lanes."""
     v = np.asarray(valid)
-    assert len(seen) == 1 + 3 + 3 + 3  # stages of a three-replica step
+    # propose, three accepts, the replies at once, three commits
+    assert len(seen) == 1 + 3 + 1 + 3
     for k, cols in enumerate(seen):
         for f, (view, table) in cols.items():
             np.testing.assert_array_equal(
@@ -249,6 +257,128 @@ def test_views_under_preemption(nack_promise):
     assert (np.asarray(got[1].bal)[raised] == pack_ballot(1, 0)).all()
 
 
+# name: (G, W, B, groups the lanes fall on, share of valid lanes, lanes
+#        forced onto group 3, replicas, what the replies are)
+REPLY_CASES = {
+    **{c: (*ORDER_CASES[c], 3, "random") for c in ORDER_CASES},
+    # sender 1 nacks above the ballot on the even groups, and sender 2,
+    # after it, acks: its vote must not count there
+    "nack_above_then_ack": (64, 8, 96, 64, 1.0, 0, 3, "preempt"),
+    # a third of the groups have no coordinator here, and 12 lanes a
+    # group fill the window of 8: lanes that were not granted
+    "not_granted": (64, 8, 128, 12, 0.9, 0, 3, "random"),
+    "five_senders": (64, 8, 96, 32, 0.9, 0, 5, "random"),
+}
+
+
+def _replies_both_ways(states, g, rlo, rhi, valid, acks, bump):
+    """One propose of a coordinator with a lane order, then the replies
+    of ``len(acks)`` senders to it, both ways: ONE
+    ``accept_replies_batch`` handed the proposal and the view, and R
+    calls of ``accept_reply_batch`` in sender order after a propose
+    without a view, which wrote the vote words itself.  Sender r's reply
+    carries the coordinator's ballot plus ``bump[r]``.  Returns each
+    side's state and decided lanes, with the view on one side and the
+    same columns read from the table on the other, and the order's
+    valid lanes."""
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops import kernels
+    from gigapaxos_tpu.ops.storm import COORDINATOR_COLS
+
+    runs, g, rlo, rhi = kernels.lane_runs(g, valid, rlo, rhi,
+                                          distinct_slots=True)
+    view = kernels.group_view(states[0], g, COORDINATOR_COLS)
+    one, pr, view = kernels.propose_batch(states[0], g, rlo, rhi,
+                                          runs.valid, runs, view)
+    each, pr_each = kernels.propose_batch(states[0], g, rlo, rhi,
+                                          runs.valid, runs)
+    bals = [pr.cbal + b for b in bump]
+    one, newly, view = kernels.accept_replies_batch(
+        one, g, pr, bals, list(acks), runs, view)
+    newly_each = jnp.zeros_like(pr.granted)
+    for r, (a, b) in enumerate(zip(acks, bals)):
+        each, rr = kernels.accept_reply_batch(
+            each, g, pr.slot, b, jnp.full_like(g, r), a, pr.granted, runs)
+        newly_each = newly_each | rr.newly_decided
+    table = kernels._group_words(each.grp, g)
+    return (one, newly, view), \
+        (each, newly_each, {f: table(f) for f in view}), \
+        (pr, pr_each, runs.valid)
+
+
+@pytest.mark.parametrize("case", sorted(REPLY_CASES))
+def test_replies_at_once_equal_the_calls_in_sender_order(case):
+    """The storm's reply stage, which counts every sender's reply to the
+    proposal its step just made in registers and sets each granted
+    lane's vote word once, leaves every plane and the table, and answers
+    the same decided lanes, as one ``accept_reply_batch`` a sender in
+    sender order, bit for bit, and its view is the table those calls
+    leave: over several steps (windows wrap over vote words of earlier
+    slots, and the planes start with junk in them), with nacks above the
+    coordinator's ballot that resign it for the senders after them, lanes
+    that were not granted, and all lanes on one group."""
+    import jax
+    import jax.numpy as jnp
+    from gigapaxos_tpu.ops import kernels
+    from gigapaxos_tpu.ops.storm import make_fleet, storm
+
+    G, W, B, hit, p_valid, hot, R, replies = REPLY_CASES[case]
+    rng = np.random.default_rng(43 + sorted(REPLY_CASES).index(case))
+    fleet = list(make_fleet(G, W, R=R))
+    coord = fleet[0]._replace(**{f: jnp.asarray(rng.integers(
+        -9, 1 << 30, G * W, dtype=np.int32)) for f in (
+            "prop_slot", "prop_votes", "prop_rlo", "prop_rhi")})
+    if case == "not_granted":
+        rows = jnp.asarray(np.arange(0, G, 3, dtype=np.int32))
+        coord = coord._replace(grp=kernels._set_cols(
+            coord.grp, rows, is_coord=False, coord_active=False))
+    fleet[0] = coord
+    both = jax.jit(_replies_both_ways)
+    decided = resigned = 0
+    for it in range(4):
+        g = rng.integers(0, hit, B).astype(np.int32)
+        g[rng.choice(B, hot, replace=False)] = 3
+        lanes = [jnp.asarray(a) for a in (
+            g, rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.integers(1, 1 << 30, B, dtype=np.int32),
+            rng.random(B) < p_valid)]
+        if replies == "preempt":  # in the lane order, by the lane's group
+            even = np.sort(g) % 2 == 0
+            acks = np.ones((R, B), bool)
+            acks[1] = ~even
+            bump = np.zeros((R, B), np.int32)
+            bump[1] = even
+        else:
+            acks = rng.random((R, B)) < 0.8
+            bump = np.where(acks, rng.random((R, B)) < 0.05,
+                            rng.random((R, B)) < 0.1).astype(np.int32)
+        one, each, (pr, pr_each, v) = both(
+            tuple(fleet), *lanes, jnp.asarray(acks), jnp.asarray(bump))
+        where = f"{case}: step {it}"
+        for a, b in zip(pr, pr_each):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        (st1, newly1, view), (st2, newly2, table) = one, each
+        np.testing.assert_array_equal(np.asarray(newly1), np.asarray(newly2),
+                                      err_msg=where)
+        _assert_same_fleet((st1,), (st2,), where)
+        v = np.asarray(v)
+        for f in view:
+            np.testing.assert_array_equal(
+                np.asarray(view[f])[v], np.asarray(table[f])[v],
+                err_msg=f"{where}, view {f}")
+        decided += int(np.asarray(newly1).sum())
+        resigned += int((np.asarray(fleet[0].is_coord)
+                         & ~np.asarray(st1.is_coord)).sum())
+        if replies == "preempt":
+            lost = np.asarray(pr.granted) & even
+            assert lost.any() and not np.asarray(newly1)[lost].any()
+            assert not np.asarray(view["is_coord"])[lost].any()
+        # the fleet moves on by the storm step, so windows fill and wrap
+        fleet = list(storm(tuple(fleet), *lanes)[0])
+    assert (decided > 0) == (p_valid > 0), case
+    assert (resigned > 0) == (p_valid > 0), case
+
+
 def _count_primitives(jaxpr, out, under_cond=False):
     """Equations by primitive, those under a ``cond`` apart: a branch
     runs only when its predicate holds."""
@@ -262,14 +392,42 @@ def _count_primitives(jaxpr, out, under_cond=False):
                     _count_primitives(sub, out, under_cond or name == "cond")
 
 
+def _gathers_of_planes(jaxpr, held):
+    """The gathers whose operand is one of the variables ``held`` or a
+    version of one: what a scatter into it, or a reshape of it, made of
+    it.  Followed into ``jit`` and ``cond`` bodies, whose operands are
+    their equation's (a cond's after its predicate)."""
+    held, found = set(held), []
+    for eqn in jaxpr.eqns:
+        ins = [v in held if not hasattr(v, "val") else False
+               for v in eqn.invars]
+        if not any(ins):
+            continue
+        if eqn.primitive.name == "gather":
+            if ins[0]:
+                found.append(eqn)
+            continue
+        subs = [getattr(b, "jaxpr", b) for b in eqn.params.get(
+            "branches", [eqn.params.get("jaxpr")]) if b is not None]
+        for sub in subs:
+            off = len(ins) - len(sub.invars)
+            found += _gathers_of_planes(
+                sub, [v for v, h in zip(sub.invars, ins[off:]) if h])
+        size = next(v.aval.size for v, h in zip(eqn.invars, ins) if h)
+        held.update(v for v in eqn.outvars if v.aval.size == size)
+    return found
+
+
 def test_step_sorts_once_and_keeps_its_passes_down():
     """Counts only: every serial pass over the lanes is a sort or a
     scatter.  One sort a step (the lane order), no scatter into a
-    lane-shaped array (what un-permuting a rank is), and 35 scatters that
-    always run, each of ONE word a lane: propose 1 + 4 (``next_slot`` and
-    the four proposal components), accept (1 + 4) x R, accept-reply
-    1 x R (the vote add), commit (3 + 1) x R; the R resign scatters (two
-    columns of the group table each) sit under a cond.  Every one that
+    lane-shaped array (what un-permuting a rank is), and 32 scatters that
+    always run, each of ONE word a lane: propose 1 + 3 (``next_slot`` and
+    the proposal's slot and request id; its vote word is the reply
+    stage's), accept (1 + 4) x R, the replies' ONE vote set, commit
+    (3 + 1) x R; the resign scatter (two columns of the group table) sits
+    under a cond.  The replies read no proposal word: no gather in the
+    step reads ``prop_slot`` or ``prop_votes``.  Every one that
     always runs states that its indices are unique, and writes the group
     table ``[G * 16]`` or a linear ``[G * W]`` component plane: there is
     no ``[G]`` array.  The table is read ONCE a replica a step, R row
@@ -296,8 +454,8 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     assert sum(n == "sort" for n, _, _ in eqns) == 1
     scatters = [(c, e) for n, c, e in eqns if n.startswith("scatter")]
     always = [e for c, e in scatters if not c]
-    assert len(always) == 5 + 10 * R, len(always)
-    assert len(scatters) - len(always) == R
+    assert len(always) == 5 + 9 * R, len(always)
+    assert len(scatters) - len(always) == 1
     table = 16 * G
     assert sum(e.invars[0].aval.shape == (table,) for e in always) == \
         1 + 2 * R  # next_slot; bal and exec_cursor of each replica
@@ -313,6 +471,11 @@ def test_step_sorts_once_and_keeps_its_passes_down():
     for e in gathers:  # the plane may be seen as rows of 128 words
         assert e.invars[0].aval.size in (table, G * W), e
     assert sum(e.invars[0].aval.size == table for e in gathers) == R
+    leaves = jaxpr.jaxpr.invars
+    n = len(st)  # a replica's leaves, in ColumnarState's field order
+    assert not _gathers_of_planes(jaxpr.jaxpr, [
+        leaves[r * n + st._fields.index(f)]
+        for r in range(R) for f in ("prop_slot", "prop_votes")])
     for n, _, e in eqns:
         if n.startswith("cum"):
             assert e.invars[0].aval.shape == (B,), e
